@@ -45,8 +45,8 @@ def test_collect_child_timeout_labeled(tmp_path):
 
 
 def test_train_only_covers_compiler_crashers():
-    """The queries whose fori bodies crash the remote compile helper must
-    stay on the train path (measured round-5 diagnosis)."""
+    """The queries whose fori bodies failed to compile for the TPU in
+    round 5 stay on the train path."""
     bench = _bench()
     assert {"q18", "q95", "q3_sf10"} <= set(bench.TRAIN_ONLY)
     # the five round-5 roster entries stay present (additions are fine)

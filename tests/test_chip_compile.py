@@ -1,0 +1,127 @@
+"""Compile for the chip without the chip.
+
+The TPU's compiler is installed here and compiles for a DESCRIBED
+``v5e:2x2`` topology (nothing runs, so these say nothing about results or
+times — ``chip_smoke.py`` on the chip does). What they guard: the Pallas
+merge kernel and the whole-query XLA bodies keep LOWERING for the v5e at
+TPC-H sf1 widths, which interpret mode and the CPU backend cannot show.
+
+This is the only file that describes a topology, and it does so inside a
+fixture: only the xdist worker that RUNS this file may load libtpu (one
+process at a time holds it), and every worker must collect the same tests.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from bench import _SQL as BENCH_SQL
+
+SF1_ORDERS = 1_500_000
+SF1_LINEITEM = 6_001_215
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("block_build", [2048, 256])
+def test_merge_kernel_compiles_at_sf1_widths(one_chip, block_build):
+    from trino_tpu.ops import merge_pallas
+
+    build = jax.ShapeDtypeStruct((SF1_ORDERS,), jnp.int32, sharding=one_chip)
+    probe = jax.ShapeDtypeStruct((SF1_LINEITEM,), jnp.int32,
+                                 sharding=one_chip)
+    compiled = merge_pallas.merge_unique_sorted.lower(
+        build, probe, block_build=block_build, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel is in
+
+
+@pytest.mark.parametrize("query", ["q1", "q3"])
+def test_compiled_query_body_compiles(one_chip, query):
+    """``CompiledQuery.raw_fn`` — the whole-query XLA body ``bench.py``
+    times — traced over tpch.tiny's staged shapes, lowered for the v5e."""
+    from trino_tpu import Session
+    from trino_tpu.exec.compiled import CompiledQuery
+    from trino_tpu.exec.query import plan_sql
+
+    session = Session()
+    cq = CompiledQuery.build(session, plan_sql(session, BENCH_SQL[query]))
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in cq.input_arrays]
+    compiled = jax.jit(cq.raw_fn).lower(shapes).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_wide_sort_compiles_as_one_two_operand_sort(one_chip):
+    """q3's ORDER BY at sf1 — 6 keys and 9 payloads over 31,869 rows,
+    which as ONE ``lax.sort`` took the v5e compiler ~660 s on the chip
+    (PR 25) — through ``ranks.stable_sort``: a single (int32, int32) sort
+    instruction in a loop over the key digits, whatever the operands."""
+    from trino_tpu.ops import ranks
+
+    b, i8, i32, i64 = jnp.bool_, jnp.int8, jnp.int32, jnp.int64
+    operands = [jax.ShapeDtypeStruct((31_869,), dt, sharding=one_chip)
+                for dt in (b, i8, i64, i8, i64, i8,
+                           i32, i32, i64, b, i64, i32, b, i32, b)]
+    compiled = jax.jit(lambda *ops: ranks.stable_sort(ops, 6)).lower(
+        *operands).compile()
+    sorts = [line for line in compiled.as_text().splitlines()
+             if " sort(" in line]
+    assert len(sorts) == 1 and sorts[0].count("s32[32768]") >= 2, sorts
+
+
+def test_spmd_hash_partitioned_q3_compiles_with_all_to_all(topo):
+    """The SPMD tier's promise — shuffles are ICI collectives — checked
+    in the program the v5e compiler emits. ``DistributedQuery`` stages onto
+    the mesh it is built with, and nothing can be put on a described
+    device: build on four CPU devices, then re-jit the same body over the
+    described mesh and lower it with shapes."""
+    from trino_tpu import Session
+    from trino_tpu.exec.query import plan_sql
+    from trino_tpu.parallel.spmd import AXIS, DistributedQuery
+    from trino_tpu.sql.planner import stats
+
+    session = Session()
+    saved = (stats.GATHER_AGG_MAX_ROWS_PER_DEVICE, stats.BROADCAST_BUILD_MAX)
+    try:
+        # low thresholds force the hash-partitioned plan; they are read
+        # again while the body is traced, so they stay low through lower()
+        stats.GATHER_AGG_MAX_ROWS_PER_DEVICE = 8
+        stats.BROADCAST_BUILD_MAX = 8
+        dq = DistributedQuery.build(
+            session, plan_sql(session, BENCH_SQL["q3"]),
+            Mesh(np.array(jax.devices()[:4]), (AXIS,)))
+        assert any(k.startswith("xchg") for k in dq.capacity_hints)
+        chip_mesh = Mesh(np.array(topo.devices), (AXIS,))
+        dq.mesh = chip_mesh
+        dq._jit()
+        sharded = NamedSharding(chip_mesh, PartitionSpec(AXIS))
+        shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharded)
+                  for a in dq.inputs]
+        assert "all-to-all" in dq.fn.lower(shapes).compile().as_text()
+    finally:
+        stats.GATHER_AGG_MAX_ROWS_PER_DEVICE, stats.BROADCAST_BUILD_MAX = saved

@@ -331,3 +331,16 @@ def test_pallas_merge_null_slot_sentinel_edge():
         use_pallas=True, pallas_block_build=256, pallas_interpret=True)
     assert list(np.asarray(matched)) == [True, True, False, True]
     assert list(np.asarray(rows)[np.asarray(matched)]) == [2, 4, 5]
+
+
+def test_fused_join_pallas_off_tpu_is_a_typed_error(monkeypatch):
+    """``fused_join_pallas`` means the COMPILED kernel: a join that
+    reaches it on a backend that cannot compile it (the CPU here) fails
+    typed — it neither interprets nor quietly takes the XLA merge."""
+    from trino_tpu.exec.executor import QueryError
+
+    ex = Executor(Session(properties={"fused_join_pallas": True}))
+    monkeypatch.setattr(ex, "_merge_sentinel_safe", lambda *a: True)
+    with pytest.raises(QueryError) as err:
+        ex._merge_sorted_tier(None, None, None, None, [], [])
+    assert err.value.code == "PALLAS_MERGE_BACKEND"

@@ -571,3 +571,51 @@ def test_staging_bench_check():
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=480)
     assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+
+
+# ------------------------------------------------------- split row buckets
+@pytest.mark.parametrize("rows", [0, 1, 7, 100, 4096, 31869, 500830, 5996577])
+def test_row_bucket_pads_at_most_an_eighth(rows):
+    from trino_tpu.exec import staging
+
+    bucket = staging.row_bucket(rows)
+    assert rows <= bucket <= rows + rows // 8 + 1
+    assert staging.row_bucket(bucket) == bucket  # a bucket is its own bucket
+
+
+def test_row_bucket_gives_sibling_splits_one_shape():
+    """tpch.sf1 lineitem reaches a worker as 12 splits of these lengths
+    (chip run, PR 25): one shape, so one compile per operator, not 12."""
+    from trino_tpu.exec import staging
+
+    lengths = [500830, 499841, 499146, 499559, 499978, 499908, 500392,
+               499034, 499173, 499178, 500385, 499160]
+    assert {staging.row_bucket(n) for n in lengths} == {524288}
+
+
+def test_bucketed_page_is_the_exact_page_plus_a_dead_tail():
+    import jax.numpy as jnp
+
+    from trino_tpu.connector.spi import ColumnData
+    from trino_tpu.data.page import Page
+    from trino_tpu.exec import staging
+
+    n = 1000
+    rng = np.random.default_rng(3)
+    types = [T.BIGINT, T.BIGINT]
+    host = [
+        ColumnData(T.BIGINT, np.arange(n, dtype=np.int64), vrange=(0, n),
+                   sorted=True),
+        ColumnData(T.BIGINT, rng.integers(-1 << 40, 1 << 40, n),
+                   nulls=rng.random(n) < 0.1),
+    ]
+    exact = staging.page_from_host_columns(types, host, jnp.asarray)
+    padded_host, live = staging.pad_to_row_bucket(types, host)
+    padded = staging.page_from_host_columns(types, padded_host, jnp.asarray)
+    assert exact.sel is None and exact.num_rows == n == live
+    assert padded.num_rows == staging.row_bucket(n) > n
+    padded = Page(padded.columns, jnp.arange(padded.num_rows) < live,
+                  live_prefix=True)  # as staged_scan_page marks the tail
+    assert padded.live_count() == n
+    assert padded.columns[0].ascending  # dead rows are a tail
+    assert padded.compact().to_pylist() == exact.to_pylist()

@@ -210,10 +210,10 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "run the merge step of sorted-build joins as the Pallas tiled "
             "two-pointer merge kernel (ops/merge_pallas.py) when its "
             "contract holds (single int32 key, sentinel provably "
-            "unreachable); OPT-IN: unset/false keeps the XLA rank merge "
-            "(the kernel graduates to a default after a hardware bench "
-            "round validates it); true engages it — compiled on TPU, "
-            "interpret mode elsewhere (test meshes)",
+            "unreachable); OPT-IN: unset/false keeps the XLA rank merge; "
+            "true means the COMPILED kernel — TPU only: on any other "
+            "backend a query that reaches it fails with "
+            "PALLAS_MERGE_BACKEND (no interpreter, no silent XLA merge)",
             bool, None,
         ),
         PropertyMetadata(

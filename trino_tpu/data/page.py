@@ -15,6 +15,7 @@ TPU-first differences (SURVEY.md §7.1):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -293,51 +294,54 @@ def _from_repr(typ: T.Type, r):
     return int(r)
 
 
-def _concat_col(ca: Column, cb: Column) -> Column:
-    va, vb = ca.values, cb.values
-    if ca.type.is_nested:
+def _concat_cols(cols: Sequence[Column]) -> Column:
+    """Row-wise concatenation of one channel across pages — ONE
+    ``jnp.concatenate`` per array however many pages there are (a pairwise
+    chain is a new shape, so in the eager tier a new XLA program, for every
+    page: 34 exchange chunks of one q18 input made 371 of them on the
+    v5e, PR 25)."""
+    first = cols[0]
+    if first.type.is_nested:
         # lengths concatenate; children are flat, so their rows concatenate
         # too (offsets re-derive from the combined lengths, which by the
         # offsets() invariant describe the flat layout even for null rows).
-        kids = [_concat_col(ka, kb) for ka, kb in zip(ca.children, cb.children)]
-        vals = jnp.concatenate([va, vb])
-        nulls = None
-        if ca.nulls is not None or cb.nulls is not None:
-            na = ca.nulls if ca.nulls is not None else jnp.zeros((len(ca),), bool)
-            nb = cb.nulls if cb.nulls is not None else jnp.zeros((len(cb),), bool)
-            nulls = jnp.concatenate([na, nb])
-        return Column(ca.type, vals, nulls, None, children=kids)
-    if va.dtype != vb.dtype:  # mixed physical widths: promote
-        dt = jnp.promote_types(va.dtype, vb.dtype)
-        va, vb = va.astype(dt), vb.astype(dt)
-    d = ca.dictionary
-    if ca.dictionary is not None and cb.dictionary is not None:
-        if ca.dictionary is not cb.dictionary and ca.dictionary.values != cb.dictionary.values:
-            d = ca.dictionary.merge(cb.dictionary)
+        kids = [_concat_cols(ks) for ks in zip(*(c.children for c in cols))]
+        return Column(first.type, jnp.concatenate([c.values for c in cols]),
+                      _concat_nulls(cols), None, children=kids)
+    dt = functools.reduce(jnp.promote_types, (c.values.dtype for c in cols))
+    vals = [c.values.astype(dt) for c in cols]  # mixed widths: promote
+    d = first.dictionary
+    dicts = [c.dictionary for c in cols]
+    if all(x is not None for x in dicts) and any(
+            x is not d and x.values != d.values for x in dicts[1:]):
+        d = Dictionary(sorted(set().union(*(x.values for x in dicts))))
 
-            def recode(src_dict):
-                t = np.asarray(src_dict.recode_table(d))
-                # an all-NULL side has an empty vocab: pad so the gather
-                # below stays in range (its codes are all NULL_CODE anyway)
-                return jnp.asarray(t if len(t) else np.array([NULL_CODE], np.int32))
+        def recode(v, src_dict):
+            t = np.asarray(src_dict.recode_table(d))
+            # an all-NULL side has an empty vocab: pad so the gather
+            # below stays in range (its codes are all NULL_CODE anyway)
+            t = jnp.asarray(t if len(t) else np.array([NULL_CODE], np.int32))
+            return jnp.where(v >= 0, t[jnp.clip(v, 0)], NULL_CODE)
 
-            va = jnp.where(va >= 0, recode(ca.dictionary)[jnp.clip(va, 0)], NULL_CODE)
-            vb = jnp.where(vb >= 0, recode(cb.dictionary)[jnp.clip(vb, 0)], NULL_CODE)
-    vals = jnp.concatenate([va, vb])
-    if ca.nulls is None and cb.nulls is None:
-        nulls = None
-    else:
-        na = ca.nulls if ca.nulls is not None else jnp.zeros((len(ca),), bool)
-        nb = cb.nulls if cb.nulls is not None else jnp.zeros((len(cb),), bool)
-        nulls = jnp.concatenate([na, nb])
+        vals = [recode(v, x) for v, x in zip(vals, dicts)]
     hi = None
-    if ca.hi is not None or cb.hi is not None:
+    if any(c.hi is not None for c in cols):
         # a missing hi limb is the sign extension of the low word
-        ha = ca.hi if ca.hi is not None else (va.astype(jnp.int64) >> 63)
-        hb = cb.hi if cb.hi is not None else (vb.astype(jnp.int64) >> 63)
-        hi = jnp.concatenate([ha, hb])
-    vr = None if hi is not None else merge_vrange(ca.vrange, cb.vrange)
-    return Column(ca.type, vals, nulls, d, vr, hi=hi)
+        hi = jnp.concatenate([
+            c.hi if c.hi is not None else (v.astype(jnp.int64) >> 63)
+            for c, v in zip(cols, vals)])
+    vr = None if hi is not None else functools.reduce(
+        merge_vrange, (c.vrange for c in cols))
+    return Column(first.type, jnp.concatenate(vals), _concat_nulls(cols), d,
+                  vr, hi=hi)
+
+
+def _concat_nulls(cols: Sequence[Column]):
+    if all(c.nulls is None for c in cols):
+        return None
+    return jnp.concatenate([
+        c.nulls if c.nulls is not None else jnp.zeros((len(c),), bool)
+        for c in cols])
 
 
 def host_take(c: Column, idx: np.ndarray, device: bool = True) -> Column:
@@ -419,13 +423,21 @@ class Page:
         return cls([Column.from_python(t, data[name]) for name, t in schema.items()])
 
     @staticmethod
+    def concat_all(pages: Sequence["Page"]) -> "Page":
+        """Row-wise concatenation (static shapes: the sum of the pages').
+        Dictionaries are merged host-side with device recode gathers when
+        they differ."""
+        if len(pages) == 1:
+            return pages[0]
+        cols = [_concat_cols(cs) for cs in zip(*(p.columns for p in pages))]
+        sel = jnp.concatenate([
+            p.sel if p.sel is not None else jnp.ones((p.num_rows,), bool)
+            for p in pages])
+        return Page(cols, sel, all(p.replicated for p in pages))
+
+    @staticmethod
     def concat_pages(a: "Page", b: "Page") -> "Page":
-        """Row-wise concatenation (static shapes: n_a + n_b). Dictionaries are
-        merged host-side with device recode gathers when they differ."""
-        cols = [_concat_col(ca, cb) for ca, cb in zip(a.columns, b.columns)]
-        sa = a.sel if a.sel is not None else jnp.ones((a.num_rows,), bool)
-        sb = b.sel if b.sel is not None else jnp.ones((b.num_rows,), bool)
-        return Page(cols, jnp.concatenate([sa, sb]), a.replicated and b.replicated)
+        return Page.concat_all([a, b])
 
     @staticmethod
     def all_dead(types: Sequence[T.Type]) -> "Page":

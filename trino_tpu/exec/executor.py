@@ -29,12 +29,14 @@ from trino_tpu.data.page import Column, Page
 from trino_tpu.exec import memory as _mem
 from trino_tpu.exec.operator_stats import OperatorStats
 from trino_tpu.obs import metrics as M
+from trino_tpu.obs.devprofiler import merge_platforms
 from trino_tpu.ops import aggregate as agg_ops
 from trino_tpu.ops import expr_lower as L
 from trino_tpu.ops import fused_join as fused_ops
 from trino_tpu.ops import groupby as gb
 from trino_tpu.ops import join as join_ops
 from trino_tpu.ops import ranks as ranks_ops
+from trino_tpu.ops import scans
 from trino_tpu.ops import segments as seg
 from trino_tpu.ops import sort as sort_ops
 from trino_tpu.sql import ir
@@ -70,6 +72,17 @@ def _col_from_lowered(t: T.Type, lv: L.LoweredVal) -> Column:
     # narrowing) keep their fast paths for projected expressions
     vrange = (-lv.bound, lv.bound) if lv.bound is not None and lv.hi is None else None
     return Column(t, lv.vals, nulls, lv.dictionary, vrange, hi=lv.hi)
+
+
+def _page_platform(page: Page) -> str:
+    """The device platform holding ``page``'s first column (``"host"`` for
+    a numpy array): the kernel ledger's proof of where a launch ran."""
+    if not page.columns:
+        return ""
+    values = page.columns[0].values
+    if not isinstance(values, jax.Array):
+        return "host"
+    return next(iter(values.devices())).platform
 
 
 def _col_to_lowered(c: Column) -> join_ops.Lowered:
@@ -352,8 +365,9 @@ class Executor:
                 "planNodeId": str(node.id), "operator": st.operator,
                 "tier": "eager", "launches": 0, "wallS": 0.0,
                 "deviceS": 0.0, "inputBytes": 0, "outputBytes": 0,
-                "estimated": estimated}
+                "estimated": estimated, "platform": ""}
         ks["launches"] += 1
+        ks["platform"] = merge_platforms(ks["platform"], _page_platform(page))
         ks["wallS"] += kwall
         ks["deviceS"] += device_s
         ks["inputBytes"] += in_bytes
@@ -493,11 +507,7 @@ class Executor:
     def _exec_UnionNode(self, node: P.UnionNode) -> Page:
         """UNION ALL: row-wise page concatenation (static shapes: total =
         sum of branch capacities; dead rows stay dead)."""
-        pages = [self.execute(s) for s in node.sources_]
-        out = pages[0]
-        for p in pages[1:]:
-            out = Page.concat_pages(out, p)
-        return out
+        return Page.concat_all([self.execute(s) for s in node.sources_])
 
     def _exec_SetOpNode(self, node: P.SetOpNode) -> Page:
         left = self.execute(node.left)
@@ -566,7 +576,6 @@ class Executor:
         Original row order is kept (stable). Overflow raises
         CAPACITY_EXCEEDED:<key> for the recompile-growth loop. Shared by
         CompactNode and the device-side dynamic-filter scans."""
-        from trino_tpu.ops import ranks as ranks_ops
 
         n = page.num_rows
         if page.sel is None or capacity >= n:
@@ -579,9 +588,7 @@ class Executor:
         live = page.sel
         total = jnp.sum(live.astype(jnp.int32))
         self.errors.append((f"CAPACITY_EXCEEDED:{key}", total > capacity))
-        _, order = jax.lax.sort(
-            (~live, jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True
-        )
+        order = ranks_ops.argsort32(~live)
         idx = order[:capacity]
         arrays = []
         for c in page.columns:
@@ -1033,7 +1040,7 @@ class Executor:
             neq = vals[1:] != vals[:-1]
             boundary = jnp.concatenate(
                 [jnp.ones((1,), bool), neq | (dead[1:] != dead[:-1])])
-            gid_sorted = (jnp.cumsum(boundary.astype(jnp.int32)) - 1).astype(jnp.int32)
+            gid_sorted = scans.cumsum(boundary.astype(jnp.int32)) - 1
             num_groups = jnp.sum(boundary & ~dead)
             layout = seg.sorted_layout(
                 jnp.arange(n, dtype=jnp.int32), gid_sorted, num_groups)
@@ -1267,10 +1274,7 @@ class Executor:
                 flat, flat_valid, flat_hi = vals_l, valid_l, hi_l
                 count = jnp.int32(n)
             else:
-                order = jax.lax.sort(
-                    (~sel_l, jnp.arange(n, dtype=jnp.int32)), num_keys=1,
-                    is_stable=True,
-                )[1]
+                order = ranks_ops.argsort32(~sel_l)
                 flat = vals_l[order]
                 flat_valid = valid_l[order] if valid_l is not None else None
                 flat_hi = hi_l[order] if hi_l is not None else None
@@ -1923,24 +1927,13 @@ class Executor:
         props = getattr(self.session, "properties", None) or {}
         return bool(props.get("fused_join_enabled", True))
 
-    def _pallas_merge_mode(self) -> Optional[bool]:
-        """None = don't use the Pallas merge kernel; False = compiled mode
-        (real TPU); True = interpret mode (CPU test meshes). The kernel is
-        OPT-IN (property explicitly true): unset keeps the XLA rank merge
-        until a hardware bench round validates the Mosaic compile —
-        microbench/join_kernels.py carries the kernel case on TPU."""
+    def _pallas_merge_requested(self) -> bool:
+        """``fused_join_pallas`` asks for the COMPILED Pallas merge kernel
+        (ops/merge_pallas.py). OPT-IN: unset keeps the XLA rank merge.
+        Interpret mode is not reachable from here — tests that want it
+        pass ``interpret=True`` to the ops themselves."""
         props = getattr(self.session, "properties", None) or {}
-        v = props.get("fused_join_pallas")
-        if not v:
-            return None
-        from trino_tpu.ops import merge_pallas
-
-        if not merge_pallas.pallas_available():
-            return None  # no pallas on this jax install: XLA fallback
-        try:
-            return jax.default_backend() != "tpu"
-        except Exception:  # noqa: BLE001 — no backend yet
-            return True
+        return bool(props.get("fused_join_pallas"))
 
     def _merge_sentinel_safe(self, node: P.JoinNode, left: Page, right: Page,
                              build_keys) -> bool:
@@ -2001,10 +1994,17 @@ class Executor:
         rank merge otherwise. ``record=False`` skips the selection metric
         (the overlapped exchange calls this once per send block but the
         selection is one join)."""
-        pallas_interp = self._pallas_merge_mode()
-        use_pallas = (pallas_interp is not None
+        use_pallas = (self._pallas_merge_requested()
                       and self._merge_sentinel_safe(node, left, right,
                                                     build_keys))
+        if use_pallas and jax.default_backend() != "tpu":
+            # the kernel is Mosaic-compiled, TPU only; the property says
+            # "the kernel", so this backend fails the query rather than
+            # interpreting it (or quietly taking the XLA merge)
+            raise QueryError(
+                "fused_join_pallas=true needs the TPU backend: the Pallas "
+                "merge kernel does not compile for "
+                f"{jax.default_backend()!r}", code="PALLAS_MERGE_BACKEND")
         if record:
             M.FUSED_JOIN_SELECTIONS.inc(
                 1, "merge-pallas" if use_pallas else "merge-sorted")
@@ -2013,7 +2013,6 @@ class Executor:
             use_pallas=use_pallas,
             pallas_block_build=self.capacity_hints.get(
                 f"jtile:{node.id}", 2048),
-            pallas_interpret=bool(pallas_interp),
         )
 
     def _sortmerge_probe(self, node: P.JoinNode, left: Page, right: Page):

@@ -55,6 +55,14 @@ MAX_QUERY_PROFILES = 64
 TIERS = ("eager", "compiled", "spmd")
 
 
+def merge_platforms(a: str, b: str) -> str:
+    """Where a kernel row's launches left their output: the ``+``-joined
+    set of device platforms (``"host"`` for a numpy array) — anything
+    but the accelerator's name alone means some launch computed
+    elsewhere."""
+    return "+".join(sorted(set(filter(None, a.split("+") + b.split("+")))))
+
+
 def merge_kernel_rows(dst: Dict[tuple, dict],
                       rows: List[dict]) -> Dict[tuple, dict]:
     """Fold serialized kernel rows (``kernel_rows`` wire shape) into a
@@ -67,7 +75,7 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
             agg = {"planNodeId": key[0], "operator": key[1],
                    "tier": key[2], "nodeId": key[3], "launches": 0,
                    "wallS": 0.0, "deviceS": 0.0, "inputBytes": 0,
-                   "outputBytes": 0, "estimated": False}
+                   "outputBytes": 0, "estimated": False, "platform": ""}
             dst[key] = agg
         agg["launches"] += int(row.get("launches", 0))
         agg["wallS"] += float(row.get("wallS", 0.0))
@@ -75,6 +83,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
         agg["inputBytes"] += int(row.get("inputBytes", 0))
         agg["outputBytes"] += int(row.get("outputBytes", 0))
         agg["estimated"] = bool(agg["estimated"] or row.get("estimated"))
+        agg["platform"] = merge_platforms(agg["platform"],
+                                          row.get("platform", ""))
     return dst
 
 

@@ -21,12 +21,14 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
+from trino_tpu.ops import scans
+
 
 def offsets_from_lengths(lengths: jnp.ndarray) -> jnp.ndarray:
     """int32[n+1] exclusive prefix sum of per-row element counts."""
     lens = lengths.astype(jnp.int32)
     return jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(lens, dtype=jnp.int32)]
+        [jnp.zeros((1,), jnp.int32), scans.cumsum(lens, dtype=jnp.int32)]
     )
 
 def rowid_of_flat(offsets: jnp.ndarray, flat_n: int) -> jnp.ndarray:
@@ -44,7 +46,7 @@ def segment_reduce_by_range(
     int64 so narrow element dtypes can't wrap."""
     if jnp.issubdtype(flat_vals.dtype, jnp.integer) or flat_vals.dtype == jnp.bool_:
         flat_vals = flat_vals.astype(jnp.int64)
-    c = jnp.cumsum(flat_vals)
+    c = scans.cumsum(flat_vals)
     c0 = jnp.concatenate([jnp.zeros((1,), c.dtype), c])
     return c0[offsets[1:]] - c0[offsets[:-1]]
 
@@ -81,22 +83,17 @@ def first_match_index(
     # reverse cummin; then per row read the value at the row's start.
     big = jnp.int32(flat_n)
     cand = jnp.where(match, pos, big)
-    suffix_min = jax_lax_cummin_reverse(cand)
+    suffix_min = scans.cummin(cand, reverse=True)
     starts = offsets[:-1]
     first = suffix_min[jnp.clip(starts, 0, flat_n - 1)]
     lens = offsets[1:] - starts
     hit = (first < offsets[1:]) & (lens > 0)
     return jnp.where(hit, first - starts + 1, 0)
 
-def jax_lax_cummin_reverse(x: jnp.ndarray) -> jnp.ndarray:
-    import jax
-
-    return jax.lax.cummin(x, reverse=True)
-
 def count_in_ranges(
     offsets: jnp.ndarray, flags: jnp.ndarray
 ) -> jnp.ndarray:
     """int32[n]: per-row count of True flat flags."""
-    c = jnp.cumsum(flags.astype(jnp.int32))
+    c = scans.cumsum(flags.astype(jnp.int32))
     c0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), c])
     return c0[offsets[1:]] - c0[offsets[:-1]]

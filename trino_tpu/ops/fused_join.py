@@ -53,6 +53,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.ops import ranks, scans
+
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]
 
 
@@ -94,15 +96,11 @@ def fused_match_rows(
         dt = jnp.promote_types(bv.dtype, pv.dtype)
         operands.append(jnp.concatenate([bv.astype(dt), pv.astype(dt)]))
     idx = jnp.arange(n, dtype=jnp.int32)
-    # liveness rides the sort as a payload operand (streaming bytes) — a
-    # post-sort live_b[idx_s] gather would re-touch N rows at the ~7 ns
-    # random-access floor, the exact cost this kernel exists to avoid
+    # liveness follows the sort's permutation with the keys and the index
     live_b = _build_live(build_keys, build_sel)
     live_concat = jnp.concatenate([live_b, jnp.ones((np_,), bool)])
-    out = jax.lax.sort(
-        tuple(operands) + (idx, live_concat),
-        num_keys=len(operands), is_stable=True,
-    )
+    out = ranks.stable_sort(tuple(operands) + (idx, live_concat),
+                            len(operands))
     sorted_cols, idx_s, live_s = out[:-2], out[-2], out[-1]
     is_build = idx_s < nb
     # equal-key run boundaries (any key column differs from the previous)
@@ -110,7 +108,7 @@ def fused_match_rows(
     for c in sorted_cols:
         neq = neq | (c[1:] != c[:-1])
     run_start = jnp.concatenate([jnp.ones((1,), bool), neq])
-    run_id = jnp.cumsum(run_start.astype(jnp.int32))
+    run_id = scans.cumsum(run_start.astype(jnp.int32))
     # candidate encoding at LIVE build slots only: dead/null builds never
     # match, so they need no masking anywhere upstream
     cand_live = is_build & live_s
@@ -118,7 +116,7 @@ def fused_match_rows(
     enc = run_id.astype(jnp.int64) * stride + jnp.where(
         cand_live, idx_s.astype(jnp.int64) + 1, jnp.int64(0)
     )
-    m = jax.lax.cummax(enc)
+    m = scans.cummax(enc)
     has_build = (m // stride) == run_id.astype(jnp.int64)
     brow_sorted = jnp.where(
         has_build & (m % stride > 0), (m % stride - 1).astype(jnp.int32),
@@ -190,7 +188,6 @@ def merge_sorted_build(
     same merge expressed as ranks over the combined sort (ops/ranks.py).
     """
     from trino_tpu.ops import join as join_ops
-    from trino_tpu.ops import ranks
 
     nb = build.n
     np_ = probe_keys[0][0].shape[0]

@@ -19,6 +19,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.ops import ranks, scans
+
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]  # (vals, valid|None)
 
 
@@ -30,14 +32,10 @@ def group_plan(
     Returns (order[n] int32, gid_sorted[n] int32 non-decreasing,
     num_groups scalar, sorted_payloads). Dead rows (sel false) sort last
     and receive group ids >= num_groups; NULL keys group together (their
-    own group). ``payloads`` ride the same fused sort as extra operands
-    and come back permuted into sorted (layout) space — the free way to
-    get aggregate arguments group-contiguous (see segments.seg_sum).
-
-    The sorted key columns come straight out of the one fused ``lax.sort``
-    (operands sort together) — re-gathering them by the permutation would
-    cost ~40 ms per 6M-row column of random HBM access on v5e, ~10x the
-    marginal cost of a sort operand."""
+    own group). ``payloads`` come back permuted into sorted (layout)
+    space with the keys — aggregate arguments group-contiguous (see
+    segments.seg_sum) — by ops/ranks.stable_sort: the keys' digits sort,
+    keys and payloads follow by one batched gather per dtype group."""
     n = keys[0][0].shape[0]
     dead = jnp.zeros((n,), dtype=bool) if sel is None else ~sel
     sort_keys: List[jnp.ndarray] = [dead]
@@ -49,16 +47,14 @@ def group_plan(
             sort_keys.append(vals)
     iota = jnp.arange(n, dtype=jnp.int32)
     nk = len(sort_keys)
-    out = jax.lax.sort(
-        tuple(sort_keys) + (iota,) + tuple(payloads), num_keys=nk, is_stable=True
-    )
+    out = ranks.stable_sort(tuple(sort_keys) + (iota,) + tuple(payloads), nk)
     gathered = out[:nk]
     order = out[nk]
     sorted_payloads = list(out[nk + 1:])
     boundary = jnp.zeros((n,), dtype=bool)
     for g in gathered:
         boundary = boundary | jnp.concatenate([jnp.ones((1,), bool), g[1:] != g[:-1]])
-    gid_sorted = (jnp.cumsum(boundary.astype(jnp.int32)) - 1).astype(jnp.int32)
+    gid_sorted = scans.cumsum(boundary.astype(jnp.int32)) - 1
     dead_sorted = gathered[0]
     num_groups = jnp.sum(boundary & ~dead_sorted)
     return order, gid_sorted, num_groups, sorted_payloads
@@ -68,8 +64,6 @@ def gather_group_keys(keys: List[Lowered], rep: jnp.ndarray) -> List[Lowered]:
     """Group-key output columns: gather each key at the representative row
     (rep indexes original row order; empty slots carry rep == n, clipped).
     One batched HBM pass for all keys (ranks.batched_gather)."""
-    from trino_tpu.ops import ranks
-
     n = keys[0][0].shape[0]
     safe = jnp.clip(rep, 0, n - 1)
     arrays = [vals for vals, _ in keys] + [

@@ -28,6 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from trino_tpu.ops import ranks as ranks_ops
+from trino_tpu.ops import scans
+
 from trino_tpu.ops import segments as seg
 
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]
@@ -139,10 +142,10 @@ def approx_percentile(
     if layout.is_direct:
         # direct layouts are tiny-capacity: sort by (gid, value) too
         gids = layout.gids
-        _, x_by_group = jax.lax.sort((gids, x), num_keys=2)
+        _, x_by_group = ranks_ops.stable_sort((gids, x), 2)
         starts, cnt = _direct_ranges(layout, m_l)
     else:
-        _, x_by_group = jax.lax.sort((layout.gid_sorted, x), num_keys=2)
+        _, x_by_group = ranks_ops.stable_sort((layout.gid_sorted, x), 2)
         starts = layout.starts
         cnt = seg.seg_count(layout, m_l)
     nn = x_by_group.shape[0]
@@ -171,10 +174,10 @@ def percentile_states(layout: seg.GroupLayout, vals_l, m_l):
         sentinel = jnp.asarray(jnp.iinfo(vals_l.dtype).max, vals_l.dtype)
     x = vals_l if m_l is None else jnp.where(m_l, vals_l, sentinel)
     if layout.is_direct:
-        _, x_by_group = jax.lax.sort((layout.gids, x), num_keys=2)
+        _, x_by_group = ranks_ops.stable_sort((layout.gids, x), 2)
         starts, cnt = _direct_ranges(layout, m_l)
     else:
-        _, x_by_group = jax.lax.sort((layout.gid_sorted, x), num_keys=2)
+        _, x_by_group = ranks_ops.stable_sort((layout.gid_sorted, x), 2)
         starts = layout.starts
         cnt = seg.seg_count(layout, m_l)
     nn = x_by_group.shape[0]
@@ -216,8 +219,8 @@ def percentile_merge(layout: seg.GroupLayout, samples, cnt_state, p: float):
     gid2 = jnp.repeat(gid_l, S)
     x2 = vals.reshape(-1)
     w2 = jnp.repeat(w_row, S)
-    _, x_s, w_s = jax.lax.sort((gid2, x2, w2), num_keys=2, is_stable=True)
-    c = jnp.cumsum(w_s)
+    _, x_s, w_s = ranks_ops.stable_sort((gid2, x2, w2), 2)
+    c = scans.cumsum(w_s)
     c0 = jnp.concatenate([jnp.zeros((1,), c.dtype), c])
     e_start = starts_l.astype(jnp.int64) * S
     e_end = ends_l.astype(jnp.int64) * S
@@ -236,7 +239,7 @@ def _direct_ranges(layout: seg.GroupLayout, m_l):
     per-slot counts (rows sort group-contiguous by gid)."""
     cnt_all = seg.seg_count(layout, None)  # rows per slot including masked
     starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int64), jnp.cumsum(cnt_all)[:-1]]
+        [jnp.zeros((1,), jnp.int64), scans.cumsum(cnt_all)[:-1]]
     ).astype(jnp.int32)
     cnt = seg.seg_count(layout, m_l)
     return starts, cnt

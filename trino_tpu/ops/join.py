@@ -28,6 +28,7 @@ from typing import List, Optional, Tuple
 import jax.numpy as jnp
 
 from trino_tpu.ops import ranks
+from trino_tpu.ops import scans
 
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]
 
@@ -138,17 +139,14 @@ def build_side(keys: List[Lowered], sel: Optional[jnp.ndarray],
             vals = vals.astype(jnp.int8)
         k = jnp.where(live, vals, _sentinel_max(vals.dtype))
         return SortedBuild([k], iota, live, True)
-    # sorted key columns and the permuted live flags come out of the ONE
-    # fused lax.sort (payload operands) — never re-gathered by the
-    # permutation (random gathers cost ~40 ms per 6M rows on v5e)
+    # sorted key columns and the permuted live flags come out of ONE
+    # ranks.stable_sort
     if len(keys) == 1:
         vals = keys[0][0]
         if vals.dtype == jnp.bool_:
             vals = vals.astype(jnp.int8)
         k = jnp.where(live, vals, _sentinel_max(vals.dtype))
-        k_s, live_s, order = jax.lax.sort(
-            (k, live, iota), num_keys=1, is_stable=True
-        )
+        k_s, live_s, order = ranks.stable_sort((k, live, iota), 1)
         return SortedBuild([k_s], order, live_s, True)
     dead = (~live).astype(jnp.int8)
     masked = [
@@ -157,9 +155,7 @@ def build_side(keys: List[Lowered], sel: Optional[jnp.ndarray],
         for v, _ in keys
     ]
     sort_keys = [dead] + masked
-    out = jax.lax.sort(
-        tuple(sort_keys) + (live, iota), num_keys=len(sort_keys), is_stable=True
-    )
+    out = ranks.stable_sort(tuple(sort_keys) + (live, iota), len(sort_keys))
     return SortedBuild(list(out[:-2]), out[-1], out[-2], False)
 
 
@@ -239,7 +235,7 @@ def expand(
     if n == 0:  # zero-row probe page: all output slots dead
         z = jnp.zeros((capacity,), jnp.int64)
         return z, z, jnp.zeros((capacity,), bool), jnp.zeros((), jnp.int64)
-    offsets = jnp.cumsum(c64)  # inclusive
+    offsets = scans.cumsum(c64)  # inclusive
     total = offsets[n - 1]
     starts = offsets - c64
     # search in int32 when capacity fits: offsets past 2^31 only occur when

@@ -18,7 +18,8 @@ expresses the merge directly:
 - the kernel walks the window in ``block_build``-sized chunks DMA'd
   HBM->VMEM double-buffered (chunk k+1 transfers while chunk k
   compares), accumulating per probe key its rank (count of smaller
-  build keys) and an equality flag with plain VPU compares.
+  build keys) and an equality flag with plain VPU compares against
+  lane-rotated build rows (see ``_kernel``).
 
 Output per probe slot: the matched build RANK (index into the sorted
 build), or -1 — exactly what the projection gather consumes.
@@ -36,62 +37,80 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-BLOCK_PROBE = 1024  # probe keys per grid step (8 sublanes x 128 lanes)
+LANES = 128
+PROBE_SUBLANES = 8
+BLOCK_PROBE = PROBE_SUBLANES * LANES  # probe keys per grid step: one (8, 128) tile
 _PAD = np.int32(np.iinfo(np.int32).max)
-
-
-def pallas_available() -> bool:
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-        return True
-    except Exception:  # noqa: BLE001 — no pallas on this backend/version
-        return False
+_I32 = jnp.int32
+_LANE_UNROLL = 8  # lane rotations per loop trip (explicit: int32 loop bounds are not static)
 
 
 def _kernel(wstart_ref, nwin_ref, probe_ref, build_hbm, out_ref,
             bwin, sem, *, block_build: int):
+    """One probe tile against its build window. Everything stays in the
+    (sublane, lane) layout it arrives in: a build row (128 keys on the
+    lanes) is broadcast over the probe tile's 8 sublanes and ROTATED along
+    the lanes 128 times, so every probe key meets every key of the row
+    with elementwise compares only — no lane<->sublane relayout, no
+    cross-lane reduction, no (probe x build) intermediate. Every literal
+    is an explicit int32: under ``jax_enable_x64`` a bare Python int
+    traces as i64, which Mosaic does not lower."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     g = pl.program_id(0)
     s0 = wstart_ref[g]
     nw = nwin_ref[g]
-    pk = probe_ref[0, :]  # (BLOCK_PROBE,) int32, sorted
-    sub = block_build // 128
+    pk = probe_ref[...]  # (8, 128) int32, sorted row-major
+    sub = block_build // LANES
 
     def window_dma(slot, w):
         return pltpu.make_async_copy(
-            build_hbm.at[pl.ds((s0 + w * block_build) // 128, sub), :],
+            build_hbm.at[pl.ds((s0 + w * _I32(block_build)) // _I32(LANES),
+                               sub), :],
             bwin.at[slot],
             sem.at[slot],
         )
 
-    @pl.when(nw > 0)
+    @pl.when(nw > _I32(0))
     def _():
-        window_dma(0, 0).start()
+        window_dma(_I32(0), _I32(0)).start()
 
-    def body(w, carry):
-        acc_lt, acc_eq = carry
-        slot = jax.lax.rem(w, jnp.int32(2))
+    def lane_steps(_, carry):
+        b, acc_lt, acc_eq = carry
+        for _unrolled in range(_LANE_UNROLL):
+            acc_lt = acc_lt + (b < pk).astype(_I32)
+            acc_eq = acc_eq | (b == pk).astype(_I32)
+            b = pltpu.roll(b, _I32(1), 1)
+        return b, acc_lt, acc_eq
 
-        @pl.when(w + 1 < nw)
+    def window_step(w, carry):
+        slot = jax.lax.rem(w, _I32(2))
+
+        @pl.when(w + _I32(1) < nw)
         def _():
-            window_dma(jax.lax.rem(w + 1, jnp.int32(2)), w + 1).start()
+            window_dma(jax.lax.rem(w + _I32(1), _I32(2)), w + _I32(1)).start()
 
         window_dma(slot, w).wait()
-        bw = bwin[slot].reshape(1, block_build)  # sorted chunk
-        pkc = pk[:, None]  # (BLOCK_PROBE, 1)
-        acc_lt = acc_lt + jnp.sum(bw < pkc, axis=1, dtype=jnp.int32)
-        acc_eq = acc_eq | jnp.any(bw == pkc, axis=1)
-        return acc_lt, acc_eq
 
-    zero = jnp.zeros((pk.shape[0],), jnp.int32)
-    acc_lt, acc_eq = jax.lax.fori_loop(
-        0, nw, body, (zero, jnp.zeros((pk.shape[0],), bool))
-    )
-    out_ref[0, :] = jnp.where(acc_eq, s0 + acc_lt, jnp.int32(-1))
+        def row_step(r, carry):
+            acc_lt, acc_eq = carry
+            row = bwin[slot, pl.ds(r, 1), :]  # (1, 128) sorted build keys
+            _, acc_lt, acc_eq = jax.lax.fori_loop(
+                _I32(0), _I32(LANES // _LANE_UNROLL), lane_steps,
+                (jnp.broadcast_to(row, pk.shape), acc_lt, acc_eq))
+            return acc_lt, acc_eq
+
+        return jax.lax.fori_loop(_I32(0), _I32(sub), row_step, carry)
+
+    zero = jnp.zeros(pk.shape, _I32)
+    acc_lt, acc_eq = jax.lax.fori_loop(_I32(0), nw, window_step, (zero, zero))
+    out_ref[...] = jnp.where(acc_eq > _I32(0), s0 + acc_lt, _I32(-1))
+
+
+def _tile_index(i, *_prefetch):
+    # int32 on purpose: a bare 0 is i64 under jax_enable_x64
+    return i, jnp.int32(0)
 
 
 @functools.partial(
@@ -124,6 +143,8 @@ def merge_unique_sorted(
         probe_sorted,
         jnp.broadcast_to(probe_sorted[-1:], (g * BLOCK_PROBE - np_,)),
     ]).reshape(g, BLOCK_PROBE)
+    # one probe block = one (8, 128) tile of the kernel's probe operand
+    probe_tiles = probe_pad.reshape(g * PROBE_SUBLANES, LANES)
     # pad build with the sentinel so every window DMA stays in bounds:
     # window starts align DOWN to 128 and run a whole number of
     # block_build chunks past the covering range
@@ -147,19 +168,19 @@ def merge_unique_sorted(
         num_scalar_prefetch=2,
         grid=(g,),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_PROBE), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # build stays in HBM
+            pl.BlockSpec((PROBE_SUBLANES, LANES), _tile_index),
+            pl.BlockSpec(memory_space=pl.ANY),  # build stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_PROBE), lambda i, *_: (i, 0)),
+        out_specs=pl.BlockSpec((PROBE_SUBLANES, LANES), _tile_index),
         scratch_shapes=[
-            pltpu.VMEM((2, block_build // 128, 128), jnp.int32),
+            pltpu.VMEM((2, block_build // LANES, LANES), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, block_build=block_build),
-        out_shape=jax.ShapeDtypeStruct((g, BLOCK_PROBE), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((g * PROBE_SUBLANES, LANES), jnp.int32),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(wstart, nwin, probe_pad, build_pad.reshape(nb_pad // 128, 128))
+    )(wstart, nwin, probe_tiles, build_pad.reshape(nb_pad // LANES, LANES))
     return out.reshape(-1)[:np_]

@@ -7,9 +7,8 @@ per-query binary search: ``jnp.searchsorted`` lowers to ~log2(n) dependent
 random-gather passes over the whole query vector (measured 2.5 s for 6M
 int64 probes into 1.5M keys on v5e — the round-1 engine's dominant cost).
 
-Instead, ranks are computed by ONE combined stable sort (lax.sort is a fast
-TPU radix/merge network: 6M int64 keys ≈ 27 ms) of build keys and query keys
-tagged 0/1, followed by streaming prefix ops:
+Instead, ranks are computed by ONE combined stable sort of build keys and
+query keys (builds first), followed by streaming prefix ops:
 
 - at a query slot, every build key <= it sorts before it (builds win ties),
   so the inclusive build-count prefix IS the query's right rank
@@ -28,71 +27,165 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.ops import scans
+
 
 def _iota32(n: int) -> jnp.ndarray:
     return jnp.arange(n, dtype=jnp.int32)
 
 
-def argsort32(vals: jnp.ndarray) -> jnp.ndarray:
-    """Stable argsort returning int32 indices. Under x64, jnp.argsort carries
-    int64 iota through the sort and produces int64 indices — int64 payloads
-    slow the sort and every downstream gather runs 3.7x slower on v5e."""
-    n = vals.shape[0]
-    _, perm = jax.lax.sort((vals, _iota32(n)), num_keys=1, is_stable=True)
-    return perm
+# ------------------------------------------------------------- the one sort
+# XLA's sort on the v5e compiles in time that grows steeply with the number
+# of operands and the width of the comparator, and hardly with the row
+# count above ~32 K (compiler runs, PR 25: stable (int32, int32) 22 s;
+# (int64, int32) 55 s; 3 x int32 46 s; one key + 8 payloads 136 s; the 15
+# operands of q3's ORDER BY ~660 s on the chip). So every sort of the
+# engine is this ONE program per row bucket — a stable (int32 digit, int32
+# row index) sort — run once per 32-bit digit of the packed keys, least
+# significant first; the operands then follow the permutation by gather.
+_SORT_BUCKET_MIN = 128
+
+
+def _sort_bucket(n: int) -> int:
+    """Rows the sort program is compiled for: the next power of two."""
+    return max(_SORT_BUCKET_MIN, 1 << (n - 1).bit_length())
+
+
+def _digitisable(dtype) -> bool:
+    """Key dtypes with an order-preserving 32-bit digit form the chip's
+    compiler accepts (not float64: it has no 64-bit bitcast there)."""
+    return (dtype == jnp.bool_ or dtype == jnp.float32
+            or jnp.issubdtype(dtype, jnp.integer))
+
+
+def _key_fields(key: jnp.ndarray):
+    """``key`` as [(uint32 field, bit width)], most significant first, whose
+    unsigned lexicographic order is ``lax.sort``'s order of ``key``."""
+    if key.dtype == jnp.bool_:
+        return [(key.astype(jnp.uint32), 1)]
+    if key.dtype == jnp.float32:
+        # lax.sort's float order: -0 == +0, NaNs last; past that, IEEE total
+        # order is the signed order of the sign-folded bit pattern
+        key = jnp.where(key == 0, jnp.float32(0), key)
+        key = jnp.where(jnp.isnan(key), jnp.float32(jnp.nan), key)
+        bits = jax.lax.bitcast_convert_type(key, jnp.int32)
+        key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    width = key.dtype.itemsize * 8
+    signed = jnp.issubdtype(key.dtype, jnp.signedinteger)
+    if width == 64:
+        hi = (key >> 32).astype(jnp.int32 if signed else jnp.uint32)
+        return _key_fields(hi) + [((key & 0xFFFFFFFF).astype(jnp.uint32), 32)]
+    if not signed:
+        return [(key.astype(jnp.uint32), width)]
+    # two's complement -> offset binary: flip the sign bit of the w-bit value
+    if width == 32:
+        biased = key ^ jnp.int32(-(1 << 31))
+    else:
+        biased = key.astype(jnp.int32) + jnp.int32(1 << (width - 1))
+    return [(jax.lax.bitcast_convert_type(biased, jnp.uint32), width)]
+
+
+@jax.jit
+def _digits(sort_keys) -> Tuple[jnp.ndarray, ...]:
+    """The keys as int32 digits, most significant first: consecutive fields
+    packed into as few 32-bit words as hold them, rows padded to
+    ``_sort_bucket`` with the largest digit (a stable sort then leaves the
+    pad rows, which start last, at the end)."""
+    words, acc, used = [], None, 0
+    for key in sort_keys:
+        for field, width in _key_fields(key):
+            if acc is not None and used + width > 32:
+                words.append(acc)
+                acc, used = None, 0
+            acc = field if acc is None else (acc << jnp.uint32(width)) | field
+            used += width
+    words.append(acc)
+    n = words[0].shape[0]
+    top = jnp.iinfo(jnp.int32).max
+    return tuple(
+        jnp.pad(jax.lax.bitcast_convert_type(w ^ jnp.uint32(1 << 31), jnp.int32),
+                (0, _sort_bucket(n) - n), constant_values=top)
+        for w in words)
+
+
+@jax.jit
+def _sort_pass(digit: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.sort((digit[perm], perm), num_keys=1, is_stable=True)[1]
 
 
 def lex_argsort32(sort_keys: List[jnp.ndarray]) -> jnp.ndarray:
-    """Stable lexicographic argsort (most significant first), int32 indices,
-    one fused multi-operand sort (no per-key argsort chain)."""
+    """Stable lexicographic argsort (most significant key first), int32
+    indices: one ``_sort_pass`` per digit, least significant first."""
     n = sort_keys[0].shape[0]
-    out = jax.lax.sort(
-        tuple(sort_keys) + (_iota32(n),), num_keys=len(sort_keys), is_stable=True
-    )
-    return out[-1]
+    if n <= 1:
+        return _iota32(n)
+    if not all(_digitisable(k.dtype) for k in sort_keys):
+        out = jax.lax.sort(tuple(sort_keys) + (_iota32(n),),
+                           num_keys=len(sort_keys), is_stable=True)
+        return out[-1]
+    digits = _digits(tuple(sort_keys))
+    perm = _iota32(digits[0].shape[0])
+    if len(digits) > 1 and isinstance(digits[0], jax.core.Tracer):
+        # inside a jitted body every sort instruction is compiled again:
+        # loop, so that all the passes share ONE
+        stacked = jnp.stack(digits[::-1])
+        perm = jax.lax.fori_loop(
+            0, len(digits), lambda i, p: _sort_pass(stacked[i], p), perm)
+    else:
+        for digit in digits[::-1]:
+            perm = _sort_pass(digit, perm)
+    return perm[:n]
+
+
+def argsort32(vals: jnp.ndarray) -> jnp.ndarray:
+    """Stable argsort returning int32 indices (int64 index payloads slow
+    every downstream gather 3.7x on v5e)."""
+    return lex_argsort32([vals])
+
+
+def stable_sort(operands, num_keys: int) -> List[jnp.ndarray]:
+    """``lax.sort(operands, num_keys=num_keys, is_stable=True)``: every
+    operand permuted by the stable lexicographic order of the first
+    ``num_keys`` (see the note above: key digits sort, operands gather)."""
+    operands = list(operands)
+    return batched_gather(operands, lex_argsort32(operands[:num_keys]))
+
+
+@jax.jit
+def _gather_all(arrays, idx):
+    groups: dict = {}
+    for i, a in enumerate(arrays):
+        groups.setdefault(a.dtype, []).append(i)
+    out: List = [None] * len(arrays)
+    for idxs in groups.values():
+        if len(idxs) == 1:
+            out[idxs[0]] = arrays[idxs[0]][idx]
+        else:
+            g = jnp.stack([arrays[i] for i in idxs], axis=1)[idx]
+            for j, i in enumerate(idxs):
+                out[i] = g[:, j]
+    return tuple(out)
 
 
 def batched_gather(arrays: List[jnp.ndarray], idx: jnp.ndarray) -> List[jnp.ndarray]:
     """Gather many same-length arrays at the same indices in ONE random-HBM
     pass per dtype group. Separate gathers do not fuse when the index is
     computed (each costs ~40 ms per 6M rows on v5e); a [n, k] row-gather
-    moves k columns for about the price of one."""
-    if len(arrays) <= 1:
-        return [a[idx] for a in arrays]
-    groups: dict = {}
-    for i, a in enumerate(arrays):
-        groups.setdefault(a.dtype, []).append(i)
-    out: List = [None] * len(arrays)
-    for _, idxs in groups.items():
-        if len(idxs) == 1:
-            i = idxs[0]
-            out[i] = arrays[i][idx]
-        else:
-            m = jnp.stack([arrays[i] for i in idxs], axis=1)
-            g = m[idx]
-            for j, i in enumerate(idxs):
-                out[i] = g[:, j]
-    return out
+    moves k columns for about the price of one. (One jitted program per
+    call: the eager tier would otherwise compile the stack, the gather and
+    every column slice apart.)"""
+    return list(_gather_all(tuple(arrays), idx))
 
 
 def apply_inverse(perm: jnp.ndarray, payloads: List[jnp.ndarray]) -> List[jnp.ndarray]:
     """Return each payload re-ordered so slot perm[i] moves to slot i —
-    i.e. payload[inverse_permutation(perm)] — via ONE payload-carrying sort
-    (sort by perm). Replaces an inverse-permutation sort plus one random
-    gather per payload."""
-    out = jax.lax.sort(
-        (perm.astype(jnp.int32),) + tuple(payloads), num_keys=1, is_stable=True
-    )
-    return list(out[1:])
+    i.e. payload[inverse_permutation(perm)] (sort by perm)."""
+    return stable_sort((perm.astype(jnp.int32),) + tuple(payloads), 1)[1:]
 
 
 def inverse_permutation(perm: jnp.ndarray) -> jnp.ndarray:
     """inv[perm[i]] = i, scatter-free (one int32 sort)."""
-    n = perm.shape[0]
-    _, inv = jax.lax.sort(
-        (perm.astype(jnp.int32), _iota32(n)), num_keys=1, is_stable=True
-    )
-    return inv
+    return argsort32(perm.astype(jnp.int32))
 
 
 def sorted_ranks(
@@ -119,13 +212,10 @@ def sorted_ranks(
         ])
         for b, q in zip(build_cols_sorted, query_cols)
     ]
-    out = jax.lax.sort(
-        tuple(operands) + (_iota32(n),), num_keys=len(operands), is_stable=True
-    )
-    sorted_cols = out[: len(operands)]
-    idx_s = out[-1]
+    idx_s = lex_argsort32(operands)
+    sorted_cols = batched_gather(operands, idx_s)
     is_build = (idx_s < nb).astype(jnp.int32)
-    prefix_incl = jnp.cumsum(is_build, dtype=jnp.int32)
+    prefix_incl = scans.cumsum(is_build, dtype=jnp.int32)
     prefix_excl = prefix_incl - is_build
     # equal-key run starts
     neq = jnp.zeros((max(n - 1, 0),), bool)
@@ -135,13 +225,11 @@ def sorted_ranks(
     # left rank for every slot of a run = build prefix at run start;
     # propagate by running max (prefixes are non-decreasing across runs)
     left_at_start = jnp.where(run_start, prefix_excl, jnp.int32(-1))
-    # lax.cummax, NOT associative_scan: the latter's unrolled log-depth graph
-    # does not compile at multi-million rows on v5e
-    left_all = jax.lax.cummax(left_at_start)
+    # a two-level cummax, NOT associative_scan: the latter's unrolled
+    # log-depth graph does not compile at multi-million rows on v5e
+    left_all = scans.cummax(left_at_start)
     right_all = prefix_incl  # at query slots: builds <= query
-    # back to query order (query i sits at combined index nb + i): ONE
-    # payload-carrying sort by idx_s, instead of inverse_permutation plus
-    # two random gathers (~40 ms each per 6M rows on v5e)
+    # back to query order (query i sits at combined index nb + i)
     left_o, right_o = apply_inverse(idx_s, [left_all, right_all])
     lo = left_o[nb:]
     counts = right_o[nb:] - lo

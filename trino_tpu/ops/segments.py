@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from trino_tpu.ops import ranks
+from trino_tpu.ops import scans
 
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]
 
@@ -109,7 +110,7 @@ def sorted_layout(
         [jnp.ones((1,), bool), gid_sorted[1:] != gid_sorted[:-1]]
     )
     nb = jnp.sum(boundary.astype(jnp.int32))
-    _, starts_seq = jax.lax.sort((~boundary, pos), num_keys=1, is_stable=True)
+    starts_seq = ranks.argsort32(~boundary)
     nn = jnp.int32(n)
     starts = jnp.where(pos < nb, starts_seq, nn)
     next_start = jnp.concatenate([starts_seq[1:], jnp.full((1,), nn, jnp.int32)])
@@ -140,7 +141,7 @@ def _cumsum_diff_ranges(
 ) -> jnp.ndarray:
     """Per-range sums of a segment-contiguous array via cumsum + boundary
     difference (exact for ints: wraparound cancels mod 2^64)."""
-    c = jnp.cumsum(x_sorted)
+    c = scans.cumsum(x_sorted)
     c0 = jnp.concatenate([jnp.zeros((1,), c.dtype), c])
     return c0[ends] - c0[starts]
 
@@ -215,7 +216,7 @@ def seg_minmax(
         return jnp.stack(
             [red(jnp.where(layout.gids == g, x, sentinel)) for g in range(layout.capacity)]
         )
-    _, x_by_group = jax.lax.sort((layout.gid_sorted, x), num_keys=2)
+    _, x_by_group = ranks.stable_sort((layout.gid_sorted, x), 2)
     n = layout.n
     pos = layout.starts if is_min else jnp.clip(layout.ends - 1, 0, n - 1)
     out = x_by_group[jnp.clip(pos, 0, n - 1)]

@@ -3,8 +3,8 @@
 Reference: ``operator/OrderByOperator.java`` + ``sql/gen/OrderingCompiler``
 (type-specialized comparators). Here: per-key transform to a sortable int64/
 float array (descending = negation, NULLs = rank-prefix keys per
-nulls_first), then ONE fused multi-operand stable ``lax.sort`` with an int32
-payload (ops/ranks.lex_argsort32). Dead rows (selection mask false) always
+nulls_first), then ONE stable lexicographic argsort
+(ops/ranks.lex_argsort32). Dead rows (selection mask false) always
 sort last so LIMIT/host slicing sees live rows first.
 """
 from __future__ import annotations
@@ -56,16 +56,11 @@ def sort_payloads(
     sel: Optional[jnp.ndarray],
     payloads: List[jnp.ndarray],
 ) -> List[jnp.ndarray]:
-    """Every payload array permuted into sort order (dead rows last) by ONE
-    payload-carrying ``lax.sort`` — computed-permutation gathers don't fuse
-    and cost ~40 ms per 6M-row column on v5e, ~10x a sort operand's
-    marginal cost."""
-    import jax
-
+    """Every payload array permuted into sort order (dead rows last): the
+    keys' argsort, then one batched gather per dtype group. (Payloads do
+    not ride the sort as operands: each one multiplies the v5e compiler's
+    time for the sort program — see ops/ranks.py.)"""
     sort_keys = _sort_operands(keys, sel)
     if not sort_keys:
         return list(payloads)
-    out = jax.lax.sort(
-        tuple(sort_keys) + tuple(payloads), num_keys=len(sort_keys), is_stable=True
-    )
-    return list(out[len(sort_keys):])
+    return ranks.batched_gather(list(payloads), ranks.lex_argsort32(sort_keys))

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from trino_tpu.ops import ranks
+from trino_tpu.ops import scans
 from trino_tpu.ops import sort as sort_ops
 
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]
@@ -87,10 +88,10 @@ def build_layout(
     pb = boundary(dead_cols + part_cols)
     peerb = pb | boundary(peer_cols) if peer_cols else pb
     idx = jnp.arange(n, dtype=jnp.int32)
-    part_start = jax.lax.cummax(jnp.where(pb, idx, jnp.int32(-1)))
-    peer_start = jax.lax.cummax(jnp.where(peerb, idx, jnp.int32(-1)))
-    part_id = jnp.cumsum(pb.astype(jnp.int32)) - 1
-    dense_peer = jnp.cumsum(peerb.astype(jnp.int32)) - 1
+    part_start = scans.cummax(jnp.where(pb, idx, jnp.int32(-1)))
+    peer_start = scans.cummax(jnp.where(peerb, idx, jnp.int32(-1)))
+    part_id = scans.cumsum(pb.astype(jnp.int32)) - 1
+    dense_peer = scans.cumsum(peerb.astype(jnp.int32)) - 1
     # ends via merge ranks over the dense non-decreasing ids
     ps, pc = ranks.sorted_ranks([part_id], [part_id])
     part_end = ps + pc
@@ -152,7 +153,7 @@ def agg_sum(layout: WindowLayout, arg: Lowered, frame: str, out_dtype,
     m = valid[layout.order] if valid is not None else None
     if m is not None:
         x = jnp.where(m, x, jnp.zeros((), out_dtype))
-    c = jnp.cumsum(x)
+    c = scans.cumsum(x)
     c0 = jnp.concatenate([jnp.zeros((1,), c.dtype), c])
     lo, hi = _frame_bounds(layout, frame, frame_lo, frame_hi)
     s = c0[hi] - c0[lo]
@@ -172,7 +173,7 @@ def agg_count(layout: WindowLayout, arg: Optional[Lowered], frame: str,
 def _count_in_frame(layout, m, lo, hi) -> jnp.ndarray:
     if m is None:
         return (hi - lo).astype(jnp.int64)
-    c = jnp.cumsum(m.astype(jnp.int64))
+    c = scans.cumsum(m.astype(jnp.int64))
     c0 = jnp.concatenate([jnp.zeros((1,), c.dtype), c])
     return c0[hi] - c0[lo]
 
@@ -190,7 +191,7 @@ def agg_minmax(layout: WindowLayout, arg: Lowered, frame: str, is_min: bool) -> 
         sentinel = info.max if is_min else info.min
     x = vals if valid is None else jnp.where(valid, vals, sentinel)
     xs = x[layout.order]
-    _, x_by = jax.lax.sort((layout.part_id, xs), num_keys=2)
+    _, x_by = ranks.stable_sort((layout.part_id, xs), 2)
     pos = layout.part_start if is_min else jnp.clip(layout.part_end - 1, 0, layout.n - 1)
     out = x_by[pos]
     m = valid[layout.order] if valid is not None else None

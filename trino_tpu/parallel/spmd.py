@@ -32,7 +32,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as PSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
 from trino_tpu import types as T
 from trino_tpu.connector import spi as spi_mod
@@ -42,23 +42,23 @@ from trino_tpu.exec.executor import Executor, QueryError, _col_to_lowered
 from trino_tpu.exec.page_tree import ColSpec, PageSpec, flatten_page, unflatten_page
 from trino_tpu.ops import aggregate as agg_ops
 from trino_tpu.ops import groupby as gb
+from trino_tpu.ops import ranks as ranks_ops
 from trino_tpu.sql.planner import plan as P
 
 AXIS = "d"
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map: ``jax.shard_map`` (0.5+, check_vma)
-    with the ``jax.experimental.shard_map`` (0.4.x, check_rep) fallback —
-    replication checking stays off either way (error flags are replicated
-    by construction, the checker can't see it)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
+    """``jax.shard_map`` with replication checking off (error flags are
+    replicated by construction, the checker can't see it)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+
+def _mesh_sharding(mesh: Mesh) -> NamedSharding:
+    """Leading (device) axis split over the mesh, the rest replicated —
+    the layout of every staged [ndev, rows, ...] scan array."""
+    return NamedSharding(mesh, PSpec(AXIS))
 
 
 def _gather_flat(x: jnp.ndarray) -> jnp.ndarray:
@@ -320,10 +320,7 @@ class SpmdExecutor(Executor):
         pages = [self.execute(s) for s in node.sources_]
         if len({p.replicated for p in pages}) > 1:
             pages = [gather_page(p) for p in pages]
-        out = pages[0]
-        for p in pages[1:]:
-            out = Page.concat_pages(out, p)
-        return out
+        return Page.concat_all(pages)
 
     def set_op_pages(self, node, left: Page, right: Page) -> Page:
         """Whole-row membership needs equal rows co-located: big inputs
@@ -408,9 +405,7 @@ class SpmdExecutor(Executor):
         ]
         t_ops = sort_ops._sort_operands(keys, None)  # ascending-comparable
         # local live-first key sort -> evenly spaced live samples
-        s_ops = jax.lax.sort(
-            tuple([~live] + t_ops), num_keys=1 + len(t_ops), is_stable=True
-        )[1:]
+        s_ops = ranks_ops.stable_sort([~live] + t_ops, 1 + len(t_ops))[1:]
         nlive = jnp.maximum(jnp.sum(live).astype(jnp.int32), 1)
         m = self.SORT_SAMPLES_PER_SHARD
         pos = jnp.clip(
@@ -418,7 +413,7 @@ class SpmdExecutor(Executor):
         )
         samples = [o[pos] for o in s_ops]
         gath = [jax.lax.all_gather(s, AXIS).reshape(-1) for s in samples]
-        gsorted = jax.lax.sort(tuple(gath), num_keys=len(gath), is_stable=True)
+        gsorted = ranks_ops.stable_sort(gath, len(gath))
         total = m * self.n_devices
         sp_pos = (jnp.arange(1, self.n_devices, dtype=jnp.int32) * total) // self.n_devices
         splitters = [g[sp_pos] for g in gsorted]
@@ -472,7 +467,7 @@ def _take_prefix(page: Page, k: int) -> Page:
 
 
 def stage_sharded_scans(session, root: P.OutputNode, n_devices: int,
-                        dyn_domains=None, profile=None):
+                        dyn_domains=None, profile=None, mesh=None):
     """Enumerate splits per scan, load per-device shards, pad to a common
     per-device shape, stack [ndev, rows]. This is the SOURCE_DISTRIBUTION
     split assignment done statically. ``dyn_domains`` carries phase-1
@@ -485,7 +480,11 @@ def stage_sharded_scans(session, root: P.OutputNode, n_devices: int,
     (trino_tpu/devcache/) first: a warm entry skips split enumeration,
     generation/IO, dynamic-domain pruning, AND the host->device transfer
     — the shard component of the key pins the mesh width, so a cache
-    built for one device count never serves another."""
+    built for one device count never serves another.
+
+    With ``mesh`` the stacked arrays go host -> device ALREADY SHARDED
+    along the mesh axis (shard i straight to device i); without it they
+    land whole on the default device (single-device callers and tests)."""
     from trino_tpu import devcache
     from trino_tpu.exec.executor import (
         dynamic_domain_map, scan_constraint_with)
@@ -512,9 +511,13 @@ def stage_sharded_scans(session, root: P.OutputNode, n_devices: int,
             # along the rows axis (exec/staging.blocked_transfer).
             t0 = _time.perf_counter()
             with _tracing.span("staging/transfer", table=node.table) as sp:
-                xfer = _staging.blocked_transfer()
-                arrays = [xfer(a) if isinstance(a, np.ndarray)
-                          else jnp.asarray(a) for a in arrays]
+                if mesh is not None:
+                    arrays = [jax.device_put(a, _mesh_sharding(mesh))
+                              for a in arrays]
+                else:
+                    xfer = _staging.blocked_transfer()
+                    arrays = [xfer(a) if isinstance(a, np.ndarray)
+                              else jnp.asarray(a) for a in arrays]
                 sp.set("arrays", len(arrays))
             _M.STAGING_PHASE_SECONDS.inc(_time.perf_counter() - t0,
                                          "transfer")
@@ -769,7 +772,7 @@ class DistributedQuery:
         phase1_s = _time.perf_counter() - t0
         prof: Dict[str, float] = {}
         staged_arrays, specs = stage_sharded_scans(
-            session, root, n_devices, dyn, profile=prof)
+            session, root, n_devices, dyn, profile=prof, mesh=mesh)
         if capacity_hints is None:
             capacity_hints = stats.estimate_capacity_hints(session, root)
             capacity_hints.update(stats.estimate_exchange_hints(session, root, n_devices))
@@ -786,8 +789,12 @@ class DistributedQuery:
             apply_reseed(session, root, pages, n_devices, capacity_hints)
         layout = [(nid, len(arrs)) for nid, arrs in staged_arrays.items()]
         flat_inputs: List = []
+        # a warm device-cache entry may have been staged for another mesh
+        # of this width (or for none): device_put is a no-op for arrays
+        # already sharded over THIS mesh and a reshard otherwise
         for _, arrs in staged_arrays.items():
-            flat_inputs.extend(jnp.asarray(a) for a in arrs)
+            flat_inputs.extend(
+                jax.device_put(a, _mesh_sharding(mesh)) for a in arrs)
         dq = cls(mesh, None, flat_inputs, [None], [None], session, root, dict(capacity_hints))
         dq.phase1_s = phase1_s
         dq.df_apply_s = prof.get("df_apply_s", 0.0)

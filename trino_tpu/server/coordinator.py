@@ -3415,6 +3415,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--port", type=int, default=0)
     args = ap.parse_args()
+    from trino_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     c = CoordinatorServer(args.port)
     c.start()
     print(json.dumps({"url": c.base_url}), flush=True)

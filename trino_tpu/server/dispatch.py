@@ -696,13 +696,13 @@ def executor_process_main(argv=None) -> None:
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--platforms", default="cpu")
     args = ap.parse_args(argv)
-    try:
-        import jax  # lint: allow(jnp-in-host-module) executor-process entry point: pins the child's platform to CPU BEFORE the engine imports (the accelerator stays with the dispatch-process device owner); never runs in the dispatch process
+    import jax  # lint: allow(jnp-in-host-module) executor-process entry point: pins the child's platform to CPU BEFORE the engine imports (the accelerator stays with the dispatch-process device owner); never runs in the dispatch process
 
-        jax.config.update("jax_platforms", args.platforms)
-    except Exception:  # noqa: BLE001 — platform pinning is best-effort
-        pass
+    jax.config.update("jax_platforms", args.platforms)
+    from trino_tpu.compile_cache import configure_compile_cache
     from trino_tpu.server.coordinator import CoordinatorServer
+
+    configure_compile_cache()
 
     server = CoordinatorServer(executor_lanes=args.lanes,
                                executor_plane="thread")

@@ -102,8 +102,12 @@ class FragmentExecutor(Executor):
             # semantics: the whole fresh scan+assemble+transfer wall
             # (device-cache hits never reach this loader).
             t0 = time.perf_counter()
+            # a task's scans stage at a bucketed length: the splits of one
+            # table differ by a few rows, and the split-at-a-time driver
+            # would compile every operator program again for each
             page, rows, _prof = staging.staged_scan_page(
-                self.session, node, conn, splits, constraint)
+                self.session, node, conn, splits, constraint,
+                bucket_rows=True)
             M.STAGED_ROWS.inc(rows)
             M.STAGING_SECONDS.inc(time.perf_counter() - t0)
             return page, rows, _mem.page_bytes(page), len(splits)
@@ -131,10 +135,7 @@ class FragmentExecutor(Executor):
         pages = [p for p in pages if p.num_rows > 0]
         if not pages:
             return Page.all_dead(node.types)
-        page = pages[0]
-        for p in pages[1:]:
-            page = Page.concat_pages(page, p)
-        return page
+        return Page.concat_all(pages)
 
 
 class SqlTask:
@@ -761,9 +762,7 @@ class SqlTask:
 
         def emit(batch: List[Page]) -> None:
             batch_rows = sum(p.num_rows for p in batch)
-            page = batch[0]
-            for p in batch[1:]:
-                page = Page.concat_pages(page, p)
+            page = Page.concat_all(batch)
             ex = FragmentExecutor(session, {}, {src.fragment_id: [page]})
             self._track_executor(ex)
             t0 = time.perf_counter()
@@ -802,11 +801,8 @@ class SqlTask:
 
             def fold(running, batch):
                 batch_rows = sum(p.num_rows for p in batch)
-                page = batch[0]
-                for p in batch[1:]:
-                    page = Page.concat_pages(page, p)
-                if running is not None:
-                    page = Page.concat_pages(running, page)
+                page = Page.concat_all(
+                    batch if running is None else [running] + batch)
                 ex = FragmentExecutor(session, {}, {})
                 self._track_executor(ex)
                 t0 = time.perf_counter()
@@ -827,7 +823,15 @@ class SqlTask:
                     batch.append(page)
                     batch_rows += page.num_rows
                     in_rows += page.num_rows
-                    if batch_rows >= self.STREAM_BATCH_ROWS:
+                    # fold once the batch has grown to the running state's
+                    # size: every fold re-groups the whole state, so a
+                    # fixed batch would re-sort a many-group state once per
+                    # batch (and, each fold being a new shape, compile its
+                    # programs again); doubling keeps folds logarithmic
+                    # and the task at twice the state
+                    if batch_rows >= max(
+                            self.STREAM_BATCH_ROWS,
+                            0 if running is None else running.num_rows):
                         running = fold(running, batch)
                         batch, batch_rows = [], 0
                 if batch:

@@ -526,6 +526,9 @@ def main() -> None:
     ap.add_argument("--coordinator", default=None)
     ap.add_argument("--node-id", default=None)
     args = ap.parse_args()
+    from trino_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     w = WorkerServer(args.port, args.coordinator, args.node_id)
     w.start()
     print(json.dumps({"nodeId": w.node_id, "url": w.base_url}), flush=True)
