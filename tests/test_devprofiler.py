@@ -289,3 +289,120 @@ def test_profile_check():
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=480)
     assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+
+
+# --------------------------------------- host_read and the charged kernel row
+def test_host_read_on_a_numpy_input_counts_nothing():
+    import numpy as np
+
+    from trino_tpu.obs.devprofiler import charge_to, host_read, new_kernel_row
+
+    row = new_kernel_row("1", "Filter", "eager")
+    arr = np.arange(5)
+    with charge_to(row):
+        assert host_read(arr, "compact") is arr
+        assert host_read(np.int64(3), "compact") == 3
+        assert host_read(True, "error-flags")
+    assert (row["hostSyncs"], row["hostSyncS"], row["d2hBytes"]) == (0, 0.0, 0)
+    assert row["hostSyncSites"] == {}
+
+
+def test_host_read_charges_the_innermost_row_and_its_site():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trino_tpu.obs import trace as tracing
+    from trino_tpu.obs.devprofiler import (
+        charge_to, host_read, new_kernel_row, sync_sites_of)
+
+    outer = new_kernel_row("1", "Aggregation", "eager")
+    inner = new_kernel_row("2", "Filter", "eager")
+    mask = jnp.arange(1000) % 2 == 0
+    counts = mask.astype(jnp.int32)   # compiled out here, not under the tracer
+    tracer = tracing.Tracer()
+    with tracing.activate(tracer), charge_to(outer):
+        with charge_to(inner):
+            got = host_read(mask, "operator-stats")
+            host_read(mask, "compact")
+        host_read(counts, "join-emit-count")
+    assert isinstance(got, np.ndarray) and int(got.sum()) == 500
+    assert inner["hostSyncs"] == 2 and inner["d2hBytes"] == 2000
+    assert outer["hostSyncs"] == 1 and outer["d2hBytes"] == 4000
+    assert inner["hostSyncSites"]["operator-stats"][0] == 1
+    assert set(outer["hostSyncSites"]) == {"join-emit-count"}
+    sites = sync_sites_of([outer, inner])
+    assert {s: v["count"] for s, v in sites.items()} == {
+        "operator-stats": 1, "compact": 1, "join-emit-count": 1}
+    assert sum(v["bytes"] for v in sites.values()) == 6000
+    # only reads of 50 us and more are stored as spans; all are counted
+    for sp in tracer.to_dicts():
+        assert sp["name"] == "host/sync"
+        assert sp["durationS"] >= tracing.MIN_STORED_SPAN_S
+    assert len(tracer.to_dicts()) <= 3
+    # outside any charged row a read is still done, and counted nowhere
+    assert int(host_read(mask, "compact").sum()) == 500
+
+
+def test_merge_kernel_rows_adds_the_sync_and_compile_fields():
+    a = dict(_row(), hostSyncs=2, hostSyncS=0.5, d2hBytes=10, compiles=1,
+             compileS=0.25, hostSyncSites={"compact": [2, 0.5, 10]})
+    b = dict(_row(), hostSyncs=1, hostSyncS=0.25, d2hBytes=6,
+             hostSyncSites={"compact": [1, 0.25, 6]})
+    old = _row()   # a row from before the fields existed
+    (row,) = merge_kernel_rows({}, [a, b, old]).values()
+    assert (row["hostSyncs"], row["d2hBytes"], row["compiles"]) == (3, 16, 1)
+    assert row["hostSyncS"] == pytest.approx(0.75)
+    assert row["compileS"] == pytest.approx(0.25)
+    assert row["hostSyncSites"] == {"compact": [3, 0.75, 16]}
+    assert a["hostSyncSites"] == {"compact": [2, 0.5, 10]}, "inputs untouched"
+
+
+def test_compile_listener_charges_the_executing_operator():
+    import jax
+    import jax.numpy as jnp
+
+    from trino_tpu.obs import trace as tracing
+    from trino_tpu.obs.devprofiler import (
+        charge_to, install_process_hooks, new_kernel_row)
+
+    install_process_hooks()
+    install_process_hooks()   # once per process, however often it is asked
+    row = new_kernel_row("9", "Project", "eager")
+    tracer = tracing.Tracer()
+    x = jnp.arange(7)
+    with tracing.activate(tracer), charge_to(row):
+        jax.jit(lambda x: x * 3 + 41)(x).block_until_ready()
+    assert row["compiles"] == 1 and row["compileS"] > 0
+    assert [s["name"] for s in tracer.to_dicts()] == ["xla/compile"]
+
+
+def test_operator_stats_read_is_charged_to_the_node_that_made_the_page(cluster):
+    """A filter-then-aggregate statement: the blocking read of the
+    selection mask that counts a page's live rows sits in the row (and
+    inside the clock) of the node that produced the page, not its parent's."""
+    coord, _ = cluster
+    client = StatementClient(coord.base_url, {
+        "catalog": "tpch", "schema": "tiny", "result_cache_enabled": "false"})
+    client.execute(
+        "select l_returnflag, count(*) from lineitem "
+        "where l_quantity < 10 group by l_returnflag")
+    prof = _profile(coord, client.query_id)
+    rows = [k for k in prof["kernels"] if k["launches"]]
+    filters = [k for k in rows if k["operator"] == "Filter"]
+    assert filters
+    for k in filters:
+        n, seconds, nbytes = k["hostSyncSites"]["operator-stats"]
+        assert n == k["launches"], "one read per execution, in its own row"
+        assert nbytes > 0 and k["hostSyncS"] >= seconds
+        # the wait is inside the node's own wall
+        assert k["wallS"] >= seconds
+    for k in rows:
+        n = k["hostSyncSites"].get("operator-stats", [0])[0]
+        assert n <= k["launches"], (k["operator"], "charged a child's read")
+    # the new columns ride SQL
+    _cols, table = client.execute(
+        "select operator, host_syncs, host_sync_seconds, d2h_bytes, compiles, "
+        "compile_seconds from system.runtime.kernels "
+        f"where query_id = '{client.query_id}'")
+    assert sum(r[1] for r in table) == sum(k["hostSyncs"] for k in prof["kernels"])
+    assert sum(r[3] for r in table) == sum(k["d2hBytes"] for k in prof["kernels"])

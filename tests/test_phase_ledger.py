@@ -245,3 +245,145 @@ def test_cli_summary_and_explain_analyze_render_ledger(cluster):
         "explain analyze select count(*) from region")
     text = "\n".join(r[0] for r in rows)
     assert "Phase ledger:" in text
+
+
+# ---------------------------------------------------- level two: the detail
+def test_nested_operator_spans_get_self_time():
+    """Nested spans of one kind give each instant to the innermost one: an
+    operator's share of ``detail`` is its self time, and the leaves it
+    contains (a blocking read, a staging stage) come off it."""
+    spans = [
+        _span("query", 0.0, 10.0, "r"),
+        _span("device/execute", 1.0, 8.0, "x"),
+        _span("operator/Aggregation", 1.5, 7.0, "a"),
+        _span("operator/Filter", 2.0, 4.0, "f"),        # inside a
+        _span("operator/TableScan", 2.5, 2.0, "s"),     # inside f
+        _span("device/staging", 2.6, 1.5, "st"),        # inside s
+        _span("staging/transfer", 3.0, 1.0, "tr"),      # inside st
+        _span("host/sync", 5.0, 0.5, "h"),              # inside f
+        _span("process/gc", 5.2, 0.1, "g"),             # inside h
+        _span("task/output", 9.2, 0.6, "o"),            # after the body
+    ]
+    d = compute_timeline(spans, 0.0, 10.5).to_dict()
+    detail = d["detail"]
+    want = {
+        "device-execute/op:Aggregation": 0.5 + 2.5,     # [1.5,2) + [6,8.5)
+        "device-execute/op:Filter": 0.5 + 0.5 + 0.5,    # [2,2.5) [4.5,5) [5.5,6)
+        "device-execute/op:TableScan": 0.1 + 0.4,       # [2.5,2.6) [4.1,4.5)
+        "device-execute/host-sync": 0.4,                # 0.5 less the pause
+        "device-execute/gc-pause": 0.1,
+        "device-execute/remainder": 0.5 + 0.5,          # [1,1.5) [8.5,9)
+        "device-staging/op:TableScan": 0.4 + 0.1,       # staging, no stage open
+        "device-staging/transfer": 1.0,
+        "dispatch/task-output": 0.6,
+        "dispatch/remainder": 1.0 + 0.2 + 0.2,
+        "unattributed/remainder": 0.5,
+    }
+    assert set(detail) == set(want)
+    for key, seconds in want.items():
+        assert detail[key] == pytest.approx(seconds, abs=1e-9), key
+    for phase, seconds in d["phases"].items():
+        assert sum(v for k, v in detail.items()
+                   if k.startswith(phase + "/")) == pytest.approx(
+            seconds, abs=1e-6), phase
+
+
+def test_level_one_is_the_same_with_and_without_detail_spans():
+    """``phases`` is swept from the same span names as before level two
+    existed: the detail-only spans (operators, blocking reads, the output
+    path, collector pauses, listener compiles) move no phase."""
+    level_one = [
+        _span("query", 10.1, 0.9, "r"),
+        _span("schedule", 10.2, 0.4, "sc"),
+        _span("device/staging", 10.3, 0.2, "st"),
+        _span("staging/scan", 10.3, 0.1, "ss"),
+        _span("device/execute", 10.5, 0.1, "de"),
+        _span("execute/root-fragment", 10.6, 0.35, "ex"),
+        _span("exchange/pull", 10.62, 0.1, "p1"),
+    ]
+    detail_only = [
+        _span("operator/Join", 10.5, 0.1, "o1"),
+        _span("operator/Output", 10.75, 0.1, "o2"),
+        _span("host/sync", 10.52, 0.03, "h"),
+        _span("task/output", 10.6, 0.05, "t"),
+        _span("process/gc", 10.05, 0.2, "g"),    # over queued and dispatch
+        _span("xla/compile", 10.26, 0.02, "c"),
+    ]
+    plain = compute_timeline(level_one, 10.0, 11.0).to_dict()
+    both = compute_timeline(level_one + detail_only, 10.0, 11.0).to_dict()
+    assert list(both["phases"]) == list(PHASES) and len(PHASES) == 14
+    assert both["phases"] == plain["phases"]
+    assert both["unattributedS"] == plain["unattributedS"]
+    assert both["detail"]["queued/gc-pause"] == pytest.approx(0.05)
+    assert both["detail"]["dispatch/gc-pause"] == pytest.approx(0.1)
+    assert both["detail"]["schedule/gc-pause"] == pytest.approx(0.05)
+    assert both["detail"]["schedule/compile"] == pytest.approx(0.02)
+    assert both["detail"]["exchange-wait/task-output"] == pytest.approx(0.03)
+    # without detail spans every phase is its own remainder, the staging
+    # stage and the pull excepted: they were spans before
+    assert plain["detail"]["device-execute/remainder"] == pytest.approx(
+        plain["phases"]["device-execute"])
+    assert plain["detail"]["device-staging/scan"] == pytest.approx(0.1)
+    assert plain["detail"]["exchange-wait/pull"] == pytest.approx(0.1)
+
+
+def _span_names(node, out):
+    out.setdefault(node["name"], []).append(node)
+    for child in node["children"]:
+        _span_names(child, out)
+    return out
+
+
+_POINT = ("PREPARE ledger_order FROM select o_orderkey, o_custkey, "
+          "o_totalprice from orders where o_orderkey = ?")
+_DETAIL_STATEMENTS = {
+    "q1": TPCH[1], "q3": TPCH[3], "q6": TPCH[6], "q18": TPCH[18],
+    "point": "EXECUTE ledger_order USING 7",
+}
+
+
+@pytest.mark.parametrize("name", list(_DETAIL_STATEMENTS))
+def test_detail_sums_to_phases_through_the_statement_protocol(cluster, name):
+    """Through ``/v1/statement``: 14 phases, every phase's detail summing
+    to it, an ``operator/<Kind>`` span for every plan node kind executed,
+    and every blocking read counted once in a kernel row and once under
+    its site."""
+    coord, _ = cluster
+    props = {"catalog": "tpch", "schema": "tiny",
+             "result_cache_enabled": "false"}
+    if name == "point":
+        props["short_query_fast_path"] = "true"
+    client = StatementClient(coord.base_url, props)
+    if name == "point":
+        client.execute(_POINT)
+    _columns, rows = client.execute(_DETAIL_STATEMENTS[name])
+    assert rows
+    tl = client.stats["timeline"]
+    assert list(tl["phases"]) == list(PHASES) and len(tl["phases"]) == 14
+    for phase, seconds in tl["phases"].items():
+        split = sum(v for k, v in tl["detail"].items()
+                    if k.startswith(phase + "/"))
+        assert split == pytest.approx(seconds, abs=1e-5), (phase, tl["detail"])
+    assert any(k.startswith("device-execute/op:") for k in tl["detail"])
+    # every operator kind the kernel ledger saw executing has its spans
+    prof = json.loads(urllib.request.urlopen(
+        f"{coord.base_url}/v1/query/{client.query_id}/profile").read())
+    trace = json.loads(urllib.request.urlopen(
+        f"{coord.base_url}/v1/query/{client.query_id}/trace").read())
+    spans = _span_names(trace["root"], {})
+    executed = {k["operator"] for k in prof["kernels"] if k["launches"]}
+    assert executed, prof["kernels"]
+    for kind in executed:
+        assert f"operator/{kind}" in spans, (kind, sorted(spans))
+    for sp in spans.get("operator/Filter", []):
+        assert "planNodeId" in sp["attributes"] and "rows" in sp["attributes"]
+    # the counters at the same boundaries
+    syncs = sum(k["hostSyncs"] for k in prof["kernels"])
+    assert syncs >= 1
+    assert syncs == sum(s["count"] for s in prof["hostSyncSites"].values())
+    assert sum(k["d2hBytes"] for k in prof["kernels"]) == sum(
+        s["bytes"] for s in prof["hostSyncSites"].values())
+    assert "operator-stats" in prof["hostSyncSites"]
+    if name != "point":
+        # a worker's output path is spanned, in every driver shape
+        assert "task/output" in spans

@@ -490,3 +490,135 @@ def test_process_self_metrics_on_both_servers(cluster):
         "where name = 'trino_tpu_process_rss_bytes'", {})
     assert _wait_terminal(q) == "FINISHED", q.failure
     assert q.rows and q.rows[0][1] > 0
+
+
+# ------------------------------------- one clock, stored spans, GC pauses
+def test_spans_are_profiler_annotations_on_the_trace_clock(tmp_path):
+    """Every span is also a ``jax.profiler.TraceAnnotation`` of its name: a
+    profiler session holds the program's spans in the xplane, so they can
+    be read in Perfetto above the device operations."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        t = Tracer()
+        with t.span("operator/Probe"):
+            opened = t.start_span("exchange/pull")
+            time.sleep(0.002)
+            t.end_span(opened)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    names = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                names[ev.name] = ev.duration_ns
+    assert "operator/Probe" in names and "exchange/pull" in names
+    assert names["exchange/pull"] >= 2e6   # the span's own interval, in ns
+    assert names["operator/Probe"] >= names["exchange/pull"]
+
+
+def test_record_span_stores_a_finished_span_under_the_ambient_parent():
+    t = Tracer()
+    with t.span("operator/Filter") as parent:
+        tracing.record("host/sync", 100.0, 0.25, site="compact", bytes=8)
+    tracing.record("host/sync", 200.0, 0.25)   # no tracer: nothing, no error
+    by_name = {s["name"]: s for s in t.to_dicts()}
+    sync = by_name["host/sync"]
+    assert sync["parentId"] == parent.span_id
+    assert (sync["start"], sync["durationS"]) == (100.0, 0.25)
+    assert sync["attributes"] == {"site": "compact", "bytes": 8}
+    # the cap holds for recorded spans too
+    small = Tracer(max_spans=1)
+    small.record_span("host/sync", 1.0, 0.1)
+    small.record_span("host/sync", 2.0, 0.1)
+    assert len(small.spans()) == 1 and small.dropped_spans == 1
+
+
+def test_gc_recorder_keeps_pauses_and_hands_out_overlapping_spans():
+    rec = tracing.GcRecorder(capacity=3)
+    rec("start", {"generation": 2})
+    time.sleep(0.002)
+    t_mid = time.time()
+    rec("stop", {"generation": 2, "collected": 0})
+    rec("start", {"generation": 0})
+    rec("stop", {"generation": 0})            # far under 50 us: counted only
+    (start, pause, gen), = list(rec.pauses)
+    assert gen == 2 and pause >= 0.002 and start <= t_mid <= start + pause + 1e-3
+    assert rec.total_s[2] == pytest.approx(pause) and 0 in rec.total_s
+    (span,) = rec.spans_between(start - 1, start + 1, parent_id="root")
+    assert span["name"] == "process/gc" and span["parentId"] == "root"
+    assert span["attributes"] == {"generation": 2}
+    assert span["durationS"] == pytest.approx(pause, abs=1e-6)
+    assert rec.spans_between(start + pause + 1, start + pause + 2) == []
+    for _ in range(5):                         # a bounded ring
+        rec.pauses.append((0.0, 1.0, 1))
+    assert len(rec.pauses) == 3
+
+
+def test_a_forced_collection_inside_a_statement_shows_as_gc_pause(
+        cluster, monkeypatch):
+    """The process's collector pauses that overlap a statement's wall join
+    its span export: ``process/gc`` in the trace, ``<phase>/gc-pause`` in
+    the ledger's detail."""
+    import gc
+
+    from trino_tpu.exec.executor import Executor
+
+    coord, _ = cluster
+    real = Executor.execute_checked
+
+    def collect_first(self, node):
+        gc.collect()
+        return real(self, node)
+
+    monkeypatch.setattr(Executor, "execute_checked", collect_first)
+    q = coord.submit(
+        "select count(*) from orders where o_totalprice > 1000",
+        {"catalog": "tpch", "schema": "tiny",
+         "result_cache_enabled": "false"})
+    assert _wait_terminal(q) == "FINISHED", q.failure
+    tl = q.timeline_dict()
+    paused = {k: v for k, v in tl["detail"].items() if k.endswith("/gc-pause")}
+    assert paused and sum(paused.values()) > 0, tl["detail"]
+    trace = _get_json(f"{coord.base_url}/v1/query/{q.query_id}/trace")
+    pauses = [n for n in flatten_tree(trace["root"])
+              if n["name"] == "process/gc"]
+    assert any(n["attributes"]["generation"] == 2 for n in pauses)
+    assert trace["root"]["name"] == "query", "pauses hang under the root"
+    # level one did not move: the 14 phases still sum to the wall
+    in_wall = sum(v for p, v in tl["phases"].items()
+                  if p not in ("client-drain", "segment-fetch"))
+    assert in_wall == pytest.approx(tl["wallS"], abs=2e-5)
+    # and the registry has the seconds, by generation
+    text = urllib.request.urlopen(f"{coord.base_url}/v1/metrics").read().decode()
+    assert 'trino_tpu_gc_pause_seconds_total{generation="2"}' in text
+
+
+def test_back_to_back_reads_of_one_site_are_one_span():
+    t = Tracer()
+    with t.span("task/output") as parent:
+        tracing.record_burst("host/sync", 10.0, 0.001, "compact", 4)
+        tracing.record_burst("host/sync", 10.0015, 0.001, "compact", 8)   # 0.5 ms on
+        tracing.record_burst("host/sync", 10.003, 0.001, "serialize", 2)  # another site
+        tracing.record_burst("host/sync", 10.1, 0.001, "serialize", 2)    # 96 ms on
+    with t.span("task/output"):
+        tracing.record_burst("host/sync", 10.1015, 0.001, "serialize", 2)  # another parent
+    tracing.record_burst("host/sync", 11.0, 0.001, "compact", 1)           # no tracer
+    syncs = [s for s in t.to_dicts() if s["name"] == "host/sync"]
+    assert [(s["start"], s["attributes"]) for s in syncs] == [
+        (10.0, {"site": "compact", "reads": 2, "bytes": 12}),
+        (10.003, {"site": "serialize", "reads": 1, "bytes": 2}),
+        (10.1, {"site": "serialize", "reads": 1, "bytes": 2}),
+        (10.1015, {"site": "serialize", "reads": 1, "bytes": 2}),
+    ]
+    assert syncs[0]["durationS"] == pytest.approx(0.002)   # the reads alone
+    assert syncs[0]["parentId"] == parent.span_id

@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""Fail when a registered metric is missing from the README metric table.
+"""Fail when a registered metric, a kernel-ledger row field or a label of
+the phase ledger's detail is missing from the README.
 
 Every exported metric is DECLARED module-level in ``trino_tpu/obs/
 metrics.py`` (the registry is the single source of truth), so doc coverage
@@ -8,8 +9,14 @@ require each name to appear in README.md's Observability section. Wired as
 a tier-1 test (tests/test_metric_docs.py) and into ``tools/lint.py --all``
 (shared plumbing: tools/gates.py).
 
+The counters that are not registry series are held to the same rule: the
+fields of a kernel-ledger row (``obs/devprofiler.py: new_kernel_row``, the
+``kernels`` of ``GET /v1/query/{id}/profile``) and the labels of
+``timeline.detail`` (``obs/timeline.py: DETAIL_LABELS``) must each be
+mentioned in backticks.
+
 Usage: ``python tools/check_metric_docs.py [--readme PATH]`` — exit 0 when
-every metric is documented, 1 with the missing names otherwise.
+every name is documented, 1 with the missing names otherwise.
 """
 from __future__ import annotations
 
@@ -37,19 +44,34 @@ def documented_metric_names(readme_path: str) -> set:
     return set(re.findall(r"\btrino_tpu_[a-z0-9_]+\b", text))
 
 
+def ledger_names() -> list:
+    """Kernel-row fields and detail labels (both modules are stdlib-only
+    at import, loaded as files like the registry)."""
+    prof = gates.load_module_file("trino_tpu/obs/devprofiler.py",
+                                  "_obs_devprofiler_standalone")
+    timeline = gates.load_module_file("trino_tpu/obs/timeline.py",
+                                      "_obs_timeline_standalone")
+    labels = {label for _rank, label in timeline.DETAIL_LABELS.values()}
+    return sorted(set(prof.new_kernel_row("", "", "")) | labels
+                  | {timeline.REMAINDER, "op:<Kind>"})
+
+
 def check(readme_path: str | None = None) -> list:
-    """Missing metric names (empty means the docs are complete)."""
+    """Missing names (empty means the docs are complete)."""
     documented = documented_metric_names(readme_path)
-    return [name for name in registered_metric_names()
-            if name not in documented]
+    backticked = gates.backticked_names(gates.read_readme(readme_path))
+    return ([name for name in registered_metric_names()
+             if name not in documented]
+            + [name for name in ledger_names() if name not in backticked])
 
 
 def main() -> int:
     return gates.gate_main(
         __doc__, check,
-        "metrics registered in code but missing from the README "
-        "Observability table:",
-        "add each to the metric table in README.md (## Observability)",
+        "metrics, kernel-row fields or detail labels in code but missing "
+        "from the README Observability section:",
+        "add each to README.md (## Observability): a metric to the metric "
+        "table, a field or label in backticks",
         lambda: (f"ok: all {len(registered_metric_names())} registered "
                  "metrics are documented"))
 

@@ -23,11 +23,13 @@ if __package__ in (None, ""):  # script mode: tools/ on sys.path
 else:  # imported as tools.check_span_docs
     from tools import gates
 
-# a span call is any `<tracing|...tracer>.span(` / `.start_span(` — the
-# receiver prefix keeps unrelated `*_span(` helpers (e.g. ops/join.py
+# a span call is any `<tracing|...tracer>.span(` / `.start_span(`, or the
+# after-the-fact `tracing.record(` / `.record_burst(` / `.record_span(` —
+# the receiver prefix keeps unrelated `*_span(` helpers (e.g. ops/join.py
 # dense_span) out of the vocabulary
 _CALL_RE = re.compile(
-    r"(?:tracing|[A-Za-z_][\w.]*tracer)\s*\.\s*(?:start_)?span\s*\(")
+    r"(?:tracing|[A-Za-z_][\w.]*tracer)\s*\.\s*"
+    r"(?:(?:start_|record_)?span|record(?:_burst)?)\s*\(")
 _STRING_RE = re.compile(r"\"([^\"]+)\"|'([^']+)'")
 
 
@@ -67,7 +69,9 @@ def emitted_span_names(root: str | None = None) -> list:
         for m in _CALL_RE.finditer(text):
             arg = _first_arg_slice(text, m.end() - 1)
             for sm in _STRING_RE.finditer(arg):
-                names.add(sm.group(1) or sm.group(2))
+                # an f-string documents as its template: {x} -> <x>
+                names.add(re.sub(r"\{(\w+)\}", r"<\1>",
+                                 sm.group(1) or sm.group(2)))
     return sorted(names)
 
 

@@ -203,6 +203,13 @@ SYSTEM_TABLES = {
         ("input_bytes", "bigint"),
         ("output_bytes", "bigint"),
         ("estimated", "boolean"),      # true = no-sync estimate
+        # blocking device->host reads charged to the operator
+        # (obs/devprofiler.py host_read) and the XLA compiles it owned
+        ("host_syncs", "bigint"),
+        ("host_sync_seconds", "double"),
+        ("d2h_bytes", "bigint"),
+        ("compiles", "bigint"),
+        ("compile_seconds", "double"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

@@ -23,6 +23,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.data.dictionary import NULL_CODE, Dictionary
+from trino_tpu.obs.devprofiler import host_read
 
 
 @dataclasses.dataclass
@@ -156,18 +157,20 @@ class Column:
         """Device -> host, decoding reprs back to Python values."""
         if self.type.is_nested:
             return self._nested_to_python()
+        site = "result-rows"
         if self.hi is not None:
-            his = np.asarray(self.hi).tolist()
-            los = np.asarray(self.values).view(np.uint64).tolist()
-            nulls = np.asarray(self.nulls).tolist() if self.nulls is not None else None
+            his = host_read(self.hi, site).tolist()
+            los = host_read(self.values, site).view(np.uint64).tolist()
+            nulls = (host_read(self.nulls, site).tolist()
+                     if self.nulls is not None else None)
             out = [
                 _from_repr(self.type, (h << 64) | l) for h, l in zip(his, los)
             ]
             if nulls is not None:
                 out = [None if isnull else v for v, isnull in zip(out, nulls)]
             return out
-        vals = np.asarray(self.values)
-        nulls = np.asarray(self.nulls) if self.nulls is not None else None
+        vals = host_read(self.values, site)
+        nulls = host_read(self.nulls, site) if self.nulls is not None else None
         if self.type.is_varchar:
             assert self.dictionary is not None
             out = self.dictionary.decode(vals)
@@ -182,7 +185,8 @@ class Column:
         return out
 
     def _nested_to_python(self) -> List:
-        nulls = np.asarray(self.nulls) if self.nulls is not None else None
+        nulls = (host_read(self.nulls, "result-rows")
+                 if self.nulls is not None else None)
         if isinstance(self.type, T.RowType):
             fields = [c.to_python() for c in self.children]
             out = [tuple(f[i] for f in fields) for i in range(len(self))]
@@ -344,7 +348,8 @@ def _concat_nulls(cols: Sequence[Column]):
         for c in cols])
 
 
-def host_take(c: Column, idx: np.ndarray, device: bool = True) -> Column:
+def host_take(c: Column, idx: np.ndarray, device: bool = True,
+              site: str = "compact") -> Column:
     """Row gather on the HOST (numpy). The one gather path that supports
     nested columns: child segments are re-flattened by explicit offsets —
     a data-dependent-shape operation jit'd device code cannot express.
@@ -352,16 +357,18 @@ def host_take(c: Column, idx: np.ndarray, device: bool = True) -> Column:
     ``device=False`` keeps the gathered arrays as numpy (no device_put):
     the host-consumption paths (``to_pylist`` — result rows headed
     straight to Python) would otherwise pay one device round trip per
-    column just to read them back."""
+    column just to read them back. ``site`` labels the device->host reads
+    of the column's arrays in the kernel ledger."""
     up = jnp.asarray if device else np.asarray
     if c.type.is_nested:
-        nulls = np.asarray(c.nulls)[idx] if c.nulls is not None else None
+        nulls = host_read(c.nulls, site)[idx] if c.nulls is not None else None
         if isinstance(c.type, T.RowType):
-            kids = [host_take(k, idx, device=device) for k in c.children]
-            vals = np.asarray(c.values)[idx]
+            kids = [host_take(k, idx, device=device, site=site)
+                    for k in c.children]
+            vals = host_read(c.values, site)[idx]
         else:
             off = c.offsets()
-            lens = np.asarray(c.values, dtype=np.int64)
+            lens = host_read(c.values, site).astype(np.int64)
             vals = lens[idx].astype(np.int32)
             if len(idx):
                 child_idx = np.concatenate(
@@ -369,7 +376,8 @@ def host_take(c: Column, idx: np.ndarray, device: bool = True) -> Column:
                 )
             else:
                 child_idx = np.zeros(0, np.int64)
-            kids = [host_take(k, child_idx, device=device) for k in c.children]
+            kids = [host_take(k, child_idx, device=device, site=site)
+                    for k in c.children]
         return Column(
             c.type, up(vals),
             up(nulls) if nulls is not None else None,
@@ -380,12 +388,12 @@ def host_take(c: Column, idx: np.ndarray, device: bool = True) -> Column:
     monotone = bool(c.ascending) and (len(idx) < 2 or bool(np.all(np.diff(idx) >= 0)))
     return Column(
         c.type,
-        up(np.asarray(c.values)[idx]),
-        up(np.asarray(c.nulls)[idx]) if c.nulls is not None else None,
+        up(host_read(c.values, site)[idx]),
+        up(host_read(c.nulls, site)[idx]) if c.nulls is not None else None,
         c.dictionary,
         c.vrange,
         ascending=monotone,
-        hi=up(np.asarray(c.hi)[idx]) if c.hi is not None else None,
+        hi=up(host_read(c.hi, site)[idx]) if c.hi is not None else None,
     )
 
 
@@ -467,7 +475,7 @@ class Page:
         compacting pages into the PartitionedOutputBuffer."""
         if self.sel is None:
             return self
-        live = np.asarray(self.sel)
+        live = host_read(self.sel, "compact")
         idx = np.nonzero(live)[0]
         return Page([host_take(c, idx) for c in self.columns], None, self.replicated)
 
@@ -493,27 +501,34 @@ class Page:
 
     def row_byte_estimate(self) -> int:
         """Rough serialized bytes per row (dtype widths; dictionaries are
-        amortized) — sizes output chunks."""
+        amortized) — sizes output chunks. It reads each column to the
+        host to learn its dtype (the chip's transfer guard found it: whole
+        columns, 24 MB a point lookup): counted under ``row-byte-estimate``
+        so that the issue that takes the read away can size it."""
+        site = "row-byte-estimate"
         total = 0
         for c in self.columns:
-            total += np.asarray(c.values).dtype.itemsize
+            total += host_read(c.values, site).dtype.itemsize
             if c.nulls is not None:
                 total += 1
             if c.children is not None and self.num_rows:
                 # amortize flattened children over the parent row count
                 for k in c.children:
                     total += max(
-                        1, (len(k) * np.asarray(k.values).dtype.itemsize) // self.num_rows
-                    )
+                        1, (len(k) * host_read(k.values, site).dtype.itemsize)
+                        // self.num_rows)
         return max(total, 1)
 
-    def live_count(self) -> int:
+    def live_count(self, site: str = "live-count") -> int:
+        """Live rows. With a selection mask on the device this is a
+        blocking device->host read of the whole mask, counted in the
+        kernel ledger under ``site``."""
         if self.sel is None:
             return self.num_rows
         # host count: the mask is a bool vector headed for one scalar —
         # a jnp.sum here pays a device dispatch per call, and this is
         # called several times per query on the serving path
-        return int(np.count_nonzero(np.asarray(self.sel)))
+        return int(np.count_nonzero(host_read(self.sel, site)))
 
     def to_pylist(self) -> List[tuple]:
         """Materialize live rows as Python tuples (host side, test/CLI path).
@@ -525,8 +540,8 @@ class Page:
         boundaries would be a per-column round trip bought for nothing
         (measured ~0.7ms per point query on the serving path)."""
         if self.sel is not None:
-            idx = np.nonzero(np.asarray(self.sel))[0]
-            page = Page([host_take(c, idx, device=False)
+            idx = np.nonzero(host_read(self.sel, "result-rows"))[0]
+            page = Page([host_take(c, idx, device=False, site="result-rows")
                          for c in self.columns], None, self.replicated)
         else:
             page = self

@@ -43,6 +43,7 @@ import numpy as np
 from trino_tpu import types as T
 from trino_tpu.data.dictionary import Dictionary
 from trino_tpu.data.page import Column, Page
+from trino_tpu.obs.devprofiler import host_read
 
 MAGIC = 0x7E51_00D5
 CODEC_NONE = 0
@@ -66,17 +67,18 @@ def _serialize_column(col: Column, n: int, parts: List[bytes]) -> None:
     parts.append(name)
     if col.nulls is not None:
         parts.append(b"\x01")
-        parts.append(np.packbits(np.asarray(col.nulls)).tobytes())
+        parts.append(np.packbits(host_read(col.nulls, "serialize")).tobytes())
     else:
         parts.append(b"\x00")
-    vals_np = np.ascontiguousarray(np.asarray(col.values))
+    vals_np = np.ascontiguousarray(host_read(col.values, "serialize"))
     dtype_code = _DTYPE_CODES[vals_np.dtype]
     if col.hi is not None:
         # long-decimal two-limb column: flag bit 7 on the dtype code, hi
         # limb block follows the low words (reference: Int128 flat storage)
         parts.append(struct.pack("<B", dtype_code | 0x80))
         parts.append(vals_np.tobytes())
-        parts.append(np.ascontiguousarray(np.asarray(col.hi)).tobytes())
+        parts.append(
+            np.ascontiguousarray(host_read(col.hi, "serialize")).tobytes())
     else:
         parts.append(struct.pack("<B", dtype_code))
         parts.append(vals_np.tobytes())

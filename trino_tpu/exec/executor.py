@@ -29,7 +29,9 @@ from trino_tpu.data.page import Column, Page
 from trino_tpu.exec import memory as _mem
 from trino_tpu.exec.operator_stats import OperatorStats
 from trino_tpu.obs import metrics as M
-from trino_tpu.obs.devprofiler import merge_platforms
+from trino_tpu.obs import trace as tracing
+from trino_tpu.obs.devprofiler import (
+    charge_to, host_read, merge_platforms, new_kernel_row)
 from trino_tpu.ops import aggregate as agg_ops
 from trino_tpu.ops import expr_lower as L
 from trino_tpu.ops import fused_join as fused_ops
@@ -52,10 +54,8 @@ class QueryError(RuntimeError):
 def raise_query_errors(codes, flags):
     """Raise the first deferred runtime error whose flag fired. Shared by
     the eager, compiled, and SPMD paths."""
-    import numpy as _np
-
     for code, flag in zip(codes, flags):
-        if bool(_np.asarray(flag).any()):
+        if bool(host_read(flag, "error-flags").any()):
             raise QueryError(code.replace("_", " ").capitalize(), code=code)
 
 
@@ -74,7 +74,13 @@ def _col_from_lowered(t: T.Type, lv: L.LoweredVal) -> Column:
     return Column(t, lv.vals, nulls, lv.dictionary, vrange, hi=lv.hi)
 
 
-def _page_platform(page: Page) -> str:
+def operator_kind(node: P.PlanNode) -> str:
+    """The operator name of a plan node, as the stats, the kernel ledger
+    and the ``operator/<kind>`` spans spell it."""
+    return type(node).__name__.replace("Node", "")
+
+
+def page_platform(page: Page) -> str:
     """The device platform holding ``page``'s first column (``"host"`` for
     a numpy array): the kernel ledger's proof of where a launch ran."""
     if not page.columns:
@@ -300,47 +306,58 @@ class Executor:
             return method(node)
         # per-operator profiling, always on in the eager tier (reference:
         # OperatorContext/OperatorStats via OperationTimer — SURVEY.md §5.1)
+        kind = operator_kind(node)
+        # what the node's blocking device->host reads and compiles are
+        # charged to while it executes (obs/devprofiler.py host_read), its
+        # children charging their own rows
+        ks = self._kernel_row(node)
         self._child_wall.append(0.0)
+        # kernel ledger: device seconds per dispatch. profile_sync ON:
+        # eager jax dispatch returns before the math finishes — the
+        # block_until_ready wait IS the device time, and excl_wall
+        # (dispatch + host glue) minus it is the overhead. OFF: zero-sync
+        # estimate — device ≈ exclusive wall, flagged.
+        sync_s = 0.0
         t0 = time.perf_counter()
         try:
-            page = method(node)
+            with tracing.span(f"operator/{kind}",
+                              planNodeId=node.id) as sp, charge_to(ks):
+                page = method(node)
+                if self.profile_sync:
+                    t_sync = time.perf_counter()
+                    try:
+                        jax.block_until_ready(
+                            [c.values for c in page.columns])
+                    except Exception:  # noqa: BLE001 — never fails work
+                        pass
+                    sync_s = time.perf_counter() - t_sync
+                # live rows, not padded slots: a blocking read of the
+                # selection mask, inside the clock and the row of the node
+                # that produced the page (the adaptive planner and EXPLAIN
+                # ANALYZE read output_rows)
+                live = page.live_count("operator-stats")
+                sp.set("rows", live)
         finally:
             # keep the stack balanced on error paths: the parent is still
-            # charged the subtree's time
-            wall = time.perf_counter() - t0
-            child_wall = self._child_wall.pop()
-            self._child_wall[-1] += wall
-        excl_wall = max(0.0, wall - child_wall)
-        # kernel ledger (obs/devprofiler.py): device seconds per dispatch.
-        # profile_sync ON: eager jax dispatch returns before the math
-        # finishes — the block_until_ready wait IS the device time, and
-        # excl_wall (dispatch + host glue) minus it is the overhead.
-        # OFF: zero-sync estimate — device ≈ exclusive wall, flagged.
-        device_s = excl_wall
-        estimated = True
-        if self.profile_sync:
-            t_sync = time.perf_counter()
-            try:
-                jax.block_until_ready([c.values for c in page.columns])
-            except Exception:  # noqa: BLE001 — profiling never fails work
-                pass
-            device_s = time.perf_counter() - t_sync
-            estimated = False
-            # the sync wait is elapsed time inside THIS node's subtree:
-            # charge it to the parent's child ledger so the parent's
+            # charged the subtree's time. The measured sync wait is
+            # elapsed time inside THIS node's subtree too, so the parent's
             # exclusive wall stays exclusive of it
-            self._child_wall[-1] += device_s
-        live = page.live_count()  # live rows, not padded slots
+            elapsed = time.perf_counter() - t0
+            wall = elapsed - sync_s
+            child_wall = self._child_wall.pop()
+            self._child_wall[-1] += elapsed
+        excl_wall = max(0.0, wall - child_wall)
+        estimated = not self.profile_sync
+        device_s = excl_wall if estimated else sync_s
         nbytes = _mem.page_bytes(page)
         st = self.node_stats.get(node.id)
         if st is None:
-            st = self.node_stats[node.id] = OperatorStats(
-                node.id, type(node).__name__.replace("Node", ""))
+            st = self.node_stats[node.id] = OperatorStats(node.id, kind)
         # accumulate, never overwrite: a node re-executed (per probe batch,
         # per split) ADDS its rows/bytes/time, so rollups stay additive.
         # Wall is EXCLUSIVE (children's recursive time subtracted), so the
         # per-operator-kind metrics and rollups sum to the fragment body.
-        st.wall_s += max(0.0, wall - child_wall)
+        st.wall_s += excl_wall
         st.output_rows += live
         st.output_bytes += nbytes
         st.invocations += 1
@@ -358,16 +375,8 @@ class Executor:
         in_bytes = sum(
             self._last_output_bytes.get(s.id, 0) for s in node.sources)
         kwall = excl_wall + (device_s if not estimated else 0.0)
-        kkey = (node.id, st.operator)
-        ks = self.kernel_stats.get(kkey)
-        if ks is None:
-            ks = self.kernel_stats[kkey] = {
-                "planNodeId": str(node.id), "operator": st.operator,
-                "tier": "eager", "launches": 0, "wallS": 0.0,
-                "deviceS": 0.0, "inputBytes": 0, "outputBytes": 0,
-                "estimated": estimated, "platform": ""}
         ks["launches"] += 1
-        ks["platform"] = merge_platforms(ks["platform"], _page_platform(page))
+        ks["platform"] = merge_platforms(ks["platform"], page_platform(page))
         ks["wallS"] += kwall
         ks["deviceS"] += device_s
         ks["inputBytes"] += in_bytes
@@ -386,6 +395,23 @@ class Executor:
         # LocalMemoryContext -> query-pool rollup, exact from static shapes)
         self.memory.observe(nbytes)
         return page
+
+    def _kernel_row(self, node: P.PlanNode) -> dict:
+        """The kernel-ledger row of this (node, operator), made on first
+        use."""
+        key = (node.id, operator_kind(node))
+        row = self.kernel_stats.get(key)
+        if row is None:
+            row = self.kernel_stats[key] = new_kernel_row(
+                str(node.id), key[1], "eager",
+                estimated=not self.profile_sync)
+        return row
+
+    def charging(self, node: P.PlanNode):
+        """Charge this thread's device->host reads to ``node``'s kernel
+        row: for work on a page once the node that produced it has
+        returned (result rows on the coordinator)."""
+        return charge_to(self._kernel_row(node))
 
     def _narrowed_or_flag(self, col: Column, sel=None) -> Column:
         """Degrade a two-limb long-decimal column to its low word for
@@ -1656,14 +1682,15 @@ class Executor:
             col = build.columns[ch]
             if col.type.is_varchar:
                 continue  # dictionary codes are page-local, not portable
-            vals = np.asarray(col.values)
+            site = "dynamic-filter-domain"
+            vals = host_read(col.values, site)
             live = (
                 np.ones(len(vals), bool)
                 if build.sel is None
-                else np.asarray(build.sel).copy()
+                else host_read(build.sel, site).copy()
             )
             if col.nulls is not None:
-                live &= ~np.asarray(col.nulls)
+                live &= ~host_read(col.nulls, site)
             lv = vals[live]
             if len(lv) == 0:
                 dom = Domain(values=frozenset())  # provably empty probe
@@ -1685,7 +1712,7 @@ class Executor:
                 "the executor's dispatch disagree (sql/planner/stats.py)"
             )
         try:
-            total = int(jnp.sum(emit_counts))
+            total = int(host_read(jnp.sum(emit_counts), "join-emit-count"))
         except jax.errors.ConcretizationTypeError:
             raise RuntimeError(
                 f"{key} traced without a capacity hint — compiled paths "
@@ -2283,14 +2310,14 @@ class Executor:
             col = compacted.columns[c]
             if col.type.is_nested:
                 raise NotImplementedError("ORDER BY an array/map column")
-            v = np.asarray(col.values)
+            v = host_read(col.values, "host-sort")
             if v.dtype == np.bool_:
                 v = v.astype(np.int8)
             if not asc:
                 v = -v if np.issubdtype(v.dtype, np.floating) else ~v
             nulls_first = (not asc) if nf is None else nf
             if col.nulls is not None:
-                isnull = np.asarray(col.nulls)
+                isnull = host_read(col.nulls, "host-sort")
                 rank = (~isnull).astype(np.int8) if nulls_first else isnull.astype(np.int8)
                 lex_keys.append(np.where(isnull, np.zeros((), v.dtype), v))
                 lex_keys.append(rank)

@@ -152,9 +152,11 @@ def partition_page_host(page, key_channels, parts: int, pid=None):
     import numpy as np
 
     from trino_tpu.data.page import Column, Page
+    from trino_tpu.obs.devprofiler import host_read
 
     n = page.num_rows
-    live = np.ones(n, bool) if page.sel is None else np.asarray(page.sel)
+    site = "partition"
+    live = np.ones(n, bool) if page.sel is None else host_read(page.sel, site)
     if pid is None:
         h = np.zeros(n, np.uint64)
         for ch in key_channels:
@@ -163,9 +165,10 @@ def partition_page_host(page, key_channels, parts: int, pid=None):
             # column's hi-limb PRESENCE is data-dependent (one join side may
             # carry it while the other doesn't) — mixing hi in would place
             # equal keys in different partitions across sides/producers
-            k = _mix64_np(np.asarray(col.values).astype(np.int64))
+            k = _mix64_np(host_read(col.values, site).astype(np.int64))
             if col.nulls is not None:
-                k = np.where(np.asarray(col.nulls), np.uint64(_NULL_HASH), k)
+                k = np.where(host_read(col.nulls, site),
+                             np.uint64(_NULL_HASH), k)
             h = _mix64_np(h ^ k)
         pid = (h % np.uint64(parts)).astype(np.int64)
     else:
@@ -179,7 +182,8 @@ def partition_page_host(page, key_channels, parts: int, pid=None):
             out.append(_pad_like(page))
             continue
         # host_take handles two-limb and nested columns uniformly
-        out.append(Page([host_take(c, idx) for c in page.columns], None, page.replicated))
+        out.append(Page([host_take(c, idx, site=site) for c in page.columns],
+                        None, page.replicated))
     return out
 
 

@@ -30,16 +30,27 @@ rings, O(1) append under a short lock, fan-out outside the lock):
   busy seconds, compiles in flight) sampled on the worker announce tick
   into a watermark-style ring (launches/sec, device-busy fraction).
 
+Kernel rows also count what the host did around the device: every
+blocking device->host read goes through :func:`host_read` (``hostSyncs``,
+``hostSyncS``, ``d2hBytes``, and the same three per ``site`` under
+``hostSyncSites``) and every XLA backend compile is heard by one
+``jax.monitoring`` listener (``compiles``, ``compileS``); both charge
+the row of the operator that is executing on the thread
+(:func:`charge_to`).
+
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
 recorder fan-out happen at *fold* time (query completion) or compile
 time (rare), never per-dispatch.
 
 This module is import-clean standalone (stdlib only at import time) so
-doc gates can load it without the package/jax.
+doc gates can load it without the package/jax; jax, numpy and
+``obs/trace.py`` are imported where a function first needs them.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import threading
 import time
 from collections import OrderedDict, deque
@@ -63,6 +74,37 @@ def merge_platforms(a: str, b: str) -> str:
     return "+".join(sorted(set(filter(None, a.split("+") + b.split("+")))))
 
 
+def new_kernel_row(plan_node_id: str, operator: str, tier: str,
+                   node_id: Optional[str] = None,
+                   estimated: bool = False) -> dict:
+    """An empty kernel row: the one place that knows the fields."""
+    row = {"planNodeId": plan_node_id, "operator": operator, "tier": tier,
+           "launches": 0, "wallS": 0.0, "deviceS": 0.0, "inputBytes": 0,
+           "outputBytes": 0, "estimated": estimated, "platform": "",
+           "hostSyncs": 0, "hostSyncS": 0.0, "d2hBytes": 0,
+           "compiles": 0, "compileS": 0.0, "hostSyncSites": {}}
+    if node_id is not None:
+        row["nodeId"] = node_id
+    return row
+
+
+def copy_kernel_row(row: dict, **fields) -> dict:
+    """A snapshot of ``row`` that shares nothing with it."""
+    return dict(row, hostSyncSites={
+        k: list(v) for k, v in row.get("hostSyncSites", {}).items()},
+        **fields)
+
+
+def merge_sync_sites(dst: Dict[str, list], sites: Dict[str, list]) -> None:
+    """Add ``site -> [count, seconds, bytes]`` tables."""
+    # list(): the owning thread may add a site while a status poll merges
+    for site, (n, s, b) in list((sites or {}).items()):
+        have = dst.setdefault(site, [0, 0.0, 0])
+        have[0] += int(n)
+        have[1] += float(s)
+        have[2] += int(b)
+
+
 def merge_kernel_rows(dst: Dict[tuple, dict],
                       rows: List[dict]) -> Dict[tuple, dict]:
     """Fold serialized kernel rows (``kernel_rows`` wire shape) into a
@@ -72,20 +114,129 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                row.get("tier", "eager"), row.get("nodeId", ""))
         agg = dst.get(key)
         if agg is None:
-            agg = {"planNodeId": key[0], "operator": key[1],
-                   "tier": key[2], "nodeId": key[3], "launches": 0,
-                   "wallS": 0.0, "deviceS": 0.0, "inputBytes": 0,
-                   "outputBytes": 0, "estimated": False, "platform": ""}
-            dst[key] = agg
-        agg["launches"] += int(row.get("launches", 0))
-        agg["wallS"] += float(row.get("wallS", 0.0))
-        agg["deviceS"] += float(row.get("deviceS", 0.0))
-        agg["inputBytes"] += int(row.get("inputBytes", 0))
-        agg["outputBytes"] += int(row.get("outputBytes", 0))
+            agg = dst[key] = new_kernel_row(key[0], key[1], key[2], key[3])
+        for field in ("launches", "inputBytes", "outputBytes", "hostSyncs",
+                      "d2hBytes", "compiles"):
+            agg[field] += int(row.get(field, 0))
+        for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
+            agg[field] += float(row.get(field, 0.0))
         agg["estimated"] = bool(agg["estimated"] or row.get("estimated"))
         agg["platform"] = merge_platforms(agg["platform"],
                                           row.get("platform", ""))
+        merge_sync_sites(agg["hostSyncSites"], row.get("hostSyncSites"))
     return dst
+
+
+def sync_sites_of(rows: List[dict]) -> Dict[str, dict]:
+    """The profile's ``hostSyncSites`` block: the rows' per-site tables
+    summed, ``site -> {count, seconds, bytes}``."""
+    total: Dict[str, list] = {}
+    for row in rows:
+        merge_sync_sites(total, row.get("hostSyncSites"))
+    return {site: {"count": n, "seconds": round(s, 6), "bytes": b}
+            for site, (n, s, b) in sorted(total.items())}
+
+
+# ------------------------------------------------- the row being charged
+# The kernel row of the operator executing on this thread (a contextvar,
+# like the ambient tracer): host_read and the compile listener add to it.
+_CHARGED: "contextvars.ContextVar" = contextvars.ContextVar(
+    "trino_tpu_kernel_row", default=None)
+
+
+@contextlib.contextmanager
+def charge_to(row: dict):
+    """Charge this thread's device->host reads and compiles to ``row``
+    (``new_kernel_row`` shape) until the block ends; nests."""
+    token = _CHARGED.set(row)
+    try:
+        yield row
+    finally:
+        _CHARGED.reset(token)
+
+
+def host_read(x, site: str):
+    """THE blocking device->host read of the served path: ``x`` as a numpy
+    array. A numpy input (or a Python scalar) returns at once and counts
+    nothing. A device array is fetched under an explicit
+    ``jax.transfer_guard_device_to_host("allow")`` (so the process's guard
+    stays silent about it and speaks only of reads that bypass this
+    function; a third of ``jax.device_get``'s cost a read), timed, and added
+    (count, seconds, bytes) to the charged kernel row and to its
+    ``hostSyncSites[site]``; ``site`` is a short static label of the
+    call site. A read of ``MIN_STORED_SPAN_S`` and more is also stored as
+    a ``host/sync`` span, back-to-back reads of one site as one span
+    (``trace.record_burst``)."""
+    import numpy as np
+
+    if isinstance(x, (np.ndarray, np.generic, int, float, bool)):
+        return np.asarray(x)
+    import jax  # lint: allow(jnp-in-host-module) the served path's one device->host read lives with the ledger that counts it; imported on first use, never at module import
+
+    from trino_tpu.obs import trace as tracing
+
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    if isinstance(x, jax.core.Tracer):
+        return x  # under a trace there is nothing to read: the caller's
+        # conversion raises jax's own concretization error
+    ann = tracing.annotation("host/sync")
+    start = time.time()
+    t0 = time.perf_counter()
+    with jax.transfer_guard_device_to_host("allow"):
+        out = np.asarray(x)
+    seconds = time.perf_counter() - t0
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    nbytes = int(out.nbytes)
+    row = _CHARGED.get()
+    if row is not None:
+        row["hostSyncs"] += 1
+        row["hostSyncS"] += seconds
+        row["d2hBytes"] += nbytes
+        have = row["hostSyncSites"].setdefault(site, [0, 0.0, 0])
+        have[0] += 1
+        have[1] += seconds
+        have[2] += nbytes
+    if seconds >= tracing.MIN_STORED_SPAN_S:
+        tracing.record_burst("host/sync", start, seconds, site, nbytes)
+    return out
+
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_hooks_lock = threading.Lock()
+_hooks_installed = False
+
+
+def _on_compile(event: str, duration: float, **_kw) -> None:
+    """``jax.monitoring`` listener: an XLA backend compile (or its load
+    from the persistent cache) on this thread, charged to the executing
+    operator and stored as an ``xla/compile`` span."""
+    if event != _BACKEND_COMPILE:
+        return
+    from trino_tpu.obs import trace as tracing
+
+    row = _CHARGED.get()
+    if row is not None:
+        row["compiles"] += 1
+        row["compileS"] += duration
+    tracing.record("xla/compile", time.time() - duration, duration)
+
+
+def install_process_hooks() -> None:
+    """Once per process, at a server's start: the compile listener and the
+    GC pause recorder (obs/trace.py). Nothing is registered at import."""
+    global _hooks_installed
+    with _hooks_lock:
+        if _hooks_installed:
+            return
+        _hooks_installed = True
+    import jax  # lint: allow(jnp-in-host-module) registers the compile listener at a server's start, in a process that runs the engine; never at module import
+
+    from trino_tpu.obs import trace as tracing
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    tracing.GC_RECORDER.install()
 
 
 class DeviceProfiler:
@@ -206,6 +357,9 @@ class DeviceProfiler:
                     - float(row.get("deviceS", 0.0)))
                 if overhead > 0:
                     M.KERNEL_DISPATCH_OVERHEAD.inc(overhead, op)
+            for site, body in sync_sites_of(stamped).items():
+                M.HOST_SYNCS.inc(body["count"], site)
+                M.HOST_SYNC_SECONDS.inc(body["seconds"], site)
         except Exception:  # noqa: BLE001 — accounting never fails work
             pass
 
@@ -249,8 +403,7 @@ class DeviceProfiler:
             rows = []
             for qid, store in stores.items():
                 for agg in store.values():
-                    row = dict(agg)
-                    row["queryId"] = qid
+                    row = copy_kernel_row(agg, queryId=qid)
                     row["dispatchOverheadS"] = round(
                         max(0.0, row["wallS"] - row["deviceS"]), 6)
                     rows.append(row)

@@ -382,6 +382,23 @@ KERNEL_DISPATCH_OVERHEAD = REGISTRY.counter(
     "per-operator wall minus device seconds (host dispatch overhead — "
     "the number fragment megakernels must beat), folded from the kernel "
     "ledger at query completion", ("operator",))
+# blocking device->host reads (obs/devprofiler.py host_read), by the static
+# label of the call site; folded from the kernel ledger like the launches
+HOST_SYNCS = REGISTRY.counter(
+    "trino_tpu_host_syncs_total",
+    "blocking device-to-host reads by call site, folded from the kernel "
+    "ledger at query completion", ("site",))
+HOST_SYNC_SECONDS = REGISTRY.counter(
+    "trino_tpu_host_sync_seconds_total",
+    "host seconds blocked in device-to-host reads by call site, folded "
+    "from the kernel ledger at query completion", ("site",))
+# process-wide collector pauses (obs/trace.py GcRecorder); read from the
+# recorder's totals when the page renders, never per collection
+GC_PAUSE_SECONDS = REGISTRY.counter(
+    "trino_tpu_gc_pause_seconds_total",
+    "seconds the Python garbage collector held the interpreter lock (no "
+    "thread of this process ran Python meanwhile), by generation",
+    ("generation",))
 COMPILE_SECONDS_TIERED = REGISTRY.histogram(
     "trino_tpu_compile_seconds",
     "per-event jit/Pallas compile seconds by execution tier and "
@@ -798,6 +815,25 @@ def refresh_process_gauges() -> None:
     PROCESS_THREADS.set(_threading.active_count())
     for gen, st in enumerate(gc.get_stats()):
         PROCESS_GC_COLLECTIONS.set(int(st.get("collections", 0)), str(gen))
+    _publish_gc_pauses()
+
+
+_gc_published: Dict[int, float] = {}
+
+
+def _publish_gc_pauses() -> None:
+    """Move what the GC recorder has counted since the last render into
+    ``trino_tpu_gc_pause_seconds_total``."""
+    try:
+        from trino_tpu.obs.trace import GC_RECORDER
+    except ImportError:  # loaded as a standalone file by the doc gate
+        return
+    with RENDER_LOCK:
+        for gen, total in list(GC_RECORDER.total_s.items()):
+            delta = total - _gc_published.get(gen, 0.0)
+            if delta > 0:
+                GC_PAUSE_SECONDS.inc(delta, str(gen))
+                _gc_published[gen] = total
 
 
 def render_registry() -> str:

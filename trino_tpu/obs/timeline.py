@@ -48,6 +48,32 @@ Phases (the label set of ``trino_tpu_query_phase_seconds``)::
                           (outside the wall, beside client-drain)
     client-drain          post-terminal result fetches (outside the wall)
     unattributed          wall not covered by any span (the visible gap)
+
+Level two (``detail``): each phase's instants are split again by the
+finest DETAIL span open at that instant, so that ``device-execute`` is no
+longer one number. Detail spans are recorded where the work happens
+(``DETAIL_LABELS``: ``operator/<Kind>`` in ``Executor.execute``,
+``host/sync`` in ``devprofiler.host_read``, ``task/output`` in
+``server/task.py``, ``process/gc`` from the process's ``gc.callbacks``,
+the staging sub-spans, the compiles, the exchange pulls). The keys are
+``"<phase>/<label>"``, exclusive, and sum to the phase::
+
+    op:<Kind>     an operator's SELF time: nested operator spans give each
+                  instant to the innermost one
+    host-sync     the host blocked in a device->host read
+    task-output   a worker's output path after the fragment body: compact,
+                  partition, chunk, serialise, enqueue or segment write
+    compile       an XLA compile (or its load from the persistent cache)
+    scan, decode, transfer, host-cache   the staging engine's stages
+    pull          an exchange pull or spool read with nothing finer open
+    gc-pause      the garbage collector held the interpreter lock
+    remainder     no detail span open. Under ``device-execute`` that is the
+                  coordinator waiting on its workers and the executor's
+                  unspanned glue, NOT device compute: no phase here is
+                  device time (the device trace has that)
+
+Level one does not read the detail spans: ``phases`` is computed from the
+same span names, priorities and sweep with or without them.
 """
 from __future__ import annotations
 
@@ -134,14 +160,47 @@ SPAN_PHASE: Dict[str, Tuple[int, str]] = {
     "result/spool": (_P_RESULT, "result-serialization"),
     "segment/write": (_P_RESULT, "result-serialization"),
     "segments/collect": (_P_EXECUTE, "device-execute"),
-    # the execution windows: their exclusive remainder is device compute
-    # on this process (root-fragment body, fast-path executor run)
+    # the execution windows (root-fragment body, fast-path executor run):
+    # their exclusive remainder is this process's executor at work or, on
+    # the served path, the coordinator WAITING while its workers compute,
+    # write their output and HTTP moves it: host wall, not device compute
     "execute/root-fragment": (_P_EXECUTE, "device-execute"),
     "execute/coordinator-local": (_P_EXECUTE, "device-execute"),
     "fastpath/execute": (_P_EXECUTE, "device-execute"),
+    # The three leaf kinds of level two, here for whoever names an
+    # interval by the span that covers it (the benchmark names an idle gap
+    # by SPAN_PHASE[span][1], the lowest [0] winning a tie): each is more
+    # specific than any window it sits in. Their phase is none of PHASES,
+    # so level one does not sweep them. Operator spans have no entry: they
+    # nest, and the outermost would cover every gap its children cover.
+    "process/gc": (-3, "gc-pause"),
+    "host/sync": (-2, "host-sync"),
+    "task/output": (-1, "task-output"),
 }
 
 _N_PRIORITIES = _P_SYNTH + 1
+
+# Level two. span name -> (rank, label): where several detail spans are
+# open the lowest rank wins, and within a rank the one opened last (the
+# innermost of nested operators, which is what makes an operator's share
+# its self time). Operators rank below the leaves they contain and above
+# the pulls that run beside them on other threads.
+_OPERATOR_PREFIX = "operator/"
+_R_OPERATOR = 4
+DETAIL_LABELS: Dict[str, Tuple[int, str]] = {
+    "process/gc": (0, "gc-pause"),
+    "host/sync": (1, "host-sync"),
+    "device/compile": (2, "compile"),
+    "xla/compile": (2, "compile"),
+    "staging/scan": (2, "scan"),
+    "staging/decode": (2, "decode"),
+    "staging/transfer": (2, "transfer"),
+    "staging/host-cache": (2, "host-cache"),
+    "task/output": (3, "task-output"),
+    "exchange/pull": (5, "pull"),
+    "spool/read": (5, "pull"),
+}
+REMAINDER = "remainder"
 
 
 @dataclasses.dataclass
@@ -157,6 +216,9 @@ class QueryTimeline:
     # spooled result protocol: terminal -> last segment fetch/ack seen by
     # the coordinator (outside the wall, like client-drain)
     segment_fetch_s: float = 0.0
+    # level two: "<phase>/<label>" -> exclusive seconds, summing to the
+    # phase (in-wall phases and unattributed; zero entries left out)
+    detail: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def coverage(self) -> float:
@@ -170,9 +232,15 @@ class QueryTimeline:
         phases["segment-fetch"] = round(self.segment_fetch_s, 6)
         phases["client-drain"] = round(self.client_drain_s, 6)
         phases["unattributed"] = round(self.unattributed_s, 6)
+        detail = dict(self.detail)
+        # the two phases beside the wall have no spans to split them by
+        for phase in ("segment-fetch", "client-drain"):
+            if phases[phase]:
+                detail[f"{phase}/{REMAINDER}"] = phases[phase]
         return {
             "wallS": round(self.wall_s, 6),
             "phases": phases,
+            "detail": detail,
             "unattributedS": round(self.unattributed_s, 6),
             "coverage": round(self.coverage, 4),
         }
@@ -200,7 +268,7 @@ def _segments(span_dicts: List[dict], t0: float, t1: float):
             continue
         mapped = ((_P_ROOT, "dispatch") if name == "query"
                   else SPAN_PHASE.get(name))
-        if mapped is None:
+        if mapped is None or mapped[1] not in PHASES:
             continue
         dur = s.get("durationS")
         end = t1 if dur is None else start + float(dur)
@@ -254,6 +322,9 @@ def compute_timeline(span_dicts: List[dict], created_at: float,
     cursor = t0
     i = 0
     n = len(events)
+    # what level two splits again: (start, end, phase) of each elementary
+    # interval, in order, the uncovered ones as "unattributed"
+    intervals: List[Tuple[float, float, str]] = []
     while i < n:
         t = events[i][0]
         if t > cursor:
@@ -263,7 +334,10 @@ def compute_timeline(span_dicts: List[dict], created_at: float,
                     span_len = t - cursor
                     phases[live_phase[prio]] += span_len
                     attributed += span_len
+                    intervals.append((cursor, t, live_phase[prio]))
                     break
+            else:
+                intervals.append((cursor, t, "unattributed"))
             cursor = t
         while i < n and events[i][0] == t:
             _, delta, prio, phase = events[i]
@@ -271,8 +345,66 @@ def compute_timeline(span_dicts: List[dict], created_at: float,
             if delta > 0:
                 live_phase[prio] = phase
             i += 1
+    if t1 > cursor:
+        intervals.append((cursor, t1, "unattributed"))
     unattributed = max(0.0, wall - attributed)
-    return QueryTimeline(wall, phases, unattributed, client_drain_s)
+    return QueryTimeline(wall, phases, unattributed, client_drain_s,
+                         detail=_detail(intervals, span_dicts, t1))
+
+
+def _detail(intervals: List[Tuple[float, float, str]],
+            span_dicts: List[dict], t1: float) -> Dict[str, float]:
+    """Level two: split level one's intervals by the finest detail span
+    open at each instant (``DETAIL_LABELS``; the lowest rank, then the one
+    opened last), ``remainder`` where none is. One pass over the intervals
+    and the detail spans' boundaries, both in time order."""
+    events: List[Tuple[float, int, int]] = []   # (time, +1 | -1, span index)
+    spans: List[Tuple[int, float, str]] = []    # (rank, -start, label)
+    for s in span_dicts:
+        name, start = s.get("name") or "", s.get("start")
+        if start is None:
+            continue
+        if name.startswith(_OPERATOR_PREFIX):
+            rank, label = _R_OPERATOR, "op:" + name[len(_OPERATOR_PREFIX):]
+        elif name in DETAIL_LABELS:
+            rank, label = DETAIL_LABELS[name]
+        else:
+            continue
+        dur = s.get("durationS")
+        end = t1 if dur is None else start + float(dur)
+        if end <= start:
+            continue
+        events.append((start, 1, len(spans)))
+        events.append((end, -1, len(spans)))
+        spans.append((rank, -start, label))
+    events.sort(key=lambda e: e[0])
+    detail: Dict[str, float] = {}
+    open_spans: Dict[int, Tuple[int, float, str]] = {}
+    i, n = 0, len(events)
+
+    def apply_until(t: float) -> None:
+        nonlocal i
+        while i < n and events[i][0] <= t:
+            _, delta, idx = events[i]
+            if delta > 0:
+                open_spans[idx] = spans[idx]
+            else:
+                open_spans.pop(idx, None)
+            i += 1
+
+    for a, b, phase in intervals:
+        apply_until(a)
+        cursor = a
+        while cursor < b:
+            nxt = events[i][0] if i < n and events[i][0] < b else b
+            label = (min(open_spans.values())[2] if open_spans
+                     else REMAINDER)
+            key = f"{phase}/{label}"
+            detail[key] = detail.get(key, 0.0) + (nxt - cursor)
+            cursor = nxt
+            apply_until(cursor)
+    # as it is served: sorted, to the nanosecond, zero entries left out
+    return {k: round(v, 9) for k, v in sorted(detail.items()) if v > 0.0}
 
 
 def observe_phases(timeline_dict: dict) -> None:

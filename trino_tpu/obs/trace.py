@@ -21,8 +21,10 @@ Two usage surfaces:
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
+import gc
 import os
 import threading
 import time
@@ -40,6 +42,29 @@ def _hex_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
 
 
+# One clock: every span is also a ``jax.profiler.TraceAnnotation`` of its
+# name, so a profiler session (the benchmark's, an operator's) holds the
+# program's spans in the xplane on the trace's own clock, above the device
+# operations. Outside a session an annotation costs one flag test. jax is
+# imported on first use: this module stays stdlib-only at import.
+_ANNOTATION = None
+
+
+def annotation(name: str):
+    """A profiler annotation of ``name``, running from its construction
+    until ``__exit__`` (on whichever thread), or None where jax is not
+    installed."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    return _ANNOTATION(name) if _ANNOTATION else None
+
+
 class Span:
     """One recorded operation: identity, tree position, wall interval,
     attributes. ``end`` is None while the span is open. The start/end
@@ -48,7 +73,7 @@ class Span:
     mid-span cannot produce negative or inflated span times."""
 
     __slots__ = ("span_id", "parent_id", "name", "attributes", "start",
-                 "end", "_t0", "duration")
+                 "end", "_t0", "duration", "_annotation")
 
     def __init__(self, name: str, parent_id: Optional[str],
                  attributes: Optional[dict] = None):
@@ -56,6 +81,7 @@ class Span:
         self.parent_id = parent_id
         self.name = name
         self.attributes: Dict[str, object] = dict(attributes or {})
+        self._annotation = annotation(name)
         self.start = time.time()
         self._t0 = time.perf_counter()
         self.end: Optional[float] = None
@@ -75,8 +101,28 @@ class Span:
         if self.end is None:
             self.end = time.time()
             self.duration = time.perf_counter() - self._t0
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
             return True
         return False
+
+    @classmethod
+    def completed(cls, name: str, parent_id: Optional[str], start: float,
+                  duration: float, attributes: Optional[dict] = None
+                  ) -> "Span":
+        """A span recorded once its work is over (``start`` wall-clock,
+        ``duration`` measured by the caller on the monotonic clock)."""
+        sp = cls.__new__(cls)
+        sp.span_id = _hex_id(8)
+        sp.parent_id = parent_id
+        sp.name = name
+        sp.attributes = dict(attributes or {})
+        sp._annotation = None
+        sp.start = start
+        sp._t0 = 0.0
+        sp.end = start + duration
+        sp.duration = duration
+        return sp
 
     @property
     def duration_s(self) -> Optional[float]:
@@ -106,6 +152,10 @@ class _NoopSpan:
 
 
 NOOP_SPAN = _NoopSpan()
+
+# a timed event shorter than this is counted where it happens and not
+# stored as a span
+MIN_STORED_SPAN_S = 50e-6
 
 # per-tracer span storage cap (satellite of the phase-ledger PR): a
 # pathological query — a streaming producer emitting a span per batch,
@@ -147,7 +197,22 @@ class Tracer:
         close on a different thread, e.g. async pulls)."""
         if parent_id is None:
             parent_id = self.current_span_id() or self.root_parent_id
-        sp = Span(name, parent_id, attributes)
+        return self._store(Span(name, parent_id, attributes))
+
+    def record_span(self, name: str, start: float, duration_s: float,
+                    parent_id: Optional[str] = None, **attributes) -> Span:
+        """Store a span whose work is already over: the caller timed it
+        and decided it was worth a slot (``host/sync`` keeps only reads of
+        ``MIN_STORED_SPAN_S`` and more, so the cap is not spent on the
+        thousands that return at once)."""
+        if parent_id is None:
+            parent_id = self.current_span_id() or self.root_parent_id
+        # not mirrored into the flight recorder: a statement's hundreds of
+        # reads would turn its 512-record ring over several times
+        return self._store(Span.completed(name, parent_id, start, duration_s,
+                                          attributes))
+
+    def _store(self, sp: Span) -> Span:
         with self._lock:
             if len(self._spans) >= self.max_spans:
                 # cap reached: the span still times and parents correctly
@@ -243,6 +308,115 @@ def span(name: str, **attributes):
     finally:
         _CURRENT.reset(token)
         tracer.end_span(sp)
+
+
+def record(name: str, start: float, duration_s: float, **attributes) -> None:
+    """Ambient :meth:`Tracer.record_span`; nothing without a tracer."""
+    cur = _CURRENT.get()
+    if cur is not None:
+        cur[0].record_span(name, start, duration_s, parent_id=cur[1],
+                           **attributes)
+
+
+# A run of back-to-back reads is ONE span: the worker's output path reads a
+# page column by column, hundreds of times a statement, and a span for each
+# would fill the tracer's cap and the coordinator's heap with records that
+# say one thing. The span starts with the burst's first read and lasts as
+# long as its reads took TOGETHER: the glue between them stays with the
+# enclosing span, so the ledger's ``host-sync`` is blocked time and no more.
+BURST_GAP_S = 1e-3
+_burst = threading.local()
+
+
+def record_burst(name: str, start: float, duration_s: float, site: str,
+                 nbytes: int) -> None:
+    """Ambient :meth:`Tracer.record_span` for a blocking read: adds to
+    this thread's previous ``name`` span when that has the same parent and
+    ``site`` and its last read ended at most ``BURST_GAP_S`` before
+    ``start`` (``durationS``, ``reads``, ``bytes`` add up); a new span
+    otherwise."""
+    cur = _CURRENT.get()
+    if cur is None:
+        return
+    tracer, parent_id = cur
+    last = getattr(_burst, "last", None)
+    if last is not None and last[0] is tracer:
+        sp = last[1]
+        if (sp.parent_id == parent_id and sp.attributes["site"] == site
+                and 0.0 <= start - last[2] <= BURST_GAP_S):
+            sp.duration += duration_s
+            sp.end = sp.start + sp.duration
+            sp.attributes = {"site": site,
+                             "reads": sp.attributes["reads"] + 1,
+                             "bytes": sp.attributes["bytes"] + nbytes}
+            _burst.last = (tracer, sp, start + duration_s)
+            return
+    sp = tracer.record_span(name, start, duration_s, parent_id=parent_id,
+                            site=site, reads=1, bytes=nbytes)
+    _burst.last = (tracer, sp, start + duration_s)
+
+
+# ------------------------------------------------- process-wide GC pauses
+# A collection holds the interpreter lock: no thread of the process runs
+# Python until it ends, whichever statement it serves. So pauses are kept
+# per process and joined to a statement's span export by time
+# (server/coordinator.py ``_warm_timeline``): the witness that the ledger's
+# long gaps with statements in flight lacked.
+GC_RING_CAPACITY = 4096
+
+
+class GcRecorder:
+    """``gc.callbacks`` hook: (wall start, seconds, generation) of every
+    collection of ``MIN_STORED_SPAN_S`` and more in a bounded ring, and
+    the seconds of ALL of them per generation (plain adds; the registry
+    reads the totals when it renders)."""
+
+    def __init__(self, capacity: int = GC_RING_CAPACITY):
+        self.pauses: "collections.deque" = collections.deque(maxlen=capacity)
+        self.total_s: Dict[int, float] = {}
+        self._t0: Optional[float] = None
+        self._annotation = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        # collections do not nest and run under the interpreter lock: one
+        # start is open at a time
+        if phase == "start":
+            self._annotation = annotation("process/gc")
+            self._t0 = time.perf_counter()
+            return
+        if self._t0 is None:
+            return
+        pause = time.perf_counter() - self._t0
+        self._t0 = None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        gen = int(info.get("generation", 0))
+        self.total_s[gen] = self.total_s.get(gen, 0.0) + pause
+        if pause >= MIN_STORED_SPAN_S:
+            self.pauses.append((time.time() - pause, pause, gen))
+
+    def install(self) -> None:
+        """Idempotent: servers call it at start."""
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def spans_between(self, t0: float, t1: float,
+                      parent_id: Optional[str] = None) -> List[dict]:
+        """``process/gc`` span records (``Span.to_dict`` shape, children of
+        ``parent_id``) of the pauses that overlap the wall interval
+        [t0, t1]. Newest first, stopping at the first pause that ended
+        before ``t0``: a statement pays for its own wall, not the ring."""
+        out = []
+        for start, pause, gen in reversed(self.pauses):
+            if start + pause <= t0:
+                break
+            if start < t1:
+                out.append(Span.completed("process/gc", parent_id, start,
+                                          pause, {"generation": gen}).to_dict())
+        return out[::-1]
+
+
+GC_RECORDER = GcRecorder()
 
 
 # -------------------------------------------------------- tree assembly

@@ -136,6 +136,7 @@ def fused_match_rows(
     )
 
 
+@jax.named_scope("fused_probe_unique")
 def fused_probe_unique(
     build_keys: List[Lowered],
     build_sel: Optional[jnp.ndarray],
@@ -151,6 +152,7 @@ def fused_probe_unique(
     return jnp.maximum(m, 0), matched
 
 
+@jax.named_scope("fused_membership")
 def fused_membership(
     build_keys: List[Lowered],
     build_sel: Optional[jnp.ndarray],

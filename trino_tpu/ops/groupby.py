@@ -24,6 +24,7 @@ from trino_tpu.ops import ranks, scans
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]  # (vals, valid|None)
 
 
+@jax.named_scope("group_plan")
 def group_plan(
     keys: List[Lowered], sel: Optional[jnp.ndarray], payloads=()
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, List[jnp.ndarray]]:
@@ -60,6 +61,7 @@ def group_plan(
     return order, gid_sorted, num_groups, sorted_payloads
 
 
+@jax.named_scope("gather_group_keys")
 def gather_group_keys(keys: List[Lowered], rep: jnp.ndarray) -> List[Lowered]:
     """Group-key output columns: gather each key at the representative row
     (rep indexes original row order; empty slots carry rep == n, clipped).

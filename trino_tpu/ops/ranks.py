@@ -86,6 +86,7 @@ def _key_fields(key: jnp.ndarray):
 
 
 @jax.jit
+@jax.named_scope("sort_digits")
 def _digits(sort_keys) -> Tuple[jnp.ndarray, ...]:
     """The keys as int32 digits, most significant first: consecutive fields
     packed into as few 32-bit words as hold them, rows padded to
@@ -109,10 +110,12 @@ def _digits(sort_keys) -> Tuple[jnp.ndarray, ...]:
 
 
 @jax.jit
+@jax.named_scope("sort_pass")
 def _sort_pass(digit: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.sort((digit[perm], perm), num_keys=1, is_stable=True)[1]
 
 
+@jax.named_scope("lex_argsort32")
 def lex_argsort32(sort_keys: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable lexicographic argsort (most significant key first), int32
     indices: one ``_sort_pass`` per digit, least significant first."""
@@ -152,6 +155,7 @@ def stable_sort(operands, num_keys: int) -> List[jnp.ndarray]:
 
 
 @jax.jit
+@jax.named_scope("gather_all")
 def _gather_all(arrays, idx):
     groups: dict = {}
     for i, a in enumerate(arrays):

@@ -48,6 +48,7 @@ def _highest(dtype):
     return jnp.iinfo(dtype).max
 
 
+@jax.named_scope("scan_cumsum")
 def cumsum(x: jnp.ndarray, dtype=None) -> jnp.ndarray:
     """``jnp.cumsum(x, dtype=dtype)`` of a 1-D array (same result dtype)."""
     out = jax.eval_shape(lambda a: jnp.cumsum(a, dtype=dtype),
@@ -55,10 +56,12 @@ def cumsum(x: jnp.ndarray, dtype=None) -> jnp.ndarray:
     return _two_level(jax.lax.cumsum, jnp.add, 0, x.astype(out), False)
 
 
+@jax.named_scope("scan_cummax")
 def cummax(x: jnp.ndarray) -> jnp.ndarray:
     return _two_level(jax.lax.cummax, jnp.maximum, _lowest(x.dtype), x, False)
 
 
+@jax.named_scope("scan_cummin")
 def cummin(x: jnp.ndarray, reverse: bool = False) -> jnp.ndarray:
     return _two_level(jax.lax.cummin, jnp.minimum, _highest(x.dtype), x,
                       reverse)

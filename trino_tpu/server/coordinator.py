@@ -883,7 +883,8 @@ class QueryExecution:
         self.state.set("FINISHING")
         self.columns = fragments[-1].root.column_names
         if result_page is not None:
-            self._materialize_result(session, result_page)
+            with self._root_executor.charging(fragments[-1].root):
+                self._materialize_result(session, result_page)
 
     def _cleanup_spool(self) -> None:
         """Drop this query's spooled task outputs (reference: exchange
@@ -938,7 +939,8 @@ class QueryExecution:
         dispatch plane by silently materializing in process memory."""
         from trino_tpu.obs import metrics as M
 
-        est = int(page.live_count()) * int(page.row_byte_estimate())
+        est = (int(page.live_count("result-rows"))
+               * int(page.row_byte_estimate()))
         cfg = self._spool_config(session)
         cap = int(session.properties.get("inline_result_max_bytes",
                                          256 << 20))
@@ -1191,13 +1193,15 @@ class QueryExecution:
             ex.memory.owner = f"query:{self.query_id}"
             page = ex.execute_checked(root)
             if reason is not None:
-                sp.set("rows", page.live_count())
+                with ex.charging(root):
+                    sp.set("rows", page.live_count("result-rows"))
         self._local_executor = ex  # EXPLAIN ANALYZE annotation source
         self.columns = list(root.column_names)
         # same spool/inline decision as the distributed tail: the
         # protocol choice is plan-shape-independent — a fast-path or
         # local-catalog export spools from the coordinator's own store
-        self._materialize_result(session, page)
+        with ex.charging(root):
+            self._materialize_result(session, page)
         self._note_local_stats(ex, time.perf_counter() - t0)
         ex.memory.release()
 
@@ -1371,6 +1375,15 @@ class QueryExecution:
         try:
             from trino_tpu.obs.timeline import compute_timeline
 
+            # the collector holds the interpreter lock, whoever it runs
+            # for: the process's pauses that overlap this statement's wall
+            # join its span export (the trace endpoint shows them, the
+            # ledger's detail names them)
+            root = next((sp.span_id for sp in self.tracer.spans()
+                         if sp.name == "query"), None)
+            self.extra_spans = list(self.extra_spans) + (
+                tracing.GC_RECORDER.spans_between(
+                    self.created_at, self.ended_at, parent_id=root))
             spans = (self.tracer.to_dicts() + list(self.extra_spans)
                      + self.worker_spans(
                          timeout=self.COMPLETION_PULL_TIMEOUT))
@@ -1593,7 +1606,7 @@ class QueryExecution:
         """The ``GET /v1/query/{id}/profile`` payload: merged kernel
         rows, this query's compile-ledger events, the phase ledger, and
         recent utilization samples from the coordinator's profiler."""
-        from trino_tpu.obs.devprofiler import DEVICE_PROFILER
+        from trino_tpu.obs.devprofiler import DEVICE_PROFILER, sync_sites_of
 
         folded = getattr(self, "_kernels_folded", False)
         kernels = (DEVICE_PROFILER.kernel_rows(self.query_id)
@@ -1602,6 +1615,8 @@ class QueryExecution:
             "queryId": self.query_id,
             "state": self.state.get(),
             "kernels": kernels,
+            # the kernels' blocking device->host reads by call site
+            "hostSyncSites": sync_sites_of(kernels),
             "compiles": DEVICE_PROFILER.compile_rows(
                 query_id=self.query_id),
             "utilization": DEVICE_PROFILER.utilization_rows(limit=8),
@@ -2515,11 +2530,13 @@ class CoordinatorServer:
         # device profiler (obs/devprofiler.py): same first-server-wins
         # identity stamp; compile-ledger events mirror into the flight
         # recorder so postmortems show recompile storms
-        from trino_tpu.obs.devprofiler import DEVICE_PROFILER
+        from trino_tpu.obs.devprofiler import (
+            DEVICE_PROFILER, install_process_hooks)
 
         if not DEVICE_PROFILER.node_id:
             DEVICE_PROFILER.node_id = "coordinator"
         DEVICE_PROFILER.attach_recorder(self.recorder)
+        install_process_hooks()  # compile listener + GC pause recorder
         # data-plane flow ledger (obs/flowledger.py): same
         # first-server-wins identity stamp; retried transfers mirror
         # into the flight recorder so postmortems show flaky links

@@ -312,6 +312,9 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
                             - float(r.get("deviceS", 0.0))))),
             int(r.get("inputBytes", 0)), int(r.get("outputBytes", 0)),
             bool(r.get("estimated", False)),
+            int(r.get("hostSyncs", 0)), float(r.get("hostSyncS", 0.0)),
+            int(r.get("d2hBytes", 0)), int(r.get("compiles", 0)),
+            float(r.get("compileS", 0.0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

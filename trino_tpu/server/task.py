@@ -13,6 +13,7 @@ and upstream task locations per RemoteSourceNode fragment id.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import pickle
 import threading
@@ -22,10 +23,12 @@ from typing import Dict, List, Optional
 
 from trino_tpu.data.page import Page
 from trino_tpu.data.serde import serialize_page
-from trino_tpu.exec.executor import Executor
+from trino_tpu.exec.executor import Executor, operator_kind, page_platform
 from trino_tpu.exec.operator_stats import OperatorStats
 from trino_tpu.obs import metrics as M
 from trino_tpu.obs import trace as tracing
+from trino_tpu.obs.devprofiler import (
+    charge_to, copy_kernel_row, host_read, merge_kernel_rows, new_kernel_row)
 from trino_tpu.server.buffer import OutputBuffer, PartitionedOutputBuffer
 from trino_tpu.server.statemachine import StateMachine, task_state_machine
 from trino_tpu.sql.planner import plan as P
@@ -269,8 +272,6 @@ class SqlTask:
                     self.operator_stats[nid] = _dc.replace(st)
                 else:
                     have.add(st)
-            from trino_tpu.obs.devprofiler import merge_kernel_rows
-
             merge_kernel_rows(
                 self.kernel_stats,
                 list(getattr(ex, "kernel_stats", {}).values()))
@@ -289,6 +290,33 @@ class SqlTask:
                 1 for d in ex.scan_cache.values() if d == "hit")
             self.device_cache_misses += sum(
                 1 for d in ex.scan_cache.values() if d == "miss")
+
+    @contextlib.contextmanager
+    def _charge_root(self, page: Page):
+        """Charge this thread's device->host reads to the kernel row of
+        the fragment's root node, which produced ``page``: work the task
+        does on a page once the executor that made it has been retired
+        (the output path, the streaming fold's state pages)."""
+        root = self.request.fragment_root
+        row = new_kernel_row(str(root.id), operator_kind(root), "eager")
+        row["platform"] = page_platform(page)
+        try:
+            with charge_to(row):
+                yield
+        finally:
+            if row["hostSyncs"] or row["compiles"]:
+                with self._stats_lock:
+                    merge_kernel_rows(self.kernel_stats, [row])
+
+    @contextlib.contextmanager
+    def _output_path(self, page: Page):
+        """The worker's output path after the fragment body, as one
+        ``task/output`` span: compact, partition, chunk, serialise,
+        enqueue (a wait at the buffer's watermark included) or segment
+        write. The coordinator, or the consuming task, spends this time
+        waiting."""
+        with tracing.span("task/output"), self._charge_root(page):
+            yield
 
     def stats_snapshot(self) -> dict:
         """Point-in-time task stats for ``GET /v1/task/{id}/status`` —
@@ -328,7 +356,7 @@ class SqlTask:
                 "deviceCacheHits": self.device_cache_hits,
                 "deviceCacheMisses": self.device_cache_misses,
                 "operatorStats": ops,
-                "kernelStats": [dict(self.kernel_stats[k])
+                "kernelStats": [copy_kernel_row(self.kernel_stats[k])
                                 for k in sorted(self.kernel_stats)],
             }
             if part_bytes is not None:
@@ -454,8 +482,16 @@ class SqlTask:
             ex, splits=self.total_splits,
             input_rows=sum(ex.scan_stats.values()) + remote_rows,
             device_s=device_s)
+        with self._output_path(page):
+            self._write_output(page)
+        self.state.set("FINISHED")
+
+    def _write_output(self, page: Page) -> None:
+        """The bulk body's output path: compact, then by the task's shape
+        partition, spool or stream the chunks into the output buffer."""
         from trino_tpu.exec.memory import page_bytes
 
+        req = self.request
         page = page.compact()
         self.flushing_bytes = page_bytes(page)  # held through the drain
         with self._stats_lock:
@@ -482,7 +518,6 @@ class SqlTask:
                 for pb in frames:
                     self.output.enqueue_partition(pid, pb)
             self.output.set_complete()
-            self.state.set("FINISHED")
             return
         if self._result_writer is not None:
             # spooled result output: serialized chunks roll straight into
@@ -497,7 +532,6 @@ class SqlTask:
                 sp.set("segments", len(self.result_segments))
                 sp.set("rows", int(page.live_count()))
             self.output.set_complete()
-            self.state.set("FINISHED")
             return
         # STREAMING output: size-bounded chunks enqueue as they
         # serialize, so consumers pull chunk 0 while chunk 1 encodes,
@@ -518,7 +552,6 @@ class SqlTask:
             for c in _chunk_pages(page, chunk_rows):
                 self.output.enqueue(serialize_page(c))  # blocks at watermark
         self.output.set_complete()
-        self.state.set("FINISHED")
 
     # ------------------------------------------------------- streaming loop
     @staticmethod
@@ -625,7 +658,7 @@ class SqlTask:
         # inflate the skew signal the re-planner reads
         n = page.num_rows
         live = (np.ones(n, bool) if page.sel is None
-                else np.asarray(page.sel).astype(bool))
+                else host_read(page.sel, "partition").astype(bool))
         counts = np.bincount(np.asarray(pids)[live],
                              minlength=req.consumer_count)
         with self._stats_lock:
@@ -654,6 +687,10 @@ class SqlTask:
         """Partition-aware enqueue of one output page (shared by the
         streaming paths: per-batch chains, per-split scans, and the fold
         path's finalization)."""
+        with self._output_path(out):
+            self._enqueue_chunks(out, part_channels)
+
+    def _enqueue_chunks(self, out: Page, part_channels) -> None:
         if out.num_rows == 0 or out.live_count() == 0:
             return
         from trino_tpu.exec.memory import page_bytes
@@ -703,7 +740,9 @@ class SqlTask:
                 ex = FragmentExecutor(session, {scan.id: [split]}, {})
                 self._track_executor(ex)
                 t0 = time.perf_counter()
-                out = ex.execute_checked(req.fragment_root).compact()
+                page = ex.execute_checked(req.fragment_root)
+                with self._output_path(page):
+                    out = page.compact()
                 split_s = time.perf_counter() - t0
                 device_s += split_s
                 staged_rows += sum(ex.scan_stats.values())
@@ -766,7 +805,9 @@ class SqlTask:
             ex = FragmentExecutor(session, {}, {src.fragment_id: [page]})
             self._track_executor(ex)
             t0 = time.perf_counter()
-            out = ex.execute_checked(req.fragment_root).compact()
+            page = ex.execute_checked(req.fragment_root)
+            with self._output_path(page):
+                out = page.compact()
             batch_s = time.perf_counter() - t0
             device_clock[0] += batch_s
             self._retire_executor(ex, input_rows=batch_rows, device_s=batch_s)
@@ -806,8 +847,9 @@ class SqlTask:
                 ex = FragmentExecutor(session, {}, {})
                 self._track_executor(ex)
                 t0 = time.perf_counter()
-                out = ex.aggregate_intermediate(node, page).compact()
-                ex.raise_errors()
+                with self._charge_root(page):
+                    out = ex.aggregate_intermediate(node, page).compact()
+                    ex.raise_errors()
                 fold_s = time.perf_counter() - t0
                 device_clock[0] += fold_s
                 record_agg_stats(ex, fold_s, batch_rows, out)
@@ -840,8 +882,11 @@ class SqlTask:
                     running = Page.all_dead(src.types)
                 ex = FragmentExecutor(session, {}, {})
                 t0 = time.perf_counter()
-                out = ex.aggregate_final(node, running).compact()
-                ex.raise_errors()
+                with self._charge_root(running):
+                    final = ex.aggregate_final(node, running)
+                    ex.raise_errors()
+                with self._output_path(final):
+                    out = final.compact()
                 final_s = time.perf_counter() - t0
                 device_clock[0] += final_s
                 record_agg_stats(ex, final_s, int(running.num_rows), out,
@@ -1021,16 +1066,17 @@ def _canonical_partition_ids(page: Page, channels, parts: int,
         col = page.columns[ch]
         if col.type.is_varchar and col.dictionary is not None:
             vocab_hash = _vocab_hashes(col.dictionary)
-            codes = np.asarray(col.values)
+            codes = host_read(col.values, "partition")
             k = vocab_hash[np.clip(codes, 0, len(vocab_hash) - 1)]
             k = np.where(codes < 0, np.uint64(_NULL_HASH), k)
         else:
             # low limb only: equal values share it and hi-limb presence is
             # data-dependent per producer — mixing hi would break cross-
             # producer placement consistency (see exec/memory.py)
-            k = _mix64_np(np.asarray(col.values).astype(np.int64))
+            k = _mix64_np(host_read(col.values, "partition").astype(np.int64))
         if col.nulls is not None:
-            k = np.where(np.asarray(col.nulls), np.uint64(_NULL_HASH), k)
+            k = np.where(host_read(col.nulls, "partition"),
+                         np.uint64(_NULL_HASH), k)
         h = _mix64_np(h ^ k)
     return (h % np.uint64(parts)).astype(np.int64)
 

@@ -83,11 +83,13 @@ class WorkerServer:
         # the process device profiler (obs/devprofiler.py): same
         # first-server-wins identity stamp; compile-ledger events mirror
         # into the flight recorder so postmortems show recompile storms
-        from trino_tpu.obs.devprofiler import DEVICE_PROFILER
+        from trino_tpu.obs.devprofiler import (
+            DEVICE_PROFILER, install_process_hooks)
 
         if not DEVICE_PROFILER.node_id:
             DEVICE_PROFILER.node_id = self.node_id
         DEVICE_PROFILER.attach_recorder(self.recorder)
+        install_process_hooks()  # compile listener + GC pause recorder
         # the process flow ledger (obs/flowledger.py): same
         # first-server-wins identity stamp; retried transfers mirror into
         # the flight recorder so postmortems show flaky links
