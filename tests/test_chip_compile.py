@@ -76,6 +76,35 @@ def test_compiled_query_body_compiles(one_chip, query):
     assert compiled.memory_analysis() is not None
 
 
+def test_q1_partial_aggregation_compiles_as_one_program(one_chip, monkeypatch):
+    """The served path's hottest body: q1's partial aggregation (eight
+    aggregates, two long-decimal limb sums, capacity 6) over one staged
+    split of 524,288 rows, the spec taken from tpch.tiny and the program
+    lowered for the v5e."""
+    from trino_tpu import Session
+    from trino_tpu.exec import executor
+    from trino_tpu.exec.query import plan_sql
+    from trino_tpu.sql.planner import plan as P
+
+    session = Session()
+    (agg,) = [n for n in P.walk_plan(plan_sql(session, BENCH_SQL["q1"]))
+              if isinstance(n, P.AggregationNode)]
+    partial = P.AggregationNode(
+        agg.source, list(agg.group_channels), agg.aggregates, step="partial")
+    program = executor.direct_aggregation
+    seen = []
+    monkeypatch.setattr(
+        executor, "direct_aggregation",
+        lambda spec, arrays: seen.append((spec, arrays)) or program(spec, arrays))
+    ex = executor.Executor(session)
+    ex.aggregate_partial(partial, ex.execute(agg.source))
+    (spec, arrays), = seen
+    shapes = [jax.ShapeDtypeStruct((524_288,), a.dtype, sharding=one_chip)
+              for a in arrays]
+    compiled = program.lower(spec, shapes).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_wide_sort_compiles_as_one_two_operand_sort(one_chip):
     """q3's ORDER BY at sf1 — 6 keys and 9 payloads over 31,869 rows,
     which as ONE ``lax.sort`` took the v5e compiler ~660 s on the chip

@@ -210,6 +210,10 @@ SYSTEM_TABLES = {
         ("d2h_bytes", "bigint"),
         ("compiles", "bigint"),
         ("compile_seconds", "double"),
+        # eager-tier aggregation bodies that ran as ONE compiled program
+        # (direct layout) / that dispatched their primitives one by one
+        ("agg_programs", "bigint"),
+        ("agg_eager", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

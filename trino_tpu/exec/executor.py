@@ -17,7 +17,9 @@ deferred-error contract of ops/expr_lower.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
+import types
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -28,10 +30,14 @@ from trino_tpu import types as T
 from trino_tpu.data.page import Column, Page
 from trino_tpu.exec import memory as _mem
 from trino_tpu.exec.operator_stats import OperatorStats
+from trino_tpu.exec.page_tree import (
+    StaticSpec, attach_dictionaries, flatten_page, static_spec,
+    unflatten_page,
+)
 from trino_tpu.obs import metrics as M
 from trino_tpu.obs import trace as tracing
 from trino_tpu.obs.devprofiler import (
-    charge_to, host_read, merge_platforms, new_kernel_row)
+    charge_to, count_charged, host_read, merge_platforms, new_kernel_row)
 from trino_tpu.ops import aggregate as agg_ops
 from trino_tpu.ops import expr_lower as L
 from trino_tpu.ops import fused_join as fused_ops
@@ -751,6 +757,9 @@ class Executor:
         AccumulatorCompiler intermediate states through an exchange).
         State column types follow plan._acc_types so the page can cross the
         wire (serde needs faithful dtypes)."""
+        fused = self._as_one_program("aggregate_partial", node, page)
+        if fused is not None:
+            return fused
         payload_arrays, slots = self._agg_payloads(node.aggregates, page.columns)
         layout, part_sel, payloads_l, sel_l = self.group_structure(
             node.group_channels, page, payload_arrays
@@ -780,6 +789,9 @@ class Executor:
 
     def aggregate_final(self, node: P.AggregationNode, page: Page) -> Page:
         """Final aggregation over gathered partial-state pages."""
+        fused = self._as_one_program("aggregate_final", node, page)
+        if fused is not None:
+            return fused
         k = len(node.group_channels)
         # state columns ride the grouping sort as payloads (layout space)
         payload_arrays: List = []
@@ -828,6 +840,9 @@ class Executor:
         here they are the fold step of the streaming consumer loop: state
         pages accumulate per arriving micro-batch, memory stays
         O(groups + batch) no matter how much the producer emits)."""
+        fused = self._as_one_program("aggregate_intermediate", node, page)
+        if fused is not None:
+            return fused
         k = len(node.group_channels)
         payload_arrays: List = []
         state_slots: List = []
@@ -1173,6 +1188,9 @@ class Executor:
             spilled = self._maybe_spill_aggregation(node, page)
             if spilled is not None:
                 return spilled
+        fused = self._as_one_program("aggregate_page", node, page)
+        if fused is not None:
+            return fused
         n = page.num_rows
         sel = page.sel
         if n == 0:
@@ -1240,6 +1258,60 @@ class Executor:
                 )
             )
         return Page(out_cols, out_sel, page.replicated)
+
+    @staticmethod
+    def _sum_fits_int64(page: Page, channel: int, n: int) -> bool:
+        """Whether connector stats bound a sum of ``n`` values of the column
+        inside int64 (with headroom)."""
+        vrange = page.columns[channel].vrange
+        if vrange is None:
+            return False
+        b = max(abs(int(vrange[0])), abs(int(vrange[1])))
+        return b * max(n, 1) < 2**62
+
+    def _as_one_program(self, entry: str, node: P.AggregationNode,
+                        page: Page) -> Optional[Page]:
+        """The eager tier's direct-layout aggregation as ONE compiled
+        program per page (``direct_aggregation``), in place of some
+        hundred eagerly dispatched reductions; None where the body has to
+        run as it stands: a traced tier (already inside a program), the
+        sorted and presorted layouts (a data-dependent sort in the
+        middle), and aggregates that regroup or read a dictionary's
+        content. Either way the executing operator's kernel row counts
+        the body (``aggPrograms`` / ``aggEager``)."""
+        if not self.eager_tier:
+            return None
+        # final and intermediate pages carry their keys first
+        channels = (node.group_channels if entry in
+                    ("aggregate_page", "aggregate_partial")
+                    else list(range(len(node.group_channels))))
+        columns = None
+        if ((not channels or self._direct_strides(channels, page) is not None)
+                and not any(c.distinct or c.function in _UNFUSED_AGGREGATES
+                            for c in node.aggregates)):
+            arrays, page_spec = flatten_page(page)
+            columns = static_spec(page_spec)  # None: nested columns
+        if columns is None:
+            count_charged("aggEager")
+            return None
+        spec = _AggregationSpec(
+            entry, tuple(node.group_channels), tuple(node.aggregates),
+            tuple(node.source.output_types)
+            if entry == "aggregate_partial" else (),
+            columns,
+            tuple(c.arg_channel for c in node.aggregates
+                  if c.function == "sum" and entry == "aggregate_page"
+                  and P._is_long_decimal(c.output_type)
+                  and self._sum_fits_int64(page, c.arg_channel,
+                                           page.num_rows)))
+        out_arrays, out_spec, flags = direct_aggregation(spec, arrays)
+        count_charged("aggPrograms")
+        self.errors.extend(zip(out_spec.notes, flags))
+        out = attach_dictionaries(
+            unflatten_page(out_spec.page_spec(), out_arrays), page)
+        for i, c in enumerate(channels):
+            out.columns[i].vrange = page.columns[c].vrange
+        return out
 
     def _gathered_key_cols(self, page: Page, channels, layout) -> List[Column]:
         """Output group-key columns gathered at each slot's representative
@@ -1424,12 +1496,8 @@ class Executor:
                 # int64 accumulation is exact only when stats bound the
                 # total; otherwise take the limb path (correct for the full
                 # p38 range instead of silently wrapping)
-                src = page.columns[call.arg_channel]
-                bound_ok = False
-                if src.vrange is not None:
-                    b = max(abs(int(src.vrange[0])), abs(int(src.vrange[1])))
-                    bound_ok = b * max(layout.n, 1) < 2**62
-                need128 = not bound_ok
+                need128 = not self._sum_fits_int64(
+                    page, call.arg_channel, layout.n)
             if need128:
                 (s_hi, s_lo), nonempty = agg_ops.agg_sum_128(
                     layout, vals_l, hi_l, valid_l, sel
@@ -2341,6 +2409,61 @@ class Executor:
 
     def _exec_OutputNode(self, node: P.OutputNode) -> Page:
         return self.execute(node.source)
+
+
+# Aggregates that keep the eager body on a direct layout: the nested
+# outputs regroup by a sort, and checksum hashes a dictionary's CONTENT.
+_UNFUSED_AGGREGATES = frozenset(
+    {"array_agg", "histogram", "map_agg", "checksum"})
+
+
+@dataclasses.dataclass(frozen=True)
+class _AggregationSpec:
+    """The static side of ``direct_aggregation``: all an aggregation body
+    reads besides its page's arrays. ``jax.jit`` memoises on it, so it
+    holds no dictionary content and no value range: splits that differ
+    only in those share one program."""
+
+    entry: str  # the Executor method whose body the program is
+    group_channels: Tuple[int, ...]
+    aggregates: Tuple[P.AggregateCall, ...]
+    source_types: Tuple[T.Type, ...]  # partial: accumulator types follow
+    page: StaticSpec
+    # argument channels whose long-decimal sum stats bound inside int64
+    sum_fits: Tuple[int, ...]
+
+
+class _TracedAggregation(Executor):
+    """Executor's aggregation methods with nothing behind them but an
+    error list: what ``direct_aggregation`` traces. Not the eager tier, so
+    it neither spills nor takes the seam it was called from."""
+
+    eager_tier = False
+
+    def __init__(self, sum_fits):
+        self.errors = []
+        self.sum_fits = sum_fits
+
+    def _sum_fits_int64(self, page, channel, n):
+        return channel in self.sum_fits
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def direct_aggregation(spec: _AggregationSpec, arrays):
+    """One aggregation body over one page as one XLA program: (output
+    arrays, their StaticSpec with the deferred errors' codes as notes, the
+    errors' flags)."""
+    body = _TracedAggregation(spec.sum_fits)
+    node = types.SimpleNamespace(
+        group_channels=list(spec.group_channels),
+        aggregates=list(spec.aggregates),
+        source=types.SimpleNamespace(output_types=list(spec.source_types)))
+    out = getattr(body, spec.entry)(
+        node, unflatten_page(spec.page.page_spec(), arrays))
+    out_arrays, out_spec = flatten_page(out)
+    return (out_arrays,
+            static_spec(out_spec, tuple(code for code, _ in body.errors)),
+            [flag for _, flag in body.errors])
 
 
 @dataclasses.dataclass

@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from trino_tpu import types as T
@@ -119,3 +120,63 @@ def unflatten_page(spec: PageSpec, arrays: List[jnp.ndarray]) -> Page:
         cols.append(c)
     sel = arrays[i] if spec.has_sel else None
     return Page(cols, sel, live_prefix=spec.live_prefix)
+
+
+# ------------------------------------------------ hashable specs (jit keys)
+@dataclasses.dataclass(frozen=True)
+class InputDictionary:
+    """Stands in a traced program for the dictionary of its input page's
+    column ``channel``: the program reads the size alone, and its caller
+    puts the dictionary itself back on every output column that carries
+    this (``attach_dictionaries``). So pages whose dictionaries differ in
+    content share one program."""
+
+    channel: int
+    size: int
+
+    def __len__(self) -> int:
+        return self.size
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class StaticSpec:
+    """What ``jax.jit`` can hash of a flat page: per column (type, has
+    nulls, has hi limb, dictionary stand-in), then ``has_sel``. Value
+    ranges, sort order and dictionary content stay outside. A pytree node
+    without leaves, so a program can also RETURN one beside its arrays,
+    with whatever else it learnt while tracing (``notes``)."""
+
+    columns: Tuple[tuple, ...]
+    has_sel: bool
+    notes: tuple = ()
+
+    def page_spec(self) -> PageSpec:
+        return PageSpec(
+            [ColSpec(t, d, has_nulls, has_hi=has_hi)
+             for t, has_nulls, has_hi, d in self.columns], self.has_sel)
+
+
+def static_spec(spec: PageSpec, notes: tuple = ()) -> Optional[StaticSpec]:
+    """``spec`` as a StaticSpec, a real dictionary becoming the stand-in
+    of its channel; None for a page with nested columns."""
+    if any(c.children is not None for c in spec.col_specs):
+        return None
+    columns = []
+    for channel, c in enumerate(spec.col_specs):
+        d = c.dictionary
+        if isinstance(d, Dictionary):
+            d = InputDictionary(channel, len(d))
+        columns.append((c.type, c.has_nulls, c.has_hi, d))
+    return StaticSpec(tuple(columns), spec.has_sel, notes)
+
+
+def attach_dictionaries(out: Page, source: Page) -> Page:
+    """``out``, a traced program's output page, with each InputDictionary
+    replaced by the dictionary of ``source``'s column it stands for."""
+    columns = [
+        dataclasses.replace(
+            c, dictionary=source.columns[c.dictionary.channel].dictionary)
+        if isinstance(c.dictionary, InputDictionary) else c
+        for c in out.columns]
+    return Page(columns, out.sel, source.replicated)

@@ -36,7 +36,10 @@ blocking device->host read goes through :func:`host_read` (``hostSyncs``,
 ``hostSyncSites``) and every XLA backend compile is heard by one
 ``jax.monitoring`` listener (``compiles``, ``compileS``); both charge
 the row of the operator that is executing on the thread
-(:func:`charge_to`).
+(:func:`charge_to`). So do the aggregation bodies of the eager tier
+(:func:`count_charged`): ``aggPrograms`` ran as one compiled program
+(exec/executor.py ``direct_aggregation``), ``aggEager`` dispatched their
+primitives one by one.
 
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
@@ -82,7 +85,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "launches": 0, "wallS": 0.0, "deviceS": 0.0, "inputBytes": 0,
            "outputBytes": 0, "estimated": estimated, "platform": "",
            "hostSyncs": 0, "hostSyncS": 0.0, "d2hBytes": 0,
-           "compiles": 0, "compileS": 0.0, "hostSyncSites": {}}
+           "compiles": 0, "compileS": 0.0, "hostSyncSites": {},
+           "aggPrograms": 0, "aggEager": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -116,7 +120,7 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
         if agg is None:
             agg = dst[key] = new_kernel_row(key[0], key[1], key[2], key[3])
         for field in ("launches", "inputBytes", "outputBytes", "hostSyncs",
-                      "d2hBytes", "compiles"):
+                      "d2hBytes", "compiles", "aggPrograms", "aggEager"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))
@@ -153,6 +157,14 @@ def charge_to(row: dict):
         yield row
     finally:
         _CHARGED.reset(token)
+
+
+def count_charged(field: str) -> None:
+    """One more of ``field`` on the kernel row being charged on this
+    thread, if there is one."""
+    row = _CHARGED.get()
+    if row is not None:
+        row[field] += 1
 
 
 def host_read(x, site: str):

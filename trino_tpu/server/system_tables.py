@@ -315,6 +315,7 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("hostSyncs", 0)), float(r.get("hostSyncS", 0.0)),
             int(r.get("d2hBytes", 0)), int(r.get("compiles", 0)),
             float(r.get("compileS", 0.0)),
+            int(r.get("aggPrograms", 0)), int(r.get("aggEager", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

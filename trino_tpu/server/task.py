@@ -304,7 +304,8 @@ class SqlTask:
             with charge_to(row):
                 yield
         finally:
-            if row["hostSyncs"] or row["compiles"]:
+            if (row["hostSyncs"] or row["compiles"] or row["aggPrograms"]
+                    or row["aggEager"]):
                 with self._stats_lock:
                     merge_kernel_rows(self.kernel_stats, [row])
 
