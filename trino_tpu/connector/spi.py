@@ -420,6 +420,18 @@ class Connector:
         connector embeds it in Split.info so scan() sees it."""
         raise NotImplementedError
 
+    def enforced_constraint(self, schema: str, table: str, constraint):
+        """The part of an advisory ``constraint`` that changes what
+        ``get_splits`` / ``scan`` return for this table (reference:
+        ConnectorMetadata.applyFilter hands back the enforced and the
+        remaining filter the same way). The device and host caches key a
+        staged scan by this part alone (trino_tpu/devcache/keys.py): two
+        statements whose constraints differ only in domains the connector
+        ignores read one resident copy. A connector that cannot say keeps
+        the default, all of it: a domain that changed nothing then only
+        splits the key, which is always correct."""
+        return constraint
+
     # --- data (ConnectorPageSource) ---
     def scan(self, split: Split, columns: List[str], constraint=None) -> Dict[str, ColumnData]:
         """``constraint`` as in get_splits — advisory row-reduction only."""

@@ -214,6 +214,12 @@ SYSTEM_TABLES = {
         # (direct layout) / that dispatched their primitives one by one
         ("agg_programs", "bigint"),
         ("agg_eager", "bigint"),
+        # what the device cache did for a scan (devcache/keys.py
+        # cached_stage): lookups served from / admitted to the pool, and
+        # the bytes the scan copied host -> device (0 on a hit)
+        ("cache_hits", "bigint"),
+        ("cache_misses", "bigint"),
+        ("staged_bytes", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

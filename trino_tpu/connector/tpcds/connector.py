@@ -68,6 +68,10 @@ class TpcdsConnector(spi.Connector):
         # generated data is a pure function of (table, scale factor)
         return "immutable"
 
+    def enforced_constraint(self, schema: str, table: str, constraint):
+        # the generator reads no domain: splits and rows are the table's
+        return None
+
     def get_splits(
         self, schema: str, table: str, target_splits: int, constraint=None,
         handle=None,

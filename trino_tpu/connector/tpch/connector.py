@@ -166,6 +166,18 @@ class TpchConnector(spi.Connector):
         hi = n if high is None else min(n, key_to_rows(high)[1])
         return [(lo, hi)] if lo < hi else []
 
+    def enforced_constraint(self, schema: str, table: str, constraint):
+        """Only the domain on the table's monotone key column narrows what
+        ``get_splits`` / ``scan`` generate (``_key_ranges``); every other
+        domain is left to the engine's filter."""
+        from trino_tpu.connector.predicate import TupleDomain
+
+        mono = self._MONOTONE.get(table)
+        if constraint is None or mono is None:
+            return None
+        dom = constraint.domain(mono[0])
+        return None if dom.is_all() else TupleDomain({mono[0]: dom})
+
     def get_splits(
         self, schema: str, table: str, target_splits: int, constraint=None,
         handle=None,

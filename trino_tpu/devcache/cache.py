@@ -105,8 +105,9 @@ def _default_budget() -> int:
 @dataclasses.dataclass(frozen=True)
 class CacheKey:
     """Identity of one staged device artifact. ``signature`` digests the
-    projection, pushdown handle, effective constraint, and the host-applied
-    dynamic domains (trino_tpu/devcache/keys.py); ``shard`` distinguishes
+    projection, pushdown handle, the connector-enforced part of the
+    effective constraint, and the host-applied dynamic domains
+    (trino_tpu/devcache/keys.py); ``shard`` distinguishes
     staging shapes of the same table (whole-table vs a worker task's split
     set vs an SPMD mesh width); ``conn_token`` pins process-local
     connectors (the memory connector's version counter is instance state —

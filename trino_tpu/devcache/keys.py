@@ -1,19 +1,24 @@
 """Cache-key construction for the device table cache.
 
-A staged artifact is reusable only when EVERYTHING that shaped it
-matches: the projection (column subset), the pushdown handle (an
+A staged artifact is reusable only when EVERYTHING that shaped its
+BYTES matches: the projection (column subset), the pushdown handle (an
 ``apply_limit``/``apply_topn``/``apply_aggregation`` handle changes what
-the connector returns), the effective scan constraint (static pushdown ∩
-available dynamic-filter domains — connectors may prune splits/rows from
-it, advisorily but deterministically), and the subset of dynamic domains
-the engine physically applied host-side before the transfer (the
-compiled tier applies only STRONG domains at staging and enforces weak
-ones on device — two executors with the same constraint but different
-host-applied sets stage different pages). All of that digests into
-``CacheKey.signature``; ``data_version`` and the shard shape ride
-alongside. Anything not provably stable — an unversioned connector, an
-active transaction overlay, a handle whose repr is identity-based —
-yields ``None``: bypass, never guess.
+the connector returns), the part of the effective scan constraint (static
+pushdown ∩ available dynamic-filter domains) that the connector ENFORCED
+on the rows it returned (``Connector.enforced_constraint``: the tpch
+generator narrows by its monotone key column and by nothing else; a
+connector that cannot say keeps the default, all of it), and the subset
+of dynamic domains the engine physically applied host-side before the
+transfer (the compiled tier applies only STRONG domains at staging and
+enforces weak ones on device — two executors with the same constraint but
+different host-applied sets stage different pages). All of that digests
+into ``CacheKey.signature``; ``data_version`` and the shard shape ride
+alongside. A domain the connector merely received shaped nothing: the
+Filter above the scan and the join's dynamic-filter mask enforce it after
+the lookup, so one resident copy of a table's projected columns serves
+every binding of a statement. Anything not provably stable — an
+unversioned connector, an active transaction overlay, a handle whose repr
+is identity-based — yields ``None``: bypass or over-key, never guess.
 """
 from __future__ import annotations
 
@@ -81,7 +86,8 @@ def _stable_repr(obj) -> Optional[str]:
 
 def scan_signature(node, constraint, applied_domains) -> Optional[str]:
     """Projection/pruning digest for one TableScanNode staging, or None
-    when any component has no stable content repr."""
+    when any component has no stable content repr. ``constraint`` is the
+    part the connector enforced, not all it was offered."""
     h = hashlib.blake2b(digest_size=16)
     h.update(repr(tuple(node.column_names)).encode())
     handle = getattr(node, "table_handle", None)
@@ -116,8 +122,9 @@ def splits_shard(splits: List) -> Optional[str]:
 def host_split_keys(session, node, constraint, applied_domains, splits):
     """Host-tier cache keys for a split list's decoded column sets (None
     per bypassed split). Identity = the scan signature (projection +
-    handle + constraint + host-APPLIED domain subset — the pruning baked
-    into the cached arrays) + each split's own boundary digest as the
+    handle + connector-enforced constraint + host-APPLIED domain subset —
+    the pruning baked into the cached arrays, so a split's decoded columns
+    are shared across bindings) + each split's own boundary digest as the
     shard, so the same split reached through ANY grouping (whole-table
     staging, a worker's assigned set, any SPMD mesh width) lands on one
     entry. The signature (which digests full dynamic-filter domains —
@@ -146,24 +153,36 @@ def cached_stage(session, node, constraint, applied_domains, shard, loader):
     ``loader() -> (value, rows, nbytes, splits)``; returns
     ``(CacheEntry, "hit"|"miss"|"bypass")`` — bypass wraps the loaded
     artifact in a transient (never-admitted) entry so callers read one
-    shape."""
+    shape. The executing scan's kernel row (obs/devprofiler.py) is charged
+    the disposition (``cacheHits`` / ``cacheMisses``; a bypass is neither)
+    and ``stagedBytes``, what this scan copied host -> device: 0 on a
+    hit."""
     import time
 
     from trino_tpu.devcache.cache import DEVICE_CACHE, CacheEntry
     from trino_tpu.obs import trace as tracing
+    from trino_tpu.obs.devprofiler import count_charged
 
     key = scan_cache_key(session, node, constraint, applied_domains,
                          shard=shard)
     if key is None:
         value, rows, nbytes, splits = loader()
         now = time.time()
-        return CacheEntry(None, value, rows, int(nbytes), splits,
-                          created_at=now, last_used_at=now), "bypass"
-    with tracing.span("device-cache/lookup", table=node.table) as sp:
-        ent, disposition = DEVICE_CACHE.lookup_or_stage(
-            key, loader, admit_bytes=admit_budget(session))
-        sp.set("result", disposition)
-        sp.set("bytes", ent.nbytes)
+        ent, disposition = CacheEntry(
+            None, value, rows, int(nbytes), splits,
+            created_at=now, last_used_at=now), "bypass"
+    else:
+        with tracing.span("device-cache/lookup", table=node.table) as sp:
+            ent, disposition = DEVICE_CACHE.lookup_or_stage(
+                key, loader, admit_bytes=admit_budget(session))
+            sp.set("result", disposition)
+            sp.set("bytes", ent.nbytes)
+    if disposition == "hit":
+        count_charged("cacheHits")
+    else:
+        if disposition == "miss":
+            count_charged("cacheMisses")
+        count_charged("stagedBytes", ent.nbytes)
     return ent, disposition
 
 
@@ -230,7 +249,8 @@ def scan_cache_key(session, node, constraint,
         return None
     if version is None:
         return None
-    sig = scan_signature(node, constraint, applied_domains or {})
+    enforced = conn.enforced_constraint(node.schema, node.table, constraint)
+    sig = scan_signature(node, enforced, applied_domains or {})
     if sig is None:
         return None
     return CacheKey(node.catalog, node.schema, node.table, str(version),

@@ -39,7 +39,10 @@ the row of the operator that is executing on the thread
 (:func:`charge_to`). So do the aggregation bodies of the eager tier
 (:func:`count_charged`): ``aggPrograms`` ran as one compiled program
 (exec/executor.py ``direct_aggregation``), ``aggEager`` dispatched their
-primitives one by one.
+primitives one by one. A scan's row counts what the device cache did for
+it (devcache/keys.py ``cached_stage``): ``cacheHits`` / ``cacheMisses``
+(lookups by disposition; a bypass counts neither) and ``stagedBytes``,
+the bytes it copied host -> device (0 on a hit).
 
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
@@ -63,8 +66,12 @@ from typing import Dict, List, Optional
 COMPILE_CAPACITY = 256
 # announce loop samples every 0.5 s -> ~2 minutes of per-node history
 UTILIZATION_CAPACITY = 240
-# per-query kernel rollups kept after the query folds (LRU)
-MAX_QUERY_PROFILES = 64
+# per-query kernel rollups kept after the query folds (LRU). A row is
+# about 0.5 KB as a dict and a statement folds 1 to 30 of them: 512
+# statements hold under 8 MB. A reader that asks for the last N answered
+# statements by ITS clock (the benchmark: 64, from 32 sender threads)
+# must find them whatever order the server reached their terminal states
+MAX_QUERY_PROFILES = 512
 
 TIERS = ("eager", "compiled", "spmd")
 
@@ -86,7 +93,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "outputBytes": 0, "estimated": estimated, "platform": "",
            "hostSyncs": 0, "hostSyncS": 0.0, "d2hBytes": 0,
            "compiles": 0, "compileS": 0.0, "hostSyncSites": {},
-           "aggPrograms": 0, "aggEager": 0}
+           "aggPrograms": 0, "aggEager": 0,
+           "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -120,7 +128,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
         if agg is None:
             agg = dst[key] = new_kernel_row(key[0], key[1], key[2], key[3])
         for field in ("launches", "inputBytes", "outputBytes", "hostSyncs",
-                      "d2hBytes", "compiles", "aggPrograms", "aggEager"):
+                      "d2hBytes", "compiles", "aggPrograms", "aggEager",
+                      "cacheHits", "cacheMisses", "stagedBytes"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))
@@ -159,12 +168,12 @@ def charge_to(row: dict):
         _CHARGED.reset(token)
 
 
-def count_charged(field: str) -> None:
-    """One more of ``field`` on the kernel row being charged on this
-    thread, if there is one."""
+def count_charged(field: str, amount: int = 1) -> None:
+    """``amount`` more of ``field`` on the kernel row being charged on
+    this thread, if there is one."""
     row = _CHARGED.get()
     if row is not None:
-        row[field] += 1
+        row[field] += amount
 
 
 def host_read(x, site: str):

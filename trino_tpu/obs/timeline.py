@@ -65,6 +65,7 @@ the staging sub-spans, the compiles, the exchange pulls). The keys are
                   partition, chunk, serialise, enqueue or segment write
     compile       an XLA compile (or its load from the persistent cache)
     scan, decode, transfer, host-cache   the staging engine's stages
+    cache-lookup  the device cache's LRU lookup and admission around them
     pull          an exchange pull or spool read with nothing finer open
     gc-pause      the garbage collector held the interpreter lock
     remainder     no detail span open. Under ``device-execute`` that is the
@@ -196,6 +197,8 @@ DETAIL_LABELS: Dict[str, Tuple[int, str]] = {
     "staging/decode": (2, "decode"),
     "staging/transfer": (2, "transfer"),
     "staging/host-cache": (2, "host-cache"),
+    # on a miss the loader's staging/* spans open later, inside it, and win
+    "device-cache/lookup": (2, "cache-lookup"),
     "task/output": (3, "task-output"),
     "exchange/pull": (5, "pull"),
     "spool/read": (5, "pull"),

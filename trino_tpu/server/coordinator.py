@@ -1601,16 +1601,25 @@ class QueryExecution:
         rows = self.kernel_rows_live()
         if rows:
             DEVICE_PROFILER.record_query_kernels(self.query_id, rows)
+        # set once the store holds them: profile_dict reads the store from
+        # here on, and tells "folded none" from "folded some, since aged
+        # out" by the count
+        self._kernel_rows_folded = len(rows)
 
-    def profile_dict(self) -> dict:
+    def profile_dict(self) -> Optional[dict]:
         """The ``GET /v1/query/{id}/profile`` payload: merged kernel
         rows, this query's compile-ledger events, the phase ledger, and
-        recent utilization samples from the coordinator's profiler."""
+        recent utilization samples from the coordinator's profiler.
+        ``None`` where the query folded kernel rows and they have since
+        left the profiler's LRU: "aged out" is never answered as a
+        statement that launched nothing (``"kernels": []``)."""
         from trino_tpu.obs.devprofiler import DEVICE_PROFILER, sync_sites_of
 
-        folded = getattr(self, "_kernels_folded", False)
-        kernels = (DEVICE_PROFILER.kernel_rows(self.query_id)
-                   if folded else self.kernel_rows_live())
+        folded = getattr(self, "_kernel_rows_folded", None)
+        kernels = (self.kernel_rows_live() if folded is None
+                   else DEVICE_PROFILER.kernel_rows(self.query_id))
+        if folded and not kernels:
+            return None
         return {
             "queryId": self.query_id,
             "state": self.state.get(),
@@ -3328,7 +3337,14 @@ def _make_handler(server: CoordinatorServer):
                 if q is None:
                     self._send(404, b'{"error": "no such query"}')
                     return
-                self._send(200, json.dumps(q.profile_dict()).encode())
+                profile = q.profile_dict()
+                if profile is None:
+                    self._send(404, json.dumps({"error": (
+                        "profile aged out: the query's kernel rows have "
+                        "left the device profiler's LRU "
+                        "(devprofiler.MAX_QUERY_PROFILES)")}).encode())
+                    return
+                self._send(200, json.dumps(profile).encode())
                 return
             m = _FLOWS_RE.match(url_parts.path)
             if m:

@@ -316,6 +316,8 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("d2hBytes", 0)), int(r.get("compiles", 0)),
             float(r.get("compileS", 0.0)),
             int(r.get("aggPrograms", 0)), int(r.get("aggEager", 0)),
+            int(r.get("cacheHits", 0)), int(r.get("cacheMisses", 0)),
+            int(r.get("stagedBytes", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:
