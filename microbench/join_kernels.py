@@ -5,7 +5,8 @@ Writes KERNELS_r06.json: per-size timings for the unique-key join kernels
 fused tier in ops/fused_join.py, the warm sorted-build merge, and — on
 TPU — the Pallas tiled merge), plus the overlapped-exchange case on
 multi-device meshes. ``--check`` runs the CPU tier-selection regression
-guard instead (see :func:`check`).
+guard instead (see :func:`check`); ``--compact [seed]`` times the listing
+of a mask's live rows alone (see :func:`compact_cases`).
 
 Why there is no Pallas linear-probe hash table here (the round-4 verdict's
 item 3, reference ``operator/FlatHash.java:42`` / ``join/PagesHash``):
@@ -52,7 +53,8 @@ def _harness(op, n_args):
         def step(i, carry):
             acc, a = carry
             x = a[0]
-            a0 = (x.at[0].set(jnp.where(i < 0, x[0] + 1, x[0])),) + a[1:]
+            a0 = (x.at[0].set(
+                jnp.where(i < 0, x[0] + 1, x[0]).astype(x.dtype)),) + a[1:]
             r = op(*a0)
             tot = jnp.float32(0)
             for o in (r if isinstance(r, tuple) else (r,)):
@@ -223,6 +225,60 @@ def overlap_case(n_per_shard: int = 1 << 18, blocks: int = 4):
     return res
 
 
+# (rows, kept slots, live share): Fragment 0 of q3 at SF 10 (a 60 x 2^20
+# row lineitem page, 2.7 % live); a scan split of tpch.sf1; and a whole
+# page listed (ops/segments.sorted_layout, the global array_agg)
+COMPACT_SHAPES = ((62_914_560, 2_097_152, 0.027), (524_288, 16_384, 0.027),
+                  (2_097_152, 2_097_152, 0.25))
+
+
+def scatter_positions(flags, size: int):
+    """The formulation ``ranks.true_positions`` was timed against: a prefix
+    count of the mask, then ONE scatter of every row's index to its rank
+    (dead rows to distinct out-of-bounds slots, dropped). n updates at the
+    scatter's ~7 ns an element."""
+    from trino_tpu.ops import scans
+
+    n = flags.shape[0]
+    rank = scans.cumsum(flags.astype(jnp.int32))
+    row = jnp.arange(n, dtype=jnp.int32)
+    target = jnp.where(flags, rank - 1, jnp.int32(max(n, size)) + row)
+    return (jnp.zeros((size,), jnp.int32)
+            .at[target].set(row, mode="drop", unique_indices=True))
+
+
+def compact_cases(seed: int = 7):
+    """Listing the first ``size`` live rows of a mask, as ``compact_to``
+    needs them: the stable sort by the dead flag it used until PR 31
+    against the two sort-free formulations tried for it (prefix counts and
+    a word select, kept as ``ranks.true_positions``; prefix counts and an
+    n-row scatter, above). All three return the same int32[size] wherever
+    a slot is under the live count, which is checked before timing."""
+    from trino_tpu.ops import ranks
+
+    rng = np.random.default_rng(seed)
+    out = {}
+    for n, size, share in COMPACT_SHAPES:
+        flags = jnp.asarray(rng.random(n) < share)
+        want = np.flatnonzero(np.asarray(flags))[:size]
+        cases = (
+            ("sort", lambda f: ranks.argsort32(~f)[:size], 2 if n > 1 << 24 else 8),
+            ("select", lambda f: ranks.true_positions(f, size, 0), 16),
+            ("scatter", lambda f: scatter_positions(f, size), 4 if n > 1 << 24 else 16),
+        )
+        res = {"live": int(want.shape[0])}
+        for name, op, k in cases:
+            got = np.asarray(jax.jit(op)(flags))[:want.shape[0]]
+            assert np.array_equal(got, want), (name, n, size)
+            res[name] = {"seconds": round(measure(op, (flags,), k=k), 6)}
+        for name in ("select", "scatter"):
+            res[name]["vs_sort"] = round(
+                res["sort"]["seconds"] / res[name]["seconds"], 2)
+        out[f"n={n},size={size},live_share={share}"] = res
+        print(f"[compact] {n} -> {size}: {res}", file=sys.stderr, flush=True)
+    return out
+
+
 def check(margin: float = 1.5, attempts: int = 3) -> int:
     """CPU-runnable tier-selection regression guard (``--check``):
 
@@ -319,6 +375,12 @@ def main():
     configure_compile_cache()
     if "--check" in sys.argv:
         raise SystemExit(check())
+    if "--compact" in sys.argv:
+        at = sys.argv.index("--compact") + 1
+        seed = int(sys.argv[at]) if at < len(sys.argv) else 7
+        print(json.dumps({"device": str(jax.devices()[0]), "seed": seed,
+                          "compact": compact_cases(seed)}))
+        return
     sizes = [(1 << 20, 1 << 19), (1 << 24, 1 << 22)]  # 1M and 16M probes
     result = {
         "device": str(jax.devices()[0]),

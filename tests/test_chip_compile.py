@@ -123,6 +123,23 @@ def test_wide_sort_compiles_as_one_two_operand_sort(one_chip):
     assert len(sorts) == 1 and sorts[0].count("s32[32768]") >= 2, sorts
 
 
+@pytest.mark.parametrize("n,size", [(62_914_560, 2_097_152),
+                                    (524_288, 16_384)])
+def test_true_positions_compiles_without_a_sort(one_chip, n, size):
+    """Fragment 0 of q3 at SF 10 compacts a 60 x 2^20 row page to 2^21
+    slots (and a scan split of ``tpch.sf1`` to a few thousand): the
+    positions of the kept rows lower for the v5e with no sort instruction,
+    in seconds, and inside the chip's memory beside the page."""
+    from trino_tpu.ops import ranks
+
+    mask = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(
+        lambda m: ranks.true_positions(m, size, 0)).lower(mask).compile()
+    assert not [line for line in compiled.as_text().splitlines()
+                if " sort(" in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 def test_spmd_hash_partitioned_q3_compiles_with_all_to_all(topo):
     """The SPMD tier's promise — shuffles are ICI collectives — checked
     in the program the v5e compiler emits. ``DistributedQuery`` stages onto

@@ -220,6 +220,9 @@ SYSTEM_TABLES = {
         ("cache_hits", "bigint"),
         ("cache_misses", "bigint"),
         ("staged_bytes", "bigint"),
+        # pages Executor.compact_to squeezed to their live rows, the
+        # positions listed from prefix counts (ops/ranks.py true_positions)
+        ("prefix_compactions", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

@@ -585,9 +585,8 @@ class Executor:
         return Page(page.columns, sel, page.replicated)
 
     def _exec_CompactNode(self, node: P.CompactNode) -> Page:
-        """Squeeze live rows into a smaller static-capacity page: ONE stable
-        payload-carrying sort by the dead flag (live rows first, original
-        order kept), then a static truncation to the capacity hint. Skipped
+        """Squeeze live rows into a smaller static-capacity page
+        (``compact_to``: live rows first, original order kept). Skipped
         when it cannot help (no selection mask, or capacity >= the page's
         rows — e.g. an SPMD shard already smaller than the global
         estimate). Overflow raises CAPACITY_EXCEEDED:cmp:<id> for the
@@ -599,13 +598,13 @@ class Executor:
         return self.compact_to(page, capacity, f"cmp:{node.id}")
 
     def compact_to(self, page: Page, capacity: int, key: str) -> Page:
-        """Squeeze live rows into a ``capacity``-slot page: ONE stable
-        key-only sort of (dead flag, iota) for the live-first permutation,
-        then ONE batched row-gather per dtype group at the first
-        ``capacity`` indices — gathering only the KEPT rows (capacity), not
-        all n, and never carrying the payload columns through the sort
-        network (a 6M-row multi-payload lax.sort costs ~5x the flag sort).
-        Original row order is kept (stable). Overflow raises
+        """Squeeze live rows into a ``capacity``-slot page: the positions
+        of the first ``capacity`` live rows from prefix counts of the mask
+        (``ranks.true_positions``: no sort, nothing n-sized touched at
+        random), then ONE batched row-gather per dtype group at them —
+        gathering only the KEPT rows (capacity), not all n. Original row
+        order is kept (positions ascend); slots past the live count hold
+        row 0's values under a False ``sel``. Overflow raises
         CAPACITY_EXCEEDED:<key> for the recompile-growth loop. Shared by
         CompactNode and the device-side dynamic-filter scans."""
 
@@ -620,8 +619,9 @@ class Executor:
         live = page.sel
         total = jnp.sum(live.astype(jnp.int32))
         self.errors.append((f"CAPACITY_EXCEEDED:{key}", total > capacity))
-        order = ranks_ops.argsort32(~live)
-        idx = order[:capacity]
+        idx = ranks_ops.true_positions(live, capacity)
+        if self.eager_tier:  # a traced tier would count its trace, not its runs
+            count_charged("prefixCompactions")
         arrays = []
         for c in page.columns:
             arrays.append(c.values)
@@ -1354,7 +1354,7 @@ class Executor:
         0, so cumsum(lengths) == starts for every live slot and the flat
         child aligns with no extra gather. The global (no GROUP BY) case
         rides the direct single-slot layout: live rows compact to a prefix
-        with one stable flag sort.
+        at their positions listed in order (``ranks.true_positions``).
 
         histogram / map_agg re-group on (group, key) pairs (ops/aggregate.py
         grouped_pairs): each distinct pair is one map entry; histogram's
@@ -1372,7 +1372,7 @@ class Executor:
                 flat, flat_valid, flat_hi = vals_l, valid_l, hi_l
                 count = jnp.int32(n)
             else:
-                order = ranks_ops.argsort32(~sel_l)
+                order = ranks_ops.true_positions(sel_l, n)
                 flat = vals_l[order]
                 flat_valid = valid_l[order] if valid_l is not None else None
                 flat_hi = hi_l[order] if hi_l is not None else None

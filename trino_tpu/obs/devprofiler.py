@@ -39,7 +39,10 @@ the row of the operator that is executing on the thread
 (:func:`charge_to`). So do the aggregation bodies of the eager tier
 (:func:`count_charged`): ``aggPrograms`` ran as one compiled program
 (exec/executor.py ``direct_aggregation``), ``aggEager`` dispatched their
-primitives one by one. A scan's row counts what the device cache did for
+primitives one by one, and ``prefixCompactions``, the pages
+``Executor.compact_to`` squeezed to their live rows (positions from
+prefix counts, ops/ranks.py ``true_positions``; a page it returns as it
+came counts nothing). A scan's row counts what the device cache did for
 it (devcache/keys.py ``cached_stage``): ``cacheHits`` / ``cacheMisses``
 (lookups by disposition; a bypass counts neither) and ``stagedBytes``,
 the bytes it copied host -> device (0 on a hit).
@@ -94,7 +97,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "hostSyncs": 0, "hostSyncS": 0.0, "d2hBytes": 0,
            "compiles": 0, "compileS": 0.0, "hostSyncSites": {},
            "aggPrograms": 0, "aggEager": 0,
-           "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0}
+           "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0,
+           "prefixCompactions": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -129,7 +133,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
             agg = dst[key] = new_kernel_row(key[0], key[1], key[2], key[3])
         for field in ("launches", "inputBytes", "outputBytes", "hostSyncs",
                       "d2hBytes", "compiles", "aggPrograms", "aggEager",
-                      "cacheHits", "cacheMisses", "stagedBytes"):
+                      "cacheHits", "cacheMisses", "stagedBytes",
+                      "prefixCompactions"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

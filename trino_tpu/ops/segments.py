@@ -100,17 +100,16 @@ def sorted_layout(
     """Layout from a group-contiguous permutation (ops/groupby.py).
 
     ``gid_sorted`` is DENSE and non-decreasing (run k has gid k), so slot
-    ranges need no rank search: compacting the run-boundary positions to
-    the front with one bool-key sort yields ``starts`` directly, and each
-    run ends where the next begins. One n-row 2-operand sort replaces the
-    2n-row combined rank sort plus its inverse-permutation sort."""
+    ranges need no rank search: the run-boundary positions, listed in
+    order from prefix counts (``ranks.true_positions``), ARE ``starts``,
+    and each run ends where the next begins. No sort."""
     n = order.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
     boundary = jnp.concatenate(
         [jnp.ones((1,), bool), gid_sorted[1:] != gid_sorted[:-1]]
     )
     nb = jnp.sum(boundary.astype(jnp.int32))
-    starts_seq = ranks.argsort32(~boundary)
+    starts_seq = ranks.true_positions(boundary, n)
     nn = jnp.int32(n)
     starts = jnp.where(pos < nb, starts_seq, nn)
     next_start = jnp.concatenate([starts_seq[1:], jnp.full((1,), nn, jnp.int32)])

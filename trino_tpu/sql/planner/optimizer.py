@@ -63,8 +63,9 @@ def optimize(root: P.OutputNode, session=None) -> P.OutputNode:
 
 # ------------------------------------------------------- compaction pass
 
-# only consider squeezing inputs this large (the payload sort that performs
-# the compaction has to pay for itself downstream)
+# only consider squeezing inputs this large (the compaction, a prefix count
+# of the mask and a row gather of the kept slots, has to pay for itself
+# downstream)
 COMPACT_MIN_SLOTS = 1 << 17
 COMPACT_MIN_RATIO = 2.0  # slots / estimated live rows
 
@@ -107,8 +108,8 @@ def insert_compactions(node: P.PlanNode, session) -> P.PlanNode:
     """Insert CompactNodes where cardinality estimates say the live rows
     are a small fraction of the page's slots AND a downstream operator
     (join / aggregation / window / set-op) would pay per-slot costs for the
-    dead ones. Sorts/TopN don't qualify: the compaction itself is one
-    payload sort, so compact-then-sort saves nothing over sorting.
+    dead ones. Sorts/TopN are not considered: whether squeezing the page
+    first pays for a sort of it has not been measured.
     Capacities are estimates; underestimates raise CAPACITY_EXCEEDED and
     the bucketed recompile loop doubles them (CompiledQuery.run)."""
     from trino_tpu.sql.planner import stats

@@ -208,7 +208,7 @@ class PushLimitThroughUnion(Rule):
 
 
 class PruneUnpayingCompact(Rule):
-    """Remove a CompactNode whose cost gate says the payload sort cannot
+    """Remove a CompactNode whose cost gate says the compaction cannot
     pay for itself: estimated live rows are NOT far below the input's slot
     count (the inverse of optimizer.insert_compactions' insertion gate —
     a stats-driven COST decision, reference: the iterative rules'
