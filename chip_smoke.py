@@ -14,9 +14,8 @@ One process owns the chip and starts no child. It builds an in-process
   which left its output on the accelerator) through WORKER TASKS
   (``fastPath == "distributed"``); one point query on ``orders`` through
   the coordinator-local fast path; then q3 again, cold vs warm.
-- phase ``compiled``: ``CompiledQuery.build`` + run of q1 — the tier
-  ``bench.py`` times — with its staged inputs and result columns checked to
-  live on the accelerator.
+- phase ``compiled``: ``CompiledQuery.build`` + run of q1, with its staged
+  inputs and result columns checked to live on the accelerator.
 - ``--chips 4`` runs ONLY phase ``spmd``: q3 as ``DistributedQuery`` on a
   mesh of the four devices, default plan and hash-partitioned plan.
 
@@ -41,15 +40,14 @@ import jax
 import numpy as np
 
 import tpch_reference as ref
-from bench import _SQL as BENCH_SQL
 from tests.tpch_sql import QUERIES as TPCH_SQL
 from trino_tpu.compile_cache import configure_compile_cache
 
 SERVED = {
-    "q1": (BENCH_SQL["q1"], ref.q1),
+    "q1": (TPCH_SQL[1], ref.q1),
     "q6": (TPCH_SQL[6], ref.q6),
-    "q3": (BENCH_SQL["q3"], ref.q3),
-    "q18": (BENCH_SQL["q18"], ref.q18),
+    "q3": (TPCH_SQL[3], ref.q3),
+    "q18": (TPCH_SQL[18], ref.q18),
 }
 WARM = "q3"  # served a second time: cold (compiles included) vs warm
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -221,7 +219,7 @@ def run_compiled(schema: str, emit, counter: CompileCounter) -> dict:
     want = ref.q1(schema)
     mark = counter.mark()
     t0 = time.perf_counter()
-    cq = CompiledQuery.build(session, plan_sql(session, BENCH_SQL["q1"]))
+    cq = CompiledQuery.build(session, plan_sql(session, TPCH_SQL[1]))
     for i, staged in enumerate(cq.input_arrays):
         _assert_on_device(f"compiled q1 staged input {i}", staged, platform)
     page = cq.run()
@@ -265,7 +263,7 @@ def run_spmd(schema: str, chips: int, emit,
                            f"JAX reports {len(devices)}")
     mesh = Mesh(np.array(devices), ("d",))
     session = Session(properties={"catalog": "tpch", "schema": schema})
-    sql = BENCH_SQL["q3"]
+    sql = TPCH_SQL[3]
     want = ref.q3(schema)
     # local = the same body on ONE device (the compiled tier: one program;
     # the eager tier would compile each of its sorts separately first)

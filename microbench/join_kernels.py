@@ -1,12 +1,12 @@
 """Join-kernel microbench: dense / legacy sort-merge / fused tier on TPU.
 
-Writes KERNELS_r06.json: per-size timings for the unique-key join kernels
-(ops/join.py dense_* and build_side/probe_unique baselines, the PR 8
-fused tier in ops/fused_join.py, the warm sorted-build merge, and — on
-TPU — the Pallas tiled merge), plus the overlapped-exchange case on
-multi-device meshes. ``--check`` runs the CPU tier-selection regression
-guard instead (see :func:`check`); ``--compact [seed]`` times the listing
-of a mask's live rows alone (see :func:`compact_cases`).
+Prints one JSON line (and writes it to a path, if one is given): per-size
+timings for the unique-key join kernels (ops/join.py dense_* and
+build_side/probe_unique baselines, the PR 8 fused tier in
+ops/fused_join.py, the warm sorted-build merge, and — on TPU — the Pallas
+tiled merge), plus the overlapped-exchange case on multi-device meshes.
+``--compact [seed]`` times the listing of a mask's live rows alone (see
+:func:`compact_cases`).
 
 Why there is no Pallas linear-probe hash table here (the round-4 verdict's
 item 3, reference ``operator/FlatHash.java:42`` / ``join/PagesHash``):
@@ -24,7 +24,7 @@ sort/merge-rank formulations for general keys, the direct-address table
 the identity map a perfect hash, and touching fewer rows in the first
 place (in-program dynamic filtering + stats-sized compaction).
 
-Run: python microbench/join_kernels.py  (TPU; ~2 min warm cache)
+Run: python microbench/join_kernels.py [out.json]  (TPU; ~2 min warm cache)
 """
 from __future__ import annotations
 
@@ -45,9 +45,9 @@ jax.config.update("jax_enable_x64", True)
 
 
 def _harness(op, n_args):
-    """fori-loop repetition harness (bench.py pattern): i-dependent
-    never-taken perturbation defeats hoisting, output folding defeats DCE;
-    per-op seconds = (t_2K - t_K) / K — sync/dispatch noise cancels."""
+    """fori-loop repetition harness: i-dependent never-taken perturbation
+    defeats hoisting, output folding defeats DCE; per-op seconds =
+    (t_2K - t_K) / K — sync/dispatch noise cancels."""
 
     def fn(args, k):
         def step(i, carry):
@@ -279,102 +279,10 @@ def compact_cases(seed: int = 7):
     return out
 
 
-def check(margin: float = 1.5, attempts: int = 3) -> int:
-    """CPU-runnable tier-selection regression guard (``--check``):
-
-    1. the cost gate must still pick the dense direct-address path for a
-       dense-keyed build and the fused tier for a sparse one (selection
-       drift = silent perf loss);
-    2. on the sparse case — where the gate selects the fused tier — the
-       fused kernel must not run more than ``margin`` slower than the
-       legacy sortmerge baseline it replaced (best of ``attempts`` to
-       absorb CI timing noise; the dense kernel is also reported for the
-       record).
-
-    Returns a process exit code (0 ok, 1 regression).
-    """
-    from trino_tpu import Session
-    from trino_tpu.data.page import Column, Page
-    from trino_tpu import types as T
-    from trino_tpu.exec.executor import Executor
-    from trino_tpu.obs import metrics as M
-    from trino_tpu.ops import fused_join as FJ
-    from trino_tpu.ops import join as J
-    from trino_tpu.sql.planner import plan as P
-
-    rng = np.random.default_rng(3)
-    n_probe, n_build = 1 << 17, 1 << 14
-    # --- selection: dense-keyed build -> dense tier
-    ex = Executor(Session())
-    dense_b = Page([Column(T.BIGINT, jnp.arange(n_build, dtype=jnp.int64),
-                           vrange=(0, n_build - 1))])
-    probe_p = Page([Column(
-        T.BIGINT,
-        jnp.asarray(rng.integers(0, n_build, n_probe).astype(np.int64)),
-        vrange=(0, n_build - 1))])
-    node = P.JoinNode(join_type="inner", left=None, right=None,
-                      left_keys=[0], right_keys=[0], right_unique=True)
-    before = {t: M.FUSED_JOIN_SELECTIONS.value(t)
-              for t in ("dense", "fused")}
-    ex.lookup_join(node, probe_p, dense_b)
-    if M.FUSED_JOIN_SELECTIONS.value("dense") != before["dense"] + 1:
-        print("CHECK FAIL: dense-keyed build no longer selects the dense "
-              "tier", file=sys.stderr)
-        return 1
-    # --- selection + timing: sparse build -> fused tier
-    sparse_span = 1 << 40  # far beyond DENSE_SPAN_MAX
-    bkeys_np = rng.choice(sparse_span, size=n_build, replace=False).astype(np.int64)
-    pk_np = np.concatenate([
-        rng.choice(bkeys_np, size=n_probe // 2),
-        rng.integers(0, sparse_span, size=n_probe - n_probe // 2),
-    ]).astype(np.int64)
-    sparse_b = Page([Column(T.BIGINT, jnp.asarray(bkeys_np),
-                            vrange=(0, sparse_span))])
-    sparse_p = Page([Column(T.BIGINT, jnp.asarray(pk_np),
-                            vrange=(0, sparse_span))])
-    ex.lookup_join(node, sparse_p, sparse_b)
-    if M.FUSED_JOIN_SELECTIONS.value("fused") != before["fused"] + 1:
-        print("CHECK FAIL: sparse-keyed build no longer selects the fused "
-              "tier", file=sys.stderr)
-        return 1
-    bk = jnp.asarray(bkeys_np)
-    pk = jnp.asarray(pk_np)
-    pay = jnp.asarray(rng.integers(0, 1 << 30, n_build).astype(np.int64))
-
-    def fused(p, b, w):
-        rows, matched = FJ.fused_probe_unique([(b, None)], None, [(p, None)])
-        return w[jnp.clip(rows, 0, n_build - 1)], matched
-
-    def legacy(p, b, w):
-        build = J.build_side([(b, None)], None)
-        rows, matched = J.probe_unique(build, [(p, None)])
-        return w[jnp.clip(rows, 0, n_build - 1)], matched
-
-    t_fused = min(measure(fused, (pk, bk, pay), k=4) for _ in range(attempts))
-    t_legacy = min(measure(legacy, (pk, bk, pay), k=4) for _ in range(attempts))
-    ratio = t_fused / t_legacy
-    print(json.dumps({
-        "check": "join-kernel-regression",
-        "fused_seconds": round(t_fused, 6),
-        "sortmerge_seconds": round(t_legacy, 6),
-        "fused_over_sortmerge": round(ratio, 3),
-        "margin": margin,
-        "ok": ratio <= margin,
-    }))
-    if ratio > margin:
-        print(f"CHECK FAIL: fused tier {ratio:.2f}x slower than the legacy "
-              f"sortmerge baseline it replaced (margin {margin}x)",
-              file=sys.stderr)
-        return 1
-    return 0
-
-
 def main():
     from trino_tpu.compile_cache import configure_compile_cache
 
     configure_compile_cache()
-    if "--check" in sys.argv:
-        raise SystemExit(check())
     if "--compact" in sys.argv:
         at = sys.argv.index("--compact") + 1
         seed = int(sys.argv[at]) if at < len(sys.argv) else 7
@@ -402,10 +310,10 @@ def main():
     ov = overlap_case()
     result["overlapped_exchange"] = ov if ov is not None else (
         "skipped: single-device mesh")
-    out_path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                            "KERNELS_r06.json")
-    with open(out_path, "w") as f:
-        json.dump(result, f, indent=1)
+    paths = [a for a in sys.argv[1:] if not a.startswith("--")]
+    if paths:
+        with open(paths[0], "w") as f:
+            json.dump(result, f, indent=1)
     print(json.dumps(result))
 
 

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from bench import _SQL as BENCH_SQL
+from tests.tpch_sql import QUERIES as TPCH_SQL
 
 SF1_ORDERS = 1_500_000
 SF1_LINEITEM = 6_001_215
@@ -60,16 +60,16 @@ def test_merge_kernel_compiles_at_sf1_widths(one_chip, block_build):
     assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel is in
 
 
-@pytest.mark.parametrize("query", ["q1", "q3"])
+@pytest.mark.parametrize("query", [1, 3], ids=["q1", "q3"])
 def test_compiled_query_body_compiles(one_chip, query):
-    """``CompiledQuery.raw_fn`` — the whole-query XLA body ``bench.py``
-    times — traced over tpch.tiny's staged shapes, lowered for the v5e."""
+    """``CompiledQuery.raw_fn``, the whole-query XLA body, traced over
+    tpch.tiny's staged shapes, lowered for the v5e."""
     from trino_tpu import Session
     from trino_tpu.exec.compiled import CompiledQuery
     from trino_tpu.exec.query import plan_sql
 
     session = Session()
-    cq = CompiledQuery.build(session, plan_sql(session, BENCH_SQL[query]))
+    cq = CompiledQuery.build(session, plan_sql(session, TPCH_SQL[query]))
     shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
               for a in cq.input_arrays]
     compiled = jax.jit(cq.raw_fn).lower(shapes).compile()
@@ -87,7 +87,7 @@ def test_q1_partial_aggregation_compiles_as_one_program(one_chip, monkeypatch):
     from trino_tpu.sql.planner import plan as P
 
     session = Session()
-    (agg,) = [n for n in P.walk_plan(plan_sql(session, BENCH_SQL["q1"]))
+    (agg,) = [n for n in P.walk_plan(plan_sql(session, TPCH_SQL[1]))
               if isinstance(n, P.AggregationNode)]
     partial = P.AggregationNode(
         agg.source, list(agg.group_channels), agg.aggregates, step="partial")
@@ -159,7 +159,7 @@ def test_spmd_hash_partitioned_q3_compiles_with_all_to_all(topo):
         stats.GATHER_AGG_MAX_ROWS_PER_DEVICE = 8
         stats.BROADCAST_BUILD_MAX = 8
         dq = DistributedQuery.build(
-            session, plan_sql(session, BENCH_SQL["q3"]),
+            session, plan_sql(session, TPCH_SQL[3]),
             Mesh(np.array(jax.devices()[:4]), (AXIS,)))
         assert any(k.startswith("xchg") for k in dq.capacity_hints)
         chip_mesh = Mesh(np.array(topo.devices), (AXIS,))

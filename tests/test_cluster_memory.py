@@ -83,6 +83,30 @@ def tight_cluster():
     coord.stop()
 
 
+def _heartbeat(coord, workers):
+    """The memory half of a worker's announce tick, run now: sample the
+    memory ledger, then tell the killer what is reserved. The announce
+    loop beats every 0.5 s and reservations decay when a task body ends: a
+    process whose programs are already compiled finishes the join between
+    two beats, and a killer that never saw the reservation kills nothing."""
+    for w in workers:
+        reserved = w.tasks.query_memory()
+        coord.cluster_memory.update(w.node_id, {
+            "queryMemory": reserved, "memoryBytes": sum(reserved.values()),
+            "memoryLimit": w.memory_limit_bytes,
+            "memoryOwners": w._sample_memory(reserved, None)})
+
+
+def _wait_for(condition, what, beat=lambda: None):
+    """One generous deadline for every wait of the test: it bounds a hang,
+    it is not tuned to how fast an idle box gets there."""
+    deadline = time.time() + 300
+    while not condition():
+        assert time.time() < deadline, f"timed out waiting for {what}"
+        beat()
+        time.sleep(0.005)
+
+
 def test_oversized_query_killed_small_query_finishes(tight_cluster):
     coord, workers = tight_cluster
     # a JOIN fragment executes as one bulk unit (split-at-a-time
@@ -93,18 +117,15 @@ def test_oversized_query_killed_small_query_finishes(tight_cluster):
         "select o_orderpriority, count(*) c, sum(l_quantity) q "
         "from orders, lineitem where o_orderkey = l_orderkey "
         "group by o_orderpriority order by o_orderpriority", props)
-    deadline = time.time() + 60
-    while not big.state.is_terminal() and time.time() < deadline:
-        time.sleep(0.1)
+    _wait_for(big.state.is_terminal, "the oversized query to end",
+              beat=lambda: _heartbeat(coord, workers))
     assert big.state.get() == "FAILED", big.state.get()
     assert "EXCEEDED_CLUSTER_MEMORY" in (big.failure or ""), big.failure
     assert coord.cluster_memory.kills
     # the FAILED query stores a flight-recorder postmortem whose memory
     # snapshot names per-pool watermarks and top consumers; the terminal
-    # event listener captures it asynchronously, so poll for it
-    deadline = time.time() + 15
-    while big.postmortem is None and time.time() < deadline:
-        time.sleep(0.1)
+    # event listener captures it asynchronously
+    _wait_for(lambda: big.postmortem is not None, "the postmortem")
     pm = big.postmortem
     assert pm and pm["state"] == "FAILED"
     mem = pm["coordinator"]["memory"]
@@ -115,8 +136,6 @@ def test_oversized_query_killed_small_query_finishes(tight_cluster):
     # the cluster remains usable: a small query completes normally
     small = coord.submit("select count(*) from nation",
                          {"catalog": "tpch", "schema": "tiny"})
-    deadline = time.time() + 60
-    while not small.state.is_terminal() and time.time() < deadline:
-        time.sleep(0.1)
+    _wait_for(small.state.is_terminal, "the small query to end")
     assert small.state.get() == "FINISHED", small.failure
     assert small.rows == [(25,)]

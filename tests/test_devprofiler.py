@@ -8,8 +8,8 @@ seconds never exceed the ledger's ``device-execute`` phase.
 ``system.runtime.kernels`` and ``system.runtime.compiles`` return rows
 over real SQL; a rerun of a compiled query records a compile-cache
 ``hit`` with ZERO new miss events; EXPLAIN ANALYZE VERBOSE carries the
-per-node ``launches=``/``dispatch_overhead=`` annotation; and
-``microbench/profile.py --check`` holds as the tier-1 gate.
+per-node ``launches=``/``dispatch_overhead=`` annotation; and for the point,
+q1 and q3 shapes the kernel rows cover most of the device phases.
 """
 import time
 import urllib.request
@@ -268,27 +268,47 @@ def test_explain_analyze_verbose_kernel_annotations(cluster):
     assert "launches=" in scan_line and "dispatch_overhead=" in scan_line
 
 
-# ------------------------------------------------------------ tier-1 gate
-def test_profile_check():
-    """The tier-1 profiler gate: microbench/profile.py --check boots its
-    own cluster, profiles the three query shapes, and must attribute the
-    device phases, show overhead dominating the point mix, and hit the
-    compile cache on rerun.
+# ------------------------------ kernel rows attribute the device phases
+POINT_SQL = ("select o_orderkey, o_totalprice, o_orderstatus "
+             "from orders where o_orderkey = ?")
+# result cache off: a HIT never executes, so its profile has no kernels
+SHAPE_PROPS = dict(result_cache_enabled="false", device_cache_enabled="true",
+                   device_profiling="true")
 
-    Runs in a SUBPROCESS like test_qps_check: the microbench owns its
-    server lifecycle and must not share this process's metrics registry
-    or jax state."""
-    import os
-    import subprocess
-    import sys
 
-    path = os.path.join(os.path.dirname(__file__), "..", "microbench",
-                        "profile.py")
-    res = subprocess.run(
-        [sys.executable, path, "--check"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=480)
-    assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+@pytest.mark.parametrize("shape,statements,fast_path", [
+    # prepared lookups on the coordinator-local path, the serving shape
+    ("point", [(POINT_SQL, (1_000_000 + i,)) for i in range(4)], True),
+    ("q1", [(TPCH[1], None)] * 2, False),
+    ("q3", [(TPCH[3], None)] * 2, False),
+])
+def test_kernel_rows_attribute_the_device_phases(cluster, shape, statements,
+                                                 fast_path):
+    """Every statement of the shape has kernel rows, and their wall covers
+    at least half of the ledger's ``device-staging`` + ``device-execute``
+    (TableScan's kernel wall covers the staging read; capped at 1 a
+    statement, since worker kernels overlap in wall time)."""
+    from trino_tpu.client import dbapi
+
+    coord, _ = cluster
+    conn = dbapi.connect(
+        coordinator_url=coord.base_url, user="test",
+        short_query_fast_path="true" if fast_path else "false",
+        **SHAPE_PROPS)
+    cur = conn.cursor()
+    shares = []
+    for sql, params in statements:
+        cur.execute(sql, params)
+        assert cur.stats["fastPath"] == (
+            "fast-path" if fast_path else "distributed")
+        prof = _profile(coord, conn._client.query_id)
+        assert prof["kernels"], f"{shape}: no kernel rows"
+        phases = prof["timeline"]["phases"]
+        phase = phases["device-execute"] + phases.get("device-staging", 0.0)
+        assert phase > 0
+        shares.append(
+            min(1.0, sum(k["wallS"] for k in prof["kernels"]) / phase))
+    assert sum(shares) / len(shares) >= 0.5, shares
 
 
 # --------------------------------------- host_read and the charged kernel row

@@ -1,4 +1,4 @@
-"""Short-query fast path (server/fastpath.py) + the QPS gate (ISSUE 10).
+"""Short-query fast path (server/fastpath.py) + the point-lookup mix (ISSUE 10).
 
 - the eligibility predictor must never drift from the fragmenter: it is
   compared against ``fragment_plan`` across the whole TPC-H suite;
@@ -7,8 +7,9 @@
   visible in spans, query info, system.runtime.queries, the statement
   stats block, and the CLI summary;
 - multi-stage plans and over-threshold scans stay distributed;
-- ``microbench/qps.py --check`` runs green as the tier-1 regression
-  guard (the serving config must clear its speedup bound).
+- the point-lookup mix a serving deployment sends (prepared lookups by
+  unique key, concurrent clients) fails no statement and takes the fast
+  path on every statement when it is on, on none when it is off.
 """
 from __future__ import annotations
 
@@ -228,23 +229,82 @@ def test_fast_path_respects_result_cache(cluster):
     assert "fastpath/execute" not in names  # served from cache, no run
 
 
-# ----------------------------------------------------------------- QPS gate
-def test_qps_check():
-    """The tier-1 serving regression guard: microbench/qps.py --check
-    boots its own cluster, measures the point-lookup mix with the serving
-    path on vs off, and must clear the speedup bound.
+# ------------------------------------------------------ the point-lookup mix
+# What the serving deployment sends: a prepared point lookup on ``orders``
+# by a key no other request uses (a repeated key would be a result-cache
+# hit, which runs no path at all), from concurrent DBAPI clients, with the
+# result and device caches on as a serving deployment runs them. With the
+# fast path off the same statement goes as plain SQL with its literal.
+POINT_SQL = ("select o_orderkey, o_totalprice, o_orderstatus "
+             "from orders where o_orderkey = ?")
+KNOWN_PRESENT_KEY = 7  # exists at every tpch scale
+MIX_CLIENTS, MIX_REQUESTS = 2, 20
 
-    Runs in a SUBPROCESS like test_join_kernel_regression_check: the
-    microbench owns its server lifecycle and must not share this
-    process's metrics registry or jax state."""
-    import os
-    import subprocess
-    import sys
 
-    path = os.path.join(os.path.dirname(__file__), "..", "microbench",
-                        "qps.py")
-    res = subprocess.run(
-        [sys.executable, path, "--check"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=480)
-    assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["serving-on", "serving-off"])
+def point_mix(request, cluster):
+    import threading
+
+    from trino_tpu.client import dbapi
+
+    coord, _ = cluster
+    serving_on = request.param
+    props = {"result_cache_enabled": "true", "device_cache_enabled": "true",
+             "short_query_fast_path": "true" if serving_on else "false"}
+
+    def lookup(cur, key):
+        if serving_on:
+            return cur.execute(POINT_SQL, (key,))
+        return cur.execute(POINT_SQL.replace("?", str(key)))
+
+    probe = lookup(
+        dbapi.connect(coordinator_url=coord.base_url, **props).cursor(),
+        KNOWN_PRESENT_KEY)
+    before = {path: M.FAST_PATH_QUERIES.value(path)
+              for path in ("fast-path", "distributed")}
+    failures, paths = [], []
+
+    def client_loop(ci):
+        cur = dbapi.connect(coordinator_url=coord.base_url, **props).cursor()
+        base = (2_000_000 if serving_on else 1_000_000) + ci * 100_000
+        for r in range(MIX_REQUESTS):
+            try:
+                lookup(cur, base + r)
+            except Exception as e:  # noqa: BLE001 — counted, then asserted
+                failures.append(f"{base + r}: {e}")
+                continue
+            paths.append((cur.stats or {}).get("fastPath"))
+
+    threads = [threading.Thread(target=client_loop, args=(ci,))
+               for ci in range(MIX_CLIENTS)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return {
+        "serving_on": serving_on, "probe_rows": probe.rowcount,
+        "failures": failures, "paths": paths,
+        "counted": {path: M.FAST_PATH_QUERIES.value(path) - n
+                    for path, n in before.items()},
+    }
+
+
+def test_point_mix_fails_no_statement(point_mix):
+    assert point_mix["failures"] == []
+    assert len(point_mix["paths"]) == MIX_CLIENTS * MIX_REQUESTS
+
+
+def test_point_mix_probe_returns_the_known_row(point_mix):
+    assert point_mix["probe_rows"] == 1
+
+
+def test_point_mix_takes_the_fast_path_only_when_it_is_on(point_mix):
+    """Every statement of the mix reports the coordinator-local path with
+    ``short_query_fast_path`` on and none does with it off, in the
+    statement's own stats and in ``trino_tpu_fast_path_queries_total``."""
+    n = MIX_CLIENTS * MIX_REQUESTS
+    taken, other = (("fast-path", "distributed") if point_mix["serving_on"]
+                    else ("distributed", "fast-path"))
+    assert point_mix["paths"] == [taken] * n
+    assert point_mix["counted"] == {taken: n, other: 0}

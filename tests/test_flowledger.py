@@ -22,8 +22,11 @@ Covers the PR's acceptance matrix:
   ``net_bytes_*`` columns on ``system.runtime.nodes``, the CLI summary's
   ``drain: N MB/s`` tag, EXPLAIN ANALYZE's "Data flow:" section, and
   the postmortem flow block;
-- ``tools/check_flow_docs.py`` green against the shipped README, and
-  ``microbench/flows.py --check`` holding as the tier-1 gate.
+- a uniform run (q3 three times, then a spooled export) through a DBAPI
+  client: conservation over cold and warm rounds, every link class it
+  crosses recorded, no straggler flagged, ``system.runtime.transfers``
+  filled;
+- ``tools/check_flow_docs.py`` green against the shipped README.
 """
 import json
 import threading
@@ -250,9 +253,15 @@ def _decode_wire_bytes():
             + M.SERDE_BYTES.value("decode", "none"))
 
 
+def _link_bytes():
+    totals = {}
+    for r in FLOW_LEDGER.transfer_rows():
+        totals[r["link"]] = totals.get(r["link"], 0) + int(r["bytes"])
+    return totals
+
+
 def _pull_bytes():
-    return sum(r["bytes"] for r in FLOW_LEDGER.transfer_rows()
-               if r["link"] == "exchange-pull")
+    return _link_bytes().get("exchange-pull", 0)
 
 
 def test_distributed_q3_byte_conservation(cluster):
@@ -356,29 +365,90 @@ def test_postmortem_carries_flow_snapshot(cluster):
             assert "flows" in w
 
 
-# ------------------------------------------------------------- docs + gate
+# -------------------------------------------------------------------- docs
 def test_flow_docs_gate_green():
     from tools.check_flow_docs import check
 
     assert check() == []
 
 
-def test_flows_check():
-    """The tier-1 flow-ledger gate: microbench/flows.py --check boots its
-    own 2-worker cluster and must show conservation >= 0.95, all the
-    uniform-run links, and zero straggler false positives.
+# --------------------------------------------- a uniform run, link by link
+UNIFORM_ROUNDS = 3  # q3 repeats: one cold round, then warm ones
+# wide rows, no aggregate: enough result bytes to cross the spool threshold
+# (bounded by key, not LIMIT: a limit a worker under worker-direct spooling
+# would make the returned row count ambiguous)
+EXPORT_SQL = ("select o_orderkey, o_custkey, o_totalprice, o_orderdate "
+              "from orders where o_orderkey <= 60000")
 
-    Runs in a SUBPROCESS like test_profile_check: the microbench owns
-    its server lifecycle and must not share this process's metrics
-    registry, flow ledger, or jax state."""
-    import os
-    import subprocess
-    import sys
 
-    path = os.path.join(os.path.dirname(__file__), "..", "microbench",
-                        "flows.py")
-    res = subprocess.run(
-        [sys.executable, path, "--check"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=480)
-    assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+@pytest.fixture(scope="module")
+def uniform_run(cluster):
+    """What a uniform workload leaves in the ledger: q3 three times through
+    a DBAPI client, then one spooled export (segments written by the
+    workers and fetched by the client)."""
+    from trino_tpu.client import dbapi
+
+    coord, _ = cluster
+    cur = dbapi.connect(coordinator_url=coord.base_url,
+                        catalog="tpch", schema="tiny").cursor()
+    links0, serde0 = _link_bytes(), _decode_wire_bytes()
+    straggler_flags = 0
+    for _ in range(UNIFORM_ROUNDS):
+        cur.execute(Q3_SQL)
+        cur.fetchall()
+        straggler_flags += int(
+            (cur.stats.get("flows") or {}).get("stragglers") or 0)
+    pull_delta = _pull_bytes() - links0.get("exchange-pull", 0)
+    serde_delta = _decode_wire_bytes() - serde0
+    spool = dbapi.connect(
+        coordinator_url=coord.base_url, catalog="tpch", schema="tiny",
+        spooled_results_enabled="true",
+        spooled_results_threshold_bytes="1024",
+        spooled_results_segment_bytes="65536").cursor()
+    spool.execute(EXPORT_SQL)
+    export_rows = len(spool.fetchall())
+    straggler_flags += int(
+        (spool.stats.get("flows") or {}).get("stragglers") or 0)
+    ledger_links = {link: n - links0.get(link, 0)
+                    for link, n in _link_bytes().items()}
+    # the announce loop (0.5 s cadence) must deliver the workers' flow rows
+    # before the coordinator-side table is read
+    time.sleep(1.5)
+    cur.execute("select link, sum(bytes) from system.runtime.transfers "
+                "group by link")
+    table_links = {r[0]: int(r[1]) for r in cur.fetchall()}
+    cur.execute("select count(*) from system.runtime.stragglers")
+    straggler_flags += int(cur.fetchall()[0][0])
+    return {"pull_bytes": pull_delta, "serde_bytes": serde_delta,
+            "export_rows": export_rows, "spooled": spool.stats.get("spooled"),
+            "straggler_flags": straggler_flags,
+            "ledger_links": ledger_links, "table_links": table_links}
+
+
+def test_uniform_run_conserves_bytes_over_cold_and_warm_rounds(uniform_run):
+    """The bound of ``test_distributed_q3_byte_conservation`` over the
+    window a client sees: three drained q3 rounds, two of them warm."""
+    assert uniform_run["serde_bytes"] > 0
+    assert uniform_run["pull_bytes"] >= 0.95 * uniform_run["serde_bytes"]
+
+
+@pytest.mark.parametrize("link", [
+    "exchange-pull", "staging-transfer", "spool-write", "segment-fetch",
+    "client-drain"])
+def test_uniform_run_records_every_link_it_crosses(uniform_run, link):
+    assert uniform_run["export_rows"] > 0 and uniform_run["spooled"]
+    assert uniform_run["ledger_links"].get(link, 0) > 0, (
+        f"{link} never recorded: {uniform_run['ledger_links']}")
+
+
+def test_uniform_run_flags_no_straggler(uniform_run):
+    """Zero false positives: not in any statement's ``flows`` stats block,
+    the spooled export's included, and not in
+    ``system.runtime.stragglers``."""
+    assert uniform_run["straggler_flags"] == 0
+
+
+def test_uniform_run_reaches_the_transfers_table(uniform_run):
+    links = uniform_run["table_links"]
+    assert links, "system.runtime.transfers came up empty"
+    assert links.get("exchange-pull", 0) > 0 and links.get("spool-write", 0) > 0

@@ -1,7 +1,7 @@
 """Randomized join correctness: the fused sort-merge tier against host
 ground truth (exec/host_eval.py), across inner/left/semi joins, NULL
 keys, duplicate keys, empty builds, and the all-hot single-key skew
-shape (PR 4's microbench), on both the dense and fused cost-gate paths.
+shape, on both the dense and fused cost-gate paths.
 
 Shapes are FIXED across randomized trials (only content varies) so each
 kernel compiles once and the suite stays tier-1-fast.
@@ -220,26 +220,44 @@ def test_fused_off_matches_fused_on():
     assert _page_rows(p_on) == _page_rows(p_off)
 
 
-def test_join_kernel_regression_check():
-    """The tier-selection regression guard microbench/join_kernels.py
-    --check runs green (cost gate picks dense for dense keys, fused for
-    sparse; fused within 1.5x of the legacy baseline it replaced).
+@pytest.mark.parametrize("join", ["lookup", "semi"])
+@pytest.mark.parametrize("keys,tier", [("dense", "dense"),
+                                       ("sparse", "fused")])
+def test_cost_gate_selects_the_tier_by_key_range(join, keys, tier):
+    """``trino_tpu_fused_join_selections_total`` moves once, under
+    ``dense`` for a build whose keys fill their range (the direct-address
+    table) and under ``fused`` for keys spread over 2^40 (one combined
+    sort): a drift in the selection is a silent loss of speed."""
+    from trino_tpu.obs import metrics as M
 
-    Runs in a SUBPROCESS: the microbench module enables jax x64 at import
-    time (its TPU measurement contract), and that global config flip must
-    not leak into this suite's process — it would force x64 recompiles on
-    every test collected after this one."""
-    import os
-    import subprocess
-    import sys
-
-    path = os.path.join(os.path.dirname(__file__), "..", "microbench",
-                        "join_kernels.py")
-    res = subprocess.run(
-        [sys.executable, path, "--check"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=480)
-    assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+    rng = np.random.default_rng(3)
+    n_probe, n_build = 1 << 12, 1 << 10
+    if keys == "dense":
+        span = n_build - 1
+        bkeys = np.arange(n_build, dtype=np.int64)
+    else:
+        span = 1 << 40  # far beyond the dense table's span limit
+        bkeys = rng.choice(span, size=n_build, replace=False).astype(np.int64)
+    pkeys = np.concatenate([
+        rng.choice(bkeys, size=n_probe // 2),
+        rng.integers(0, span, size=n_probe - n_probe // 2),
+    ]).astype(np.int64)
+    build = Page([Column(T.BIGINT, jnp.asarray(bkeys), vrange=(0, span))])
+    probe = Page([Column(T.BIGINT, jnp.asarray(pkeys), vrange=(0, span))])
+    tiers = ("dense", "fused", "legacy", "merge-sorted", "merge-pallas")
+    before = {t: M.FUSED_JOIN_SELECTIONS.value(t) for t in tiers}
+    ex = Executor(Session())
+    if join == "lookup":
+        node = P.JoinNode(join_type="inner", left=None, right=None,
+                          left_keys=[0], right_keys=[0], right_unique=True)
+        out = ex.lookup_join(node, probe, build)
+    else:
+        node = P.JoinNode(join_type="semi", left=None, right=None,
+                          left_keys=[0], right_keys=[0])
+        out = ex.semi_join(node, probe, build)
+    moved = {t: M.FUSED_JOIN_SELECTIONS.value(t) - before[t] for t in tiers}
+    assert moved == {t: int(t == tier) for t in tiers}
+    assert np.array_equal(np.asarray(out.sel), np.isin(pkeys, bkeys))
 
 
 # ----------------------------------------------------- sorted-build cache

@@ -15,7 +15,8 @@ Coverage map (ISSUE 15):
   (REFRESH/base-DML both invalidate), device-cache warm-on-refresh,
   system.metadata.materialized_views;
 - cross-process registry replication over the PR 12 executor plane;
-- the microbench quick gate (tier-1).
+- the q3 shape (three base tables, one view): the staleness matrix with
+  each mutation on a different base table, and the warm storage hit.
 """
 import pytest
 
@@ -696,13 +697,99 @@ def test_process_plane_registry_replication(proc_coord):
     assert [tuple(r) for r in q.rows] == base_rows
 
 
-def test_matview_bench_check():
-    """The microbench quick gate: fresh-MV speedup over the q3 shape +
-    the full staleness matrix, small schema (tier-1 wiring like the
-    qps/staging checks)."""
-    import microbench.matview as mb
+# ------------------------- the q3 shape: three base tables, one view
+Q3_AGG = """
+select l_orderkey, o_orderdate, o_shippriority,
+       sum(l_extendedprice * (1 - l_discount)) as revenue
+from customer, orders, lineitem
+where c_mktsegment = 'BUILDING' and c_custkey = o_custkey
+  and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15'
+  and l_shipdate > date '1995-03-15'
+group by l_orderkey, o_orderdate, o_shippriority
+"""
+Q3_TOP = Q3_AGG + " order by revenue desc, o_orderdate, l_orderkey limit 10"
+# each mutation moves the version of a DIFFERENT base table of the join
+Q3_MUTATIONS = {
+    "insert": ["insert into orders select * from orders limit 1"],
+    "update": ["update lineitem set l_quantity = l_quantity + 1 "
+               "where l_orderkey = 1"],
+    "delete": ["delete from customer where c_custkey = 1"],
+    "drop": ["drop table customer",
+             "create table customer as select * from tpch.tiny.customer"],
+}
 
-    report = mb.run("tiny", check_mode=True)
-    assert report["speedup"] >= mb.MIN_SPEEDUP_CHECK
-    assert report["incorrect_freshness_substitutions"] == 0
-    assert report["stale_fallback_ok"] and report["warm_storage_hit"]
+
+@pytest.fixture(scope="module")
+def q3_matrix():
+    """The staleness matrix over mutable copies of tpch.tiny: after each
+    mutation, and again after the REFRESH that follows it, whether the
+    query was substituted and whether its rows equal the base tables' own
+    (substitution forced off). Records; the tests assert."""
+    s = Session({"catalog": "memory", "schema": "default",
+                 "device_cache_enabled": True})
+    for t in ("customer", "orders", "lineitem"):
+        s.execute(f"create table {t} as select * from tpch.tiny.{t}")
+    s.execute("create materialized view q3m as " + Q3_AGG)
+
+    def observe():
+        before = _hits(s)
+        rows = s.execute(Q3_TOP).rows
+        substituted = _hits(s) > before
+        s.properties["materialized_view_substitution"] = False
+        try:
+            truth = s.execute(Q3_TOP).rows
+        finally:
+            s.properties["materialized_view_substitution"] = True
+        return {"substituted": substituted, "identical": rows == truth,
+                "rows": len(rows)}
+
+    steps = {"fresh": observe()}
+    for name, statements in Q3_MUTATIONS.items():
+        for sql in statements:
+            s.execute(sql)
+        steps[f"{name}-stale"] = observe()
+        s.execute("refresh materialized view q3m")
+        steps[f"{name}-refreshed"] = observe()
+    s.execute("drop materialized view q3m")
+    return steps
+
+
+def test_q3_matrix_never_substitutes_a_stale_view(q3_matrix):
+    """``incorrect_freshness_substitutions == 0``: no step whose view was
+    stale was answered from it, and the fresh view was."""
+    stale = [k for k in q3_matrix if k.endswith("-stale")]
+    assert len(stale) == len(Q3_MUTATIONS)
+    assert [k for k in stale if q3_matrix[k]["substituted"]] == []
+    assert q3_matrix["fresh"]["substituted"]
+    assert q3_matrix["fresh"]["rows"] == 10
+
+
+@pytest.mark.parametrize("mutation", list(Q3_MUTATIONS))
+def test_q3_stale_view_falls_back_and_refresh_resumes(q3_matrix, mutation):
+    stale = q3_matrix[f"{mutation}-stale"]
+    refreshed = q3_matrix[f"{mutation}-refreshed"]
+    assert stale["identical"] and not stale["substituted"]
+    assert refreshed["identical"] and refreshed["substituted"]
+
+
+def test_q3_fresh_view_is_a_warm_storage_hit():
+    """On the immutable tpch catalog the view stays fresh: the first
+    substituted q3 returns the base query's rows and is served from the
+    storage table the REFRESH staged into HBM (its device-cache entry
+    counts a hit)."""
+    from trino_tpu.devcache import DEVICE_CACHE
+
+    s = Session({"catalog": "tpch", "schema": "tiny",
+                 "device_cache_enabled": True})
+    base_rows = s.execute(Q3_TOP).rows
+    s.execute("create materialized view q3rev as " + Q3_AGG)
+    try:
+        storage = s.matviews.snapshot()[0].storage_table
+        hits = _hits(s)
+        assert s.execute(Q3_TOP).rows == base_rows
+        assert _hits(s) > hits, "fresh MV did not substitute"
+        (entry,) = [e for e in DEVICE_CACHE.snapshot()
+                    if e["table"] == storage]
+        assert entry["hits"] >= 1
+    finally:
+        s.execute("drop materialized view q3rev")

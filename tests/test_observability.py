@@ -72,7 +72,7 @@ def test_property_type_validation(session):
     with pytest.raises(ValueError, match="expected integer"):
         session.set_property("query_max_device_memory", "not-a-number")
     with pytest.raises(ValueError, match="positive"):
-        session.set_property("target_result_page_rows", 0)
+        session.set_property("join_max_broadcast_rows", 0)
     # string coercion (client protocol headers arrive as strings)
     session.set_property("query_max_device_memory", "1048576")
     assert session.properties["query_max_device_memory"] == 1048576

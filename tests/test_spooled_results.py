@@ -394,23 +394,6 @@ def test_small_results_stay_inline(cluster):
     assert cur.fetchall() == [(7,)]
 
 
-@pytest.mark.slow
-def test_results_bench_check():
-    """microbench/results.py --check boots subprocess clusters and
-    asserts spooled/inline row equality end to end (slow: three fresh
-    cluster boots on the quick tiny schema)."""
-    import subprocess
-    import sys
-
-    path = os.path.join(os.path.dirname(__file__), "..", "microbench",
-                        "results.py")
-    res = subprocess.run(
-        [sys.executable, path, "--check"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=580)
-    assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
-
-
 def test_segment_http_range_fetch(cluster):
     """GET /v1/segment/{id} honors Range headers (206 + Content-Range) —
     the resume semantics of the segment endpoint."""

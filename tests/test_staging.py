@@ -26,6 +26,8 @@ from trino_tpu.client.session import Session
 from trino_tpu.devcache import DEVICE_CACHE, HOST_CACHE
 from trino_tpu.obs import metrics as M
 
+from tests.tpch_sql import QUERIES as TPCH_SQL
+
 
 @pytest.fixture(autouse=True)
 def fresh_caches():
@@ -553,24 +555,87 @@ def test_staging_accounting_identity_with_fanout():
     assert delta == pytest.approx(cq.phase1_s + cq.df_apply_s, abs=1e-9)
 
 
-# --------------------------------------------------------- tier-1 bench gate
-def test_staging_bench_check():
-    """The tier-1 cold-staging regression guard: microbench/staging.py
-    --check runs the serial-vs-pipelined comparison at a quick scale,
-    asserts bit-identity and the host-refill bound, and (multi-core
-    boxes) the overlap speedup. Subprocess like test_qps_check: the
-    microbench owns its jax/metrics state."""
-    import os
-    import subprocess
-    import sys
+# ------------------------------ TPC-H q3's scans through the staging engine
+def _stage_tpch_q3(session):
+    """Stage q3's three scans as the compiled tier does (phase-1
+    dynamic-filter domains applied on the host) through
+    ``staging.staged_scan_page``. Returns the staged arrays by table, the
+    splits staged and the connector scan calls made."""
+    from trino_tpu.exec import host_eval, staging
+    from trino_tpu.exec.executor import (
+        apply_dynamic_domains, dynamic_domain_map, scan_constraint_with)
 
-    path = os.path.join(os.path.dirname(__file__), "..", "microbench",
-                        "staging.py")
-    res = subprocess.run(
-        [sys.executable, path, "--check"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=480)
-    assert res.returncode == 0, (res.stdout or "") + (res.stderr or "")
+    root, scans = _scan_node(session, TPCH_SQL[3])
+    dyn = host_eval.resolve_dynamic_filters(session, root)
+    conn = session.catalogs["tpch"]
+    calls = _count_scans(conn)
+    arrays, splits_staged = {}, 0
+    try:
+        for node in scans:
+            constraint = scan_constraint_with(node, dyn)
+            splits = conn.get_splits(
+                node.schema, node.table,
+                staging.target_split_count(
+                    session, conn, node.schema, node.table),
+                constraint=constraint, handle=node.table_handle)
+            page, _rows, prof = staging.staged_scan_page(
+                session, node, conn, splits, constraint,
+                prune=lambda datas, node=node: apply_dynamic_domains(
+                    node, dyn, datas),
+                applied_domains=dynamic_domain_map(node, dyn))
+            arrays[node.table] = _page_arrays(page)
+            splits_staged += prof.splits
+    finally:
+        del conn.scan  # the counter was set on the instance
+    return arrays, splits_staged, calls[0]
+
+
+@pytest.fixture(scope="module")
+def tpch_q3_staging():
+    """q3's scans over tpch.tiny staged three ways: serial
+    (``staging_parallelism=1``), pipelined at the width the engine picks
+    (``=0``), and again after the HBM tier alone is evicted. Each cold arm
+    starts with the generator, host and HBM caches empty."""
+    from trino_tpu.connector.tpch import generator
+
+    def cold(parallelism):
+        DEVICE_CACHE.invalidate_all()
+        HOST_CACHE.invalidate_all()
+        generator._gen_cache.clear()
+        session = Session({"catalog": "tpch", "schema": "tiny",
+                           "device_cache_enabled": True,
+                           "staging_split_bytes": 1 << 18,
+                           "staging_parallelism": parallelism})
+        return session, _stage_tpch_q3(session)
+
+    _, (serial, _splits, _scans) = cold(1)
+    session, (pipelined, splits, cold_scans) = cold(0)
+    host_bytes = HOST_CACHE.cached_bytes()
+    DEVICE_CACHE.invalidate_all()
+    refill, _splits, refill_scans = _stage_tpch_q3(session)
+    return {"serial": serial, "pipelined": pipelined, "refill": refill,
+            "splits": splits, "cold_scans": cold_scans,
+            "refill_scans": refill_scans, "host_bytes": host_bytes}
+
+
+def test_tpch_q3_pipelined_staging_bit_identical_to_serial(tpch_q3_staging):
+    run = tpch_q3_staging
+    assert run["splits"] > 3 and run["cold_scans"] == run["splits"]
+    assert set(run["serial"]) == {"customer", "orders", "lineitem"}
+    for table, serial in run["serial"].items():
+        _assert_same_arrays(serial, run["pipelined"][table])
+
+
+def test_tpch_q3_first_pass_fills_the_host_tier(tpch_q3_staging):
+    assert tpch_q3_staging["host_bytes"] > 0
+
+
+def test_tpch_q3_host_refill_bit_identical_without_a_connector_scan(
+        tpch_q3_staging):
+    run = tpch_q3_staging
+    assert run["refill_scans"] == 0
+    for table, cold in run["pipelined"].items():
+        _assert_same_arrays(cold, run["refill"][table])
 
 
 # ------------------------------------------------------- split row buckets

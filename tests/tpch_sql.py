@@ -1,4 +1,5 @@
-"""The 22 TPC-H queries (spec validation parameters), shared by tests/bench.
+"""The 22 TPC-H queries (spec validation parameters), shared by the tests and
+``chip_smoke.py``.
 
 Reference parity target: testing/trino-benchmark-queries/.../tpch/q01..q22.sql
 (SURVEY.md §6). Written from the TPC-H specification; Q15's view is expressed

@@ -13,6 +13,8 @@ This module is that shape, written once:
   which would pull in jax;
 - :func:`read_readme` / :func:`backticked_names` — README access and the
   standard "any backticked mention counts" identifier extraction;
+- :func:`missing_readme_paths` — the files the README names that are not
+  in the tree;
 - :func:`iter_source_files` — the ``trino_tpu/`` walk used by every
   source-scanning gate and linter (skips ``__pycache__``);
 - :func:`gate_main` — the argparse ``--readme`` CLI + stderr report +
@@ -65,6 +67,29 @@ def backticked_names(text: str) -> set:
     """Backtick-quoted identifiers — the standard "documented" test for
     vocabularies whose members are ordinary words (span names, columns)."""
     return set(re.findall(r"`([^`\n]+)`", text))
+
+
+_README_PATH = re.compile(r"[\w./-]+\.(?:py|json|md)")
+
+
+def missing_readme_paths(readme_path: Optional[str] = None) -> List[str]:
+    """Repository paths README.md puts in backticks (whatever ends in
+    ``.py``, ``.json`` or ``.md``; a glob is not a path) that no file
+    answers to. A path counts from the repo root or from ``trino_tpu/``,
+    the README's shorthand for engine modules; a bare file name may also
+    be any module under ``trino_tpu/``."""
+    engine_modules = {os.path.basename(p) for p in iter_source_files()}
+    missing = []
+    for name in sorted(backticked_names(read_readme(readme_path))):
+        if not _README_PATH.fullmatch(name):
+            continue
+        if any(os.path.exists(os.path.join(REPO_ROOT, base, name))
+               for base in ("", "trino_tpu")):
+            continue
+        if "/" not in name and name in engine_modules:
+            continue
+        missing.append(name)
+    return missing
 
 
 def iter_source_files(root: Optional[str] = None) -> Iterator[str]:
@@ -131,8 +156,6 @@ ALL_GATES = (
      "no import-time jnp evaluation; no jnp in repr/property/host modules"),
     ("lock-discipline", "lint.lock_discipline",
      "no lock-order inversions, re-entry, or blocking calls under locks"),
-    ("bench-trend", "bench_trend",
-     "TRAJECTORY.json fresh and no latest-round bench regression"),
 )
 
 

@@ -73,11 +73,6 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             bool, True,
         ),
         PropertyMetadata(
-            "target_result_page_rows",
-            "rows per result page on the client protocol",
-            int, 10_000, _positive,
-        ),
-        PropertyMetadata(
             "join_max_broadcast_rows",
             "estimated build-side rows above which a distributed join "
             "co-partitions both sides by key hash instead of broadcasting "
@@ -173,8 +168,8 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "fan-out width of the pipelined staging engine "
             "(exec/staging.py): split scan+decode run with this many in "
             "flight on the shared staging pool, overlapping the "
-            "host->device transfer; 1 = the serial path (the microbench "
-            "baseline), 0 = auto (min(8, cpu count))",
+            "host->device transfer; 1 = the serial path, 0 = auto "
+            "(min(8, cpu count))",
             int, 0, lambda v: None if v >= 0 else "must be >= 0",
         ),
         PropertyMetadata(

@@ -1,8 +1,8 @@
 """The one rule for JAX's persistent compilation cache.
 
 Process entry points (``coordinator.main``, ``worker.main``,
-``dispatch.executor_process_main``, ``bench.py``,
-``microbench/join_kernels.py``, ``chip_smoke.py``) call
+``dispatch.executor_process_main``, ``microbench/join_kernels.py``,
+``chip_smoke.py``, ``benchmark/run.py``) call
 :func:`configure_compile_cache` once, before their first compile. Nothing
 calls it at import or from a constructor, so library users and the tests
 keep whatever cache configuration their process already has.

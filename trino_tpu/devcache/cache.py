@@ -6,8 +6,9 @@ is a fully staged DEVICE artifact — an assembled scan ``Page`` (eager /
 compiled tiers), a per-split worker page, or the stacked shard arrays of
 an SPMD scan — so a warm query skips the whole host pipeline (connector
 scan, dynamic-domain pruning, dictionary merge, host->device transfer),
-which BENCH_r05 measured as the engine's single biggest loss (q3_sf10:
-22.7 s staging vs 1.17 s device execution).
+which is half of a q3 at SF 10: ``tpch_sf10.q3`` takes 8.7 s a statement
+and ``tpch_sf10_resident.q3``, the same statements with this cache on,
+4.1 s (ledger, PR 31).
 
 Correctness comes from the connector SPI's ``data_version()`` token
 (trino_tpu/connector/spi.py): the version rides inside every cache key,

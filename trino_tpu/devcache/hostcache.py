@@ -11,8 +11,7 @@ boundary digest as the shard component. Because the key is per split, the
 host tier survives re-shardings the HBM tier cannot: an HBM eviction, a
 mesh-width change, or a different worker split grouping re-stages from
 host memory (concat + transfer only) instead of re-running the connector
-scan and decode — the dominant cold-path cost BENCH_r05 measured
-(q3_sf10: 22.7 s staging vs 1.17 s device execute).
+scan and decode.
 
 Semantics are inherited wholesale from :class:`DeviceTableCache`:
 byte-budgeted LRU, SINGLE-FLIGHT admission (concurrent stagings of the

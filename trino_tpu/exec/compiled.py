@@ -8,7 +8,7 @@ fuses across operator boundaries (filter into scan into partial-agg, etc.),
 which no per-operator engine can do.
 
 The compiled artifact is reusable across runs with same-shaped inputs
-(same splits) — the bench harness measures steady-state throughput on it.
+(same splits).
 """
 from __future__ import annotations
 
@@ -233,10 +233,9 @@ class CompiledQuery:
             stage_sp.set("cached_rows", int(total_staged - fresh_staged))
             stage_sp.set("cache_hits", cache_hits)
             stage_sp.set("scans", len(scans))
-        # staging_df_s (bench) = phase1_s + df_apply_s: DF resolution plus
-        # host domain application — the counter charges exactly that, so
-        # the metric and bench's per-query field can never drift (asserted
-        # by tests/test_device_cache.py::test_staging_seconds_accounting)
+        # phase1_s + df_apply_s: DF resolution plus host domain
+        # application — the counter charges exactly that (asserted by
+        # tests/test_device_cache.py::test_staging_seconds_accounting)
         M.STAGED_ROWS.inc(int(fresh_staged))
         M.STAGING_SECONDS.inc(phase1_s + base.df_apply_s)
         # in-program dynamic-filter specs + stats-sized compaction per scan.
@@ -323,7 +322,7 @@ class CompiledQuery:
         cq.df_apply_s = base.df_apply_s
         cq.scan_rows = dict(base.scan_stats)
         # device-cache disposition of this build's staging (warm-serving
-        # telemetry: bench's warm_seconds and the microbench read these)
+        # telemetry: tests/test_device_cache.py reads these)
         cq.staging_s = staging_s
         cq.cache_hits = cache_hits
         cq.fresh_staged_rows = int(fresh_staged)

@@ -2142,8 +2142,9 @@ class Executor:
         dense = self._dense_join_cols(node, left, right)
         if dense is not None:
             # cost gate: dense-keyed builds keep the direct-address fast
-            # path (KERNELS_r05: one scatter + one bounded gather beats
-            # any sort formulation when the key range is dense)
+            # path (one scatter + one bounded gather, no sort: the tier
+            # every benchmark cell takes for lineitem-orders, PERF.md
+            # section 5)
             M.FUSED_JOIN_SELECTIONS.inc(1, "dense")
             bc, pc, lo, span = dense
             table = join_ops.dense_unique_table(

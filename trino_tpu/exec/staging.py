@@ -1,8 +1,9 @@
 """Pipelined cold staging: the engine every staging tier runs.
 
-BENCH_r05's dominant remaining cost is the COLD run (q3_sf10: 22.7 s
-staging vs 1.17 s device execute) — the warm-HBM device cache (PR 7)
-only fixes the second run. In the reference this work is inherently
+A statement whose tables are not resident stages them every time:
+``tpch_sf10.q3`` spends 4.2 s of its 8.7 s staging 1.35 GB (ledger, PR 31;
+``PERF.md`` section 5) — the warm-HBM device cache (PR 7) only fixes the
+second run. In the reference this work is inherently
 parallel: the connector SPI hands out *splits* and tasks run concurrent
 page-source drivers over them. This module is that split-driver plane for
 the staged-execution model, used by all three staging tiers (eager /
@@ -36,7 +37,7 @@ Observability: the ``device/staging`` wall decomposes into the
 ``device-staging`` bucket) and the
 ``trino_tpu_staging_phase_seconds_total{phase}`` counter;
 ``trino_tpu_staging_seconds_total`` keeps its exact per-tier charging
-semantics (bench's ``staging_df_s`` identity is drift-tested).
+semantics (``phase1_s + df_apply_s``, drift-tested).
 """
 from __future__ import annotations
 

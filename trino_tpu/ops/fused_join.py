@@ -5,8 +5,7 @@ phases: sort the build side (nb rows), THEN rank the probes against it
 with a combined sort of build+probe (N = nb + np rows, ops/ranks.py), THEN
 return ranks to probe order through a second N-row payload sort, THEN
 gather ``build.rows`` at the matched rank (one more np-row random pass).
-KERNELS_r05 measured the result: 0.156 GB/s on the probe=16M/build=4M
-lookup — every phase re-touches the full working set.
+Every phase re-touches the full working set.
 
 The fused formulation here sorts build and probe keys TOGETHER and emits
 the matched build row directly into the projection gather:

@@ -8,7 +8,7 @@ fan-in, owns admission + queueing and never does query work) vs
 coordinator spawned TWO fresh threads per query (an admission waiter and
 the query thread) and every submitted query got a thread no matter how
 overloaded the server was — the thread pile-up IS the single-process QPS
-ceiling QPS_r01 measured.
+ceiling.
 
 Three pieces:
 
