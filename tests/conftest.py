@@ -15,3 +15,14 @@ import jax  # noqa: E402
 
 # tests run on the CPU
 jax.config.update("jax_platforms", "cpu")
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def compacting_plans(monkeypatch):
+    """tiny's pages are under the optimizer's size gate for a CompactNode
+    (2^17 slots); lower it so q3 plans the three SF 1 and SF 10 plan."""
+    from trino_tpu.sql.planner import optimizer
+
+    monkeypatch.setattr(optimizer, "COMPACT_MIN_SLOTS", 1 << 10)

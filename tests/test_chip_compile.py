@@ -140,6 +140,28 @@ def test_true_positions_compiles_without_a_sort(one_chip, n, size):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+@pytest.mark.parametrize("source_rows,columns", [(62_914_560, 4),
+                                                  (15_728_640, 4)])
+def test_the_compacted_joins_gathers_compile_at_q3s_shapes(
+        one_chip, source_rows, columns):
+    """q3's lineitem-orders join at SF 10, squeezed to its Compact's 2^21
+    slots before its payloads move (``Executor.compacted_lookup_join``):
+    the probe side's columns and matched row ids out of 60 x 2^20 slots,
+    then the four ``orders`` columns out of 15 x 2^20, each ONE row gather
+    of a stacked ``[rows, 4]`` operand that fits the chip beside the
+    tables."""
+    from trino_tpu.ops import ranks
+
+    arrays = tuple(jax.ShapeDtypeStruct((source_rows,), jnp.int32,
+                                        sharding=one_chip)
+                   for _ in range(columns))
+    idx = jax.ShapeDtypeStruct((2_097_152,), jnp.int32, sharding=one_chip)
+    compiled = ranks._gather_all.lower(arrays, idx).compile()
+    text = compiled.as_text()
+    assert f"s32[2097152,{columns}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 def test_spmd_hash_partitioned_q3_compiles_with_all_to_all(topo):
     """The SPMD tier's promise — shuffles are ICI collectives — checked
     in the program the v5e compiler emits. ``DistributedQuery`` stages onto

@@ -167,15 +167,6 @@ def test_compact_to_lowers_without_a_sort():
 Q3 = QUERIES[3]
 
 
-@pytest.fixture
-def compacting_plans(monkeypatch):
-    """tiny's pages are under the optimizer's size gate for a CompactNode
-    (2^17 slots); lower it so q3 plans the three SF 1 and SF 10 plan."""
-    from trino_tpu.sql.planner import optimizer
-
-    monkeypatch.setattr(optimizer, "COMPACT_MIN_SLOTS", 1 << 10)
-
-
 def test_q3_counts_its_compactions_on_the_compact_rows(
         monkeypatch, compacting_plans):
     from trino_tpu.exec import query as query_module

@@ -223,6 +223,10 @@ SYSTEM_TABLES = {
         # pages Executor.compact_to squeezed to their live rows, the
         # positions listed from prefix counts (ops/ranks.py true_positions)
         ("prefix_compactions", "bigint"),
+        # lookup joins that squeezed the probe's match to the capacity of
+        # the Compact above them before gathering a build payload
+        # (Executor.compacted_lookup_join), on the Join's row
+        ("compacted_joins", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

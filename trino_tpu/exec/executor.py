@@ -304,12 +304,16 @@ class Executor:
     def raise_errors(self):
         raise_query_errors([c for c, _ in self.errors], [f for _, f in self.errors])
 
-    def execute(self, node: P.PlanNode) -> Page:
+    def execute(self, node: P.PlanNode, **how) -> Page:
+        """Run ``node`` (and, recursively, its sources) to a page. ``how``
+        is handed to the node's own method: what a parent that has seen
+        the plan shape asks of this child (a Compact asking its join to
+        squeeze the match first, ``_exec_CompactNode``)."""
         method = getattr(self, f"_exec_{type(node).__name__}", None)
         if method is None:
             raise NotImplementedError(f"executor: {type(node).__name__}")
         if not self.collect_stats:
-            return method(node)
+            return method(node, **how)
         # per-operator profiling, always on in the eager tier (reference:
         # OperatorContext/OperatorStats via OperationTimer — SURVEY.md §5.1)
         kind = operator_kind(node)
@@ -328,7 +332,7 @@ class Executor:
         try:
             with tracing.span(f"operator/{kind}",
                               planNodeId=node.id) as sp, charge_to(ks):
-                page = method(node)
+                page = method(node, **how)
                 if self.profile_sync:
                     t_sync = time.perf_counter()
                     try:
@@ -590,12 +594,29 @@ class Executor:
         when it cannot help (no selection mask, or capacity >= the page's
         rows — e.g. an SPMD shard already smaller than the global
         estimate). Overflow raises CAPACITY_EXCEEDED:cmp:<id> for the
-        recompile-growth loop."""
-        page = self.execute(node.source)
+        recompile-growth loop.
+
+        Directly on a join that compacts its match (the shape
+        optimizer.insert_compactions plans for q3's two joins) the node
+        hands the join its own id: the join squeezes the probe's match to
+        this node's capacity BEFORE it gathers a build payload
+        (``compacted_lookup_join``), and the page that comes back is
+        already the one ``compact_to`` would make, so nothing is left to
+        do here."""
+        if self._join_compacts_match(node.source):
+            page = self.execute(node.source, compact_into=node)
+        else:
+            page = self.execute(node.source)
         if page.sel is None:
             return page
         capacity = self.hint_capacity(f"cmp:{node.id}", page.sel.astype(jnp.int32))
         return self.compact_to(page, capacity, f"cmp:{node.id}")
+
+    def _join_compacts_match(self, source: P.PlanNode) -> bool:
+        """Whether a Compact on ``source`` is run by the join itself. The
+        plan shape decides (no property); a tier whose lookup join is not
+        ``Executor.lookup_join`` overrides this (parallel/spmd.py)."""
+        return P.compacts_its_match(source)
 
     def compact_to(self, page: Page, capacity: int, key: str) -> Page:
         """Squeeze live rows into a ``capacity``-slot page: the positions
@@ -606,7 +627,9 @@ class Executor:
         order is kept (positions ascend); slots past the live count hold
         row 0's values under a False ``sel``. Overflow raises
         CAPACITY_EXCEEDED:<key> for the recompile-growth loop. Shared by
-        CompactNode and the device-side dynamic-filter scans."""
+        CompactNode and the device-side dynamic-filter scans; a lookup
+        join under a CompactNode makes the same page itself
+        (``compacted_lookup_join``)."""
 
         n = page.num_rows
         if page.sel is None or capacity >= n:
@@ -616,14 +639,29 @@ class Executor:
             # (data-dependent shapes); keep the selection mask instead —
             # semantically identical, just uncompacted
             return page
-        live = page.sel
-        total = jnp.sum(live.astype(jnp.int32))
-        self.errors.append((f"CAPACITY_EXCEEDED:{key}", total > capacity))
-        idx = ranks_ops.true_positions(live, capacity)
+        idx, sel = self._kept_positions(
+            page.sel, jnp.sum(page.sel.astype(jnp.int32)), capacity, key)
         if self.eager_tier:  # a traced tier would count its trace, not its runs
             count_charged("prefixCompactions")
-        arrays = []
-        for c in page.columns:
+        cols, _ = self._columns_at(page.columns, idx)
+        return Page(cols, sel, page.replicated, live_prefix=True)
+
+    def _kept_positions(self, live, total, capacity: int, key: str):
+        """(positions of the first ``capacity`` of ``live``'s ``total``
+        set rows, the squeezed page's mask); more than ``capacity`` of
+        them flags CAPACITY_EXCEEDED:<key>."""
+        self.errors.append((f"CAPACITY_EXCEEDED:{key}", total > capacity))
+        idx = ranks_ops.true_positions(live, capacity)
+        kept = jnp.arange(capacity, dtype=jnp.int32) < jnp.minimum(total, capacity)
+        return idx, kept
+
+    @staticmethod
+    def _columns_at(columns, idx, extra=()):
+        """(``columns`` at the ASCENDING row ids ``idx``, ``extra`` arrays
+        at them): values, null masks and hi limbs in ONE batched
+        row-gather per dtype group."""
+        arrays = list(extra)
+        for c in columns:
             arrays.append(c.values)
             if c.nulls is not None:
                 arrays.append(c.nulls)
@@ -631,8 +669,8 @@ class Executor:
                 arrays.append(c.hi)
         gathered = ranks_ops.batched_gather(arrays, idx)
         cols = []
-        i = 0
-        for c in page.columns:
+        i = len(extra)
+        for c in columns:
             v = gathered[i]
             i += 1
             nulls = None
@@ -646,8 +684,7 @@ class Executor:
             # stable: live rows keep their relative order -> ascending holds
             cols.append(Column(c.type, v, nulls, c.dictionary, c.vrange,
                                ascending=c.ascending, hi=chi))
-        sel = jnp.arange(capacity, dtype=jnp.int32) < jnp.minimum(total, capacity)
-        return Page(cols, sel, page.replicated, live_prefix=True)
+        return cols, gathered[:len(extra)]
 
     def _exec_ProjectNode(self, node: P.ProjectNode) -> Page:
         page = self.execute(node.source)
@@ -1675,22 +1712,26 @@ class Executor:
         return Page(out_cols, page.sel, page.replicated)
 
     # -------------------------------------------------------------- joins
-    def _exec_JoinNode(self, node: P.JoinNode) -> Page:
+    def _exec_JoinNode(self, node: P.JoinNode,
+                       compact_into: Optional[P.CompactNode] = None) -> Page:
         # Build side FIRST (the reference's phased build-before-probe
         # ordering) so its key domains can dynamically narrow probe scans.
         right = self.execute(node.right)
         if self.enable_dynamic_filtering and node.dyn_filter_keys:
             self._collect_dynamic_filters(node, right)
         left = self.execute(node.left)
-        return self._dispatch_join(node, left, right)
+        return self._dispatch_join(node, left, right, compact_into)
 
-    def _dispatch_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
+    def _dispatch_join(self, node: P.JoinNode, left: Page, right: Page,
+                       compact_into: Optional[P.CompactNode] = None) -> Page:
         if node.left_keys and self.eager_tier:
             # eager tier: spill-partition when the working set exceeds the
             # device budget (traced tiers bound memory via capacity hints)
             spilled = self._maybe_spill_join(node, left, right)
             if spilled is not None:
-                return spilled
+                return spilled  # the passes' own path: each compacts on the host
+        if compact_into is not None:
+            return self.compacted_lookup_join(node, left, right, compact_into)
         return self._run_join_kernel(node, left, right)
 
     def _maybe_spill_join(self, node: P.JoinNode, left: Page, right: Page):
@@ -2138,30 +2179,74 @@ class Executor:
         build = join_ops.build_side(build_keys, right.sel, presorted=presorted)
         return join_ops.probe_unique(build, probe_keys)
 
-    def lookup_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
+    def _lookup_probe(self, node: P.JoinNode, left: Page, right: Page):
+        """(build_row_idx, matched) for every probe slot of the N:1 lookup
+        join: the half that moves no payload."""
         dense = self._dense_join_cols(node, left, right)
-        if dense is not None:
-            # cost gate: dense-keyed builds keep the direct-address fast
-            # path (one scatter + one bounded gather, no sort: the tier
-            # every benchmark cell takes for lineitem-orders, PERF.md
-            # section 5)
-            M.FUSED_JOIN_SELECTIONS.inc(1, "dense")
-            bc, pc, lo, span = dense
-            table = join_ops.dense_unique_table(
-                _col_to_lowered(bc), right.sel, lo, span)
-            rows, matched = join_ops.dense_probe_unique(
-                table, _col_to_lowered(pc), lo)
-        else:
-            rows, matched = self._sortmerge_probe(node, left, right)
+        if dense is None:
+            return self._sortmerge_probe(node, left, right)
+        # cost gate: dense-keyed builds keep the direct-address fast path
+        # (one scatter of the build's row ids into the span table, one
+        # bounded gather of it a probe slot, no sort: the tier every
+        # benchmark cell takes for lineitem-orders, PERF.md section 5)
+        M.FUSED_JOIN_SELECTIONS.inc(1, "dense")
+        bc, pc, lo, span = dense
+        table = join_ops.dense_unique_table(
+            _col_to_lowered(bc), right.sel, lo, span)
+        return join_ops.dense_probe_unique(table, _col_to_lowered(pc), lo)
+
+    def lookup_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
+        rows, matched = self._lookup_probe(node, left, right)
         return self._assemble_lookup_output(node, left, right, rows, matched)
+
+    def compacted_lookup_join(self, node: P.JoinNode, left: Page, right: Page,
+                              into: P.CompactNode) -> Page:
+        """``compact_to(lookup_join(node, left, right), capacity,
+        "cmp:<into.id>")`` slot for slot, with the squeeze moved between
+        the probe and the payload gather. The match mask is known after
+        the probe, so the probe columns and the matched build row ids are
+        gathered at the kept positions (``capacity`` rows, in the one
+        batched gather ``compact_to`` issues) and the build payloads at
+        THOSE row ids: ``capacity`` rows, not the probe page's n. q3's
+        lineitem-orders join at SF 10 kept 2,097,152 of 62,914,560 slots
+        and wrote four payload columns and their null masks for all of
+        them first: the largest device operation of both SF 10 cells
+        (PERF.md section 6, PR 33). Same capacity hint, same
+        CAPACITY_EXCEEDED:cmp:<id> flag; a page ``compact_to`` would
+        return as it came takes the plain path and the Compact above
+        returns it likewise."""
+        key = f"cmp:{into.id}"
+        rows, matched = self._lookup_probe(node, left, right)
+        sel = matched if left.sel is None else (left.sel & matched)
+        total = jnp.sum(sel.astype(jnp.int32))
+        capacity = self.hint_capacity(key, total)
+        if (capacity >= left.num_rows
+                or any(c.type.is_nested for c in left.columns)):
+            return self._assemble_lookup_output(
+                node, left, right, rows, matched)
+        idx, live = self._kept_positions(sel, total, capacity, key)
+        if self.eager_tier:  # a traced tier would count its trace, not its runs
+            count_charged("compactedJoins")
+            # the page is squeezed here, for that node: its row keeps count
+            self._kernel_row(into)["prefixCompactions"] += 1
+        cols, (rows_at,) = self._columns_at(left.columns, idx, (rows,))
+        # matched[idx] with no gather (a word a slot out of the n-slot
+        # mask read 20 ms at q3's shape): a live slot holds a matched row,
+        # a slot past the count holds row 0
+        matched_at = live | matched[0]
+        cols.extend(self._gather_right_cols(right.columns, rows_at, matched_at))
+        return Page(cols, live, left.replicated, live_prefix=True)
 
     def _assemble_lookup_output(self, node: P.JoinNode, left: Page,
                                 right: Page, rows, matched) -> Page:
         """Projection half of the lookup join: gather build payloads at the
-        matched rows and apply join-type/filter semantics. ROW-LOCAL in the
-        probe (each output row depends only on its probe row and the whole
-        build) — the property the overlapped SPMD exchange relies on to
-        consume probe blocks independently (parallel/spmd.py)."""
+        matched rows, for EVERY probe slot, and apply join-type/filter
+        semantics. ROW-LOCAL in the probe (each output row depends only on
+        its probe row and the whole build) — the property the overlapped
+        SPMD exchange relies on to consume probe blocks independently
+        (parallel/spmd.py). An inner join with no filter whose consumer is
+        a Compact does not come here: ``compacted_lookup_join`` gathers at
+        the kept slots only."""
         out_cols = list(left.columns)
         out_cols.extend(self._gather_right_cols(right.columns, rows, matched))
         if node.join_type == "inner":

@@ -42,8 +42,13 @@ the row of the operator that is executing on the thread
 primitives one by one, and ``prefixCompactions``, the pages
 ``Executor.compact_to`` squeezed to their live rows (positions from
 prefix counts, ops/ranks.py ``true_positions``; a page it returns as it
-came counts nothing). A scan's row counts what the device cache did for
-it (devcache/keys.py ``cached_stage``): ``cacheHits`` / ``cacheMisses``
+came counts nothing). A Join's row counts ``compactedJoins``: lookup
+joins that squeezed the probe's match to the capacity of the Compact
+above them BEFORE gathering a build payload
+(``Executor.compacted_lookup_join``; that Compact's row still counts the
+page under ``prefixCompactions``). A scan's row counts what the device
+cache did for it (devcache/keys.py ``cached_stage``): ``cacheHits`` /
+``cacheMisses``
 (lookups by disposition; a bypass counts neither) and ``stagedBytes``,
 the bytes it copied host -> device (0 on a hit).
 
@@ -98,7 +103,7 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "compiles": 0, "compileS": 0.0, "hostSyncSites": {},
            "aggPrograms": 0, "aggEager": 0,
            "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0,
-           "prefixCompactions": 0}
+           "prefixCompactions": 0, "compactedJoins": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -134,7 +139,7 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
         for field in ("launches", "inputBytes", "outputBytes", "hostSyncs",
                       "d2hBytes", "compiles", "aggPrograms", "aggEager",
                       "cacheHits", "cacheMisses", "stagedBytes",
-                      "prefixCompactions"):
+                      "prefixCompactions", "compactedJoins"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

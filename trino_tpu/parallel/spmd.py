@@ -275,6 +275,14 @@ class SpmdExecutor(Executor):
         M.EXCHANGE_OVERLAPPED.inc(1, str(blocks))
         return out
 
+    def _join_compacts_match(self, source: P.PlanNode) -> bool:
+        """Never here: this tier's lookup join is an exchange first (the
+        overlapped pipeline consumes probe BLOCKS through the row-local
+        ``_assemble_lookup_output``, the other two move a side across the
+        mesh), so a Compact on a join squeezes the joined shard page as
+        it does on any other source."""
+        return False
+
     def lookup_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
         out = self._overlapped_join(node, left, right, semi=False)
         if out is not None:

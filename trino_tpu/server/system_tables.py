@@ -319,6 +319,7 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("cacheHits", 0)), int(r.get("cacheMisses", 0)),
             int(r.get("stagedBytes", 0)),
             int(r.get("prefixCompactions", 0)),
+            int(r.get("compactedJoins", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

@@ -110,6 +110,14 @@ def insert_compactions(node: P.PlanNode, session) -> P.PlanNode:
     (join / aggregation / window / set-op) would pay per-slot costs for the
     dead ones. Sorts/TopN are not considered: whether squeezing the page
     first pays for a sort of it has not been measured.
+    The node goes on top of the child, with one exception: where the child
+    is Projects over a join that compacts its match (P.compacts_its_match:
+    inner N:1 lookup, no residual filter) it goes UNDER the Projects,
+    directly on the join, Project(Compact(Join)). A Project is row-local,
+    so the rows are the same and its expressions run over the kept slots;
+    and the executor, which sees the pair, squeezes the probe's match
+    before it gathers a single build payload
+    (Executor.compacted_lookup_join).
     Capacities are estimates; underestimates raise CAPACITY_EXCEEDED and
     the bucketed recompile loop doubles them (CompiledQuery.run)."""
     from trino_tpu.sql.planner import stats
@@ -122,6 +130,13 @@ def insert_compactions(node: P.PlanNode, session) -> P.PlanNode:
             return child
         live = stats.estimate_live_rows(session, child)
         if slots < COMPACT_MIN_RATIO * live * 1.3:
+            return child
+        lowest = None  # the Project directly on the join, if that is the shape
+        under = child
+        while isinstance(under, P.ProjectNode):
+            lowest, under = under, under.source
+        if lowest is not None and P.compacts_its_match(under):
+            lowest.source = P.CompactNode(under, estimated_rows=live)
             return child
         return P.CompactNode(child, estimated_rows=live)
 

@@ -97,9 +97,13 @@ class CompactNode(PlanNode):
     TPU-first: filters keep selection masks instead of compacting (static
     shapes), so a selective pipeline drags dead slots through every
     downstream sort/join. When the optimizer's cardinality estimate says
-    live rows are far below the slot count, this node pays one stable
-    payload-carrying sort (live rows first, original order kept) to shrink
-    the working set. Capacity comes from stats (hint key ``cmp:<id>``);
+    live rows are far below the slot count, this node lists the live
+    rows' positions from prefix counts of the mask and gathers the kept
+    rows (live rows first, original order kept) to shrink the working
+    set; directly on a join that compacts its match
+    (``compacts_its_match``) the join squeezes before it gathers its
+    build payloads and hands this node the finished page.
+    Capacity comes from stats (hint key ``cmp:<id>``);
     a too-small estimate raises CAPACITY_EXCEEDED and the bucketed
     recompile loop doubles it. Reference role: the implicit compaction the
     reference gets for free from page-at-a-time operators that drop
@@ -650,6 +654,17 @@ def uses_expansion_kernel(n: JoinNode) -> bool:
     if n.join_type in ("semi", "anti"):
         return n.filter is not None
     return not n.right_unique and not n.singleton
+
+
+def compacts_its_match(n: "PlanNode") -> bool:
+    """True for the join whose MATCH a CompactNode placed directly on it
+    squeezes before any build payload moves (Executor.compacted_lookup_join):
+    an inner, equi-keyed N:1 lookup join with no residual filter, so the
+    probe's (row, matched) pair is all the join knows about a slot. The
+    optimizer's compaction pass places the node by this and the executor
+    dispatches by it."""
+    return (isinstance(n, JoinNode) and n.join_type == "inner"
+            and bool(n.left_keys) and n.right_unique and n.filter is None)
 
 
 def kernel_annotations(rows) -> dict:
