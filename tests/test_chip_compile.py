@@ -162,6 +162,25 @@ def test_the_compacted_joins_gathers_compile_at_q3s_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+@pytest.mark.parametrize("what", ["sums", "counts"])
+def test_the_run_layouts_scans_compile_at_q18s_shape(one_chip, what):
+    """Q18's group-by of lineitem by ``l_orderkey`` at SF 10: 60 x 2^20
+    slots, already group-contiguous, aggregated by prefix scans alone
+    (``ops/segments.py`` run layout), with no gather in the program: one
+    62.9 M-slot gather out of a 62.9 M-row array took 2 s on the chip."""
+    from trino_tpu.ops import segments as seg
+
+    n = 62_914_560
+    flag = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip)
+    if what == "sums":
+        arg = jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
+        compiled = jax.jit(seg._run_sums).lower(flag, arg).compile()
+    else:
+        compiled = jax.jit(seg._run_counts).lower(flag, flag).compile()
+    assert " gather(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 def test_spmd_hash_partitioned_q3_compiles_with_all_to_all(topo):
     """The SPMD tier's promise — shuffles are ICI collectives — checked
     in the program the v5e compiler emits. ``DistributedQuery`` stages onto

@@ -130,14 +130,14 @@ def q6(schema="tiny"):
     return [(total,)]
 
 
-def q18(schema="tiny", limit=100):
+def q18(schema="tiny", limit=100, quantity=300):
     cust = load_table(schema, "customer", ["c_custkey", "c_name"])
     orders = load_table(schema, "orders", ["o_orderkey", "o_custkey", "o_orderdate", "o_totalprice"])
     li = load_table(schema, "lineitem", ["l_orderkey", "l_quantity"])
     qty = defaultdict(Decimal)
     for r in li:
         qty[r["l_orderkey"]] += r["l_quantity"]
-    big = {k for k, v in qty.items() if v > 300}
+    big = {k for k, v in qty.items() if v > quantity}
     cmap = {c["c_custkey"]: c["c_name"] for c in cust}
     rows = []
     for o in orders:

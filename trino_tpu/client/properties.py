@@ -11,6 +11,7 @@ when a new subsystem grows a switch.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Callable, Dict, Optional
 
 
@@ -25,6 +26,30 @@ class PropertyMetadata:
 
 def _positive(v) -> Optional[str]:
     return None if v > 0 else "must be positive"
+
+
+_DURATION_UNIT_SECONDS = {"ns": 1e-9, "us": 1e-6, "ms": 1e-3, "s": 1.0,
+                          "m": 60.0, "h": 3600.0, "d": 86400.0}
+
+
+def parse_duration(text: str) -> float:
+    """Seconds of a duration in the reference's syntax (airlift
+    ``Duration``): a number and a unit, ``15m``, ``1.5h``, ``30 s``,
+    ``100ms``; units ns, us, ms, s, m, h, d. Raises ValueError on anything
+    else."""
+    m = re.fullmatch(r"\s*(\d+(?:\.\d+)?)\s*([a-z]+)\s*", str(text))
+    if m is None or m.group(2) not in _DURATION_UNIT_SECONDS:
+        raise ValueError(
+            f"{text!r} is not a duration: a number and one of "
+            f"{', '.join(_DURATION_UNIT_SECONDS)} (for example 15m)")
+    return float(m.group(1)) * _DURATION_UNIT_SECONDS[m.group(2)]
+
+
+def _positive_duration(v) -> Optional[str]:
+    try:
+        return None if parse_duration(v) > 0 else "must be positive"
+    except ValueError as e:
+        return str(e)
 
 
 SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
@@ -285,6 +310,16 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "stages); default (unset) = AUTO: on under pytest, off "
             "otherwise",
             bool, None,
+        ),
+        PropertyMetadata(
+            "query_max_execution_time",
+            "the longest a statement may execute, as a duration ('15m', "
+            "'90s', '1.5h'); counted from when the statement leaves the "
+            "queue; a statement that passes it is ended by the coordinator "
+            "with EXCEEDED_TIME_LIMIT, its tasks are cancelled and the "
+            "server keeps serving; unset: no limit (reference: "
+            "query.max-execution-time / query_max_execution_time)",
+            str, None, _positive_duration,
         ),
         PropertyMetadata(
             "query_max_history",

@@ -227,6 +227,13 @@ SYSTEM_TABLES = {
         # the Compact above them before gathering a build payload
         # (Executor.compacted_lookup_join), on the Join's row
         ("compacted_joins", "bigint"),
+        # executions of a single-step aggregation finished inside the
+        # source fragment that scans its table (the group keys include the
+        # table's partitioning columns), on the Aggregation's row
+        ("colocated_aggs", "bigint"),
+        # live rows a task handed to its output buffer, on the row of its
+        # fragment's root operator: what crossed an exchange
+        ("exchanged_rows", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

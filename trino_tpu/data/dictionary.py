@@ -26,11 +26,20 @@ class Dictionary:
     pure integer comparisons on device.
     """
 
-    __slots__ = ("values", "_index")
+    __slots__ = ("values", "_lookup")
 
     def __init__(self, sorted_values: Sequence[str]):
         self.values: List[str] = list(sorted_values)
-        self._index = {v: i for i, v in enumerate(self.values)}
+        # string -> code, built when something first asks for a code: a
+        # vocabulary that only crosses an exchange (1.5 M customer names a
+        # Q18 at SF 10) is decoded and recoded by position and never asked
+        self._lookup = None
+
+    @property
+    def _index(self):
+        if self._lookup is None:
+            self._lookup = {v: i for i, v in enumerate(self.values)}
+        return self._lookup
 
     @classmethod
     def build(cls, strings: Iterable[Optional[str]]) -> "Dictionary":

@@ -318,16 +318,29 @@ def _concat_cols(cols: Sequence[Column]) -> Column:
     dicts = [c.dictionary for c in cols]
     if all(x is not None for x in dicts) and any(
             x is not d and x.values != d.values for x in dicts[1:]):
-        d = Dictionary(sorted(set().union(*(x.values for x in dicts))))
+        offsets = _consecutive_vocabularies(dicts)
+        if offsets is not None:
+            # each page's vocabulary lies wholly after the one before it
+            # (the chunks of one key-ordered page): the merged vocabulary
+            # is their concatenation and a code moves by its page's offset
+            d = Dictionary([s for x in dicts for s in x.values])
+            # (an all-NULL page has an empty vocabulary and whatever codes
+            # under its null mask: they become NULL_CODE, as below)
+            vals = [jnp.full_like(v, NULL_CODE) if not x.values
+                    else jnp.where(v >= 0, v + off, NULL_CODE) if off else v
+                    for v, off, x in zip(vals, offsets, dicts)]
+        else:
+            d = Dictionary(sorted(set().union(*(x.values for x in dicts))))
 
-        def recode(v, src_dict):
-            t = np.asarray(src_dict.recode_table(d))
-            # an all-NULL side has an empty vocab: pad so the gather
-            # below stays in range (its codes are all NULL_CODE anyway)
-            t = jnp.asarray(t if len(t) else np.array([NULL_CODE], np.int32))
-            return jnp.where(v >= 0, t[jnp.clip(v, 0)], NULL_CODE)
+            def recode(v, src_dict):
+                t = np.asarray(src_dict.recode_table(d))
+                # an all-NULL side has an empty vocab: pad so the gather
+                # below stays in range (its codes are all NULL_CODE anyway)
+                t = jnp.asarray(
+                    t if len(t) else np.array([NULL_CODE], np.int32))
+                return jnp.where(v >= 0, t[jnp.clip(v, 0)], NULL_CODE)
 
-        vals = [recode(v, x) for v, x in zip(vals, dicts)]
+            vals = [recode(v, x) for v, x in zip(vals, dicts)]
     hi = None
     if any(c.hi is not None for c in cols):
         # a missing hi limb is the sign extension of the low word
@@ -338,6 +351,22 @@ def _concat_cols(cols: Sequence[Column]) -> Column:
         merge_vrange, (c.vrange for c in cols))
     return Column(first.type, jnp.concatenate(vals), _concat_nulls(cols), d,
                   vr, hi=hi)
+
+
+def _consecutive_vocabularies(dicts) -> Optional[List[int]]:
+    """Each dictionary's code offset in the concatenation of all of them,
+    where every vocabulary's strings sort after the previous one's (empty
+    ones anywhere); None where they interleave or repeat."""
+    offsets, last, total = [], None, 0
+    for d in dicts:
+        offsets.append(total)
+        if not d.values:
+            continue
+        if last is not None and not last < d.values[0]:
+            return None
+        last = d.values[-1]
+        total += len(d.values)
+    return offsets
 
 
 def _concat_nulls(cols: Sequence[Column]):

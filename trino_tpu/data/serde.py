@@ -61,6 +61,25 @@ _DTYPE_CODES = {
 _CODE_DTYPES = {v: k for k, v in _DTYPE_CODES.items()}
 
 
+# a page whose varchar vocabulary is larger than this AND than its row count
+# ships only the entries its rows reference: the chunks of one output page
+# share the page's dictionary (Page.slice_rows), and each wrote all of it
+# (five chunks of customer at SF 10 wrote 1.5 M names five times, and the
+# consumer decoded them five times: most of a Q18 statement's 10 s)
+VOCAB_PRUNE_MIN = 4096
+
+
+def _referenced_vocabulary(codes: np.ndarray, vocab):
+    """(codes, vocabulary) cut to the entries ``codes`` reference, in
+    vocabulary order, so that code order stays string order; negative
+    codes (NULL) stay as they are."""
+    live = codes >= 0
+    used = np.unique(codes[live])
+    out = codes.copy()
+    out[live] = np.searchsorted(used, codes[live]).astype(codes.dtype)
+    return out, [vocab[i] for i in used.tolist()]
+
+
 def _serialize_column(col: Column, n: int, parts: List[bytes]) -> None:
     name = str(col.type).encode()
     parts.append(struct.pack("<H", len(name)))
@@ -71,6 +90,12 @@ def _serialize_column(col: Column, n: int, parts: List[bytes]) -> None:
     else:
         parts.append(b"\x00")
     vals_np = np.ascontiguousarray(host_read(col.values, "serialize"))
+    vocab = None
+    if col.type.is_varchar:
+        assert col.dictionary is not None
+        vocab = col.dictionary.values
+        if len(vocab) > max(VOCAB_PRUNE_MIN, n):
+            vals_np, vocab = _referenced_vocabulary(vals_np, vocab)
     dtype_code = _DTYPE_CODES[vals_np.dtype]
     if col.hi is not None:
         # long-decimal two-limb column: flag bit 7 on the dtype code, hi
@@ -82,9 +107,7 @@ def _serialize_column(col: Column, n: int, parts: List[bytes]) -> None:
     else:
         parts.append(struct.pack("<B", dtype_code))
         parts.append(vals_np.tobytes())
-    if col.type.is_varchar:
-        assert col.dictionary is not None
-        vocab = col.dictionary.values
+    if vocab is not None:
         parts.append(struct.pack("<I", len(vocab)))
         for s in vocab:
             b = s.encode()

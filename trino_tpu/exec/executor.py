@@ -786,6 +786,9 @@ class Executor:
             return self.aggregate_partial(node, page)
         if node.step == "final":
             return self.aggregate_final(node, page)
+        if node.distribution == "colocated":
+            # finished where its table is scanned: nothing was cut under it
+            count_charged("colocatedAggs")
         return self.aggregate_page(node, page)
 
     def aggregate_partial(self, node: P.AggregationNode, page: Page) -> Page:
@@ -1071,7 +1074,8 @@ class Executor:
         raise NotImplementedError(call.function)
 
     def group_structure(
-        self, group_channels: List[int], page: Page, payloads=(), force_sort=False
+        self, group_channels: List[int], page: Page, payloads=(), force_sort=False,
+        in_place=False,
     ):
         """(GroupLayout, out_sel, payloads_l, sel_l): group assignment.
 
@@ -1118,6 +1122,12 @@ class Executor:
             neq = vals[1:] != vals[:-1]
             boundary = jnp.concatenate(
                 [jnp.ones((1,), bool), neq | (dead[1:] != dead[:-1])])
+            if in_place:
+                # the caller's aggregates are prefix scans over the runs
+                # (sum, count, avg of integers): no group is listed, no
+                # slot gathered; each group's row is its run's last
+                layout = seg.run_layout(boundary)
+                return layout, seg.run_ends(layout) & ~dead, list(payloads), sel
             gid_sorted = scans.cumsum(boundary.astype(jnp.int32)) - 1
             num_groups = jnp.sum(boundary & ~dead)
             layout = seg.sorted_layout(
@@ -1193,6 +1203,22 @@ class Executor:
         return col.values
 
     @staticmethod
+    def _scans_alone(node: P.AggregationNode, page: Page) -> bool:
+        """Whether every aggregate of ``node`` is a prefix scan over
+        presorted runs (``seg.run_layout``): sum, count and avg of integer
+        (or decimal) arguments held in one limb. Anything else reads the
+        sorted layout's group list."""
+        for call in node.aggregates:
+            if call.distinct or call.function not in ("sum", "count", "avg"):
+                return False
+            if call.arg_channel is not None:
+                col = page.columns[call.arg_channel]
+                if col.hi is not None or not jnp.issubdtype(
+                        col.values.dtype, jnp.integer):
+                    return False
+        return True
+
+    @staticmethod
     def _direct_strides(group_channels: List[int], page: Page):
         sizes = []
         for c in group_channels:
@@ -1249,10 +1275,14 @@ class Executor:
             for c in node.aggregates
         )
         layout, out_sel, payloads_l, sel_l = self.group_structure(
-            node.group_channels, page, payload_arrays, force_sort=force_sort
+            node.group_channels, page, payload_arrays, force_sort=force_sort,
+            in_place=self._scans_alone(node, page),
         )
         out_cols: List[Column] = []
-        if node.group_channels:
+        if layout.run_start is not None:
+            # slot i is row i: the key column is its own output
+            out_cols.append(page.columns[node.group_channels[0]])
+        elif node.group_channels:
             out_cols.extend(
                 self._gathered_key_cols(page, node.group_channels, layout)
             )

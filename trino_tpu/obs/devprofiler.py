@@ -46,7 +46,14 @@ came counts nothing). A Join's row counts ``compactedJoins``: lookup
 joins that squeezed the probe's match to the capacity of the Compact
 above them BEFORE gathering a build payload
 (``Executor.compacted_lookup_join``; that Compact's row still counts the
-page under ``prefixCompactions``). A scan's row counts what the device
+page under ``prefixCompactions``). An Aggregation's row counts
+``colocatedAggs``: executions of a single-step aggregation the fragmenter
+finished inside the source fragment that scans its table
+(sql/planner/fragmenter.py ``_colocated_aggregation``: no partial/final
+cut, no exchange under it). The row of a fragment's ROOT operator counts
+``exchangedRows``: the live rows its task handed to its output buffer
+(server/task.py ``SqlTask._output_path``), what crosses an exchange. A
+scan's row counts what the device
 cache did for it (devcache/keys.py ``cached_stage``): ``cacheHits`` /
 ``cacheMisses``
 (lookups by disposition; a bypass counts neither) and ``stagedBytes``,
@@ -103,7 +110,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "compiles": 0, "compileS": 0.0, "hostSyncSites": {},
            "aggPrograms": 0, "aggEager": 0,
            "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0,
-           "prefixCompactions": 0, "compactedJoins": 0}
+           "prefixCompactions": 0, "compactedJoins": 0,
+           "colocatedAggs": 0, "exchangedRows": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -139,7 +147,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
         for field in ("launches", "inputBytes", "outputBytes", "hostSyncs",
                       "d2hBytes", "compiles", "aggPrograms", "aggEager",
                       "cacheHits", "cacheMisses", "stagedBytes",
-                      "prefixCompactions", "compactedJoins"):
+                      "prefixCompactions", "compactedJoins",
+                      "colocatedAggs", "exchangedRows"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

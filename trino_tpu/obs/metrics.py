@@ -265,6 +265,10 @@ QUERIES = REGISTRY.gauge(
     "trino_tpu_queries", "tracked queries by lifecycle state", ("state",))
 QUERIES_TOTAL = REGISTRY.counter(
     "trino_tpu_queries_total", "queries submitted since server start")
+QUERIES_TIME_LIMITED = REGISTRY.counter(
+    "trino_tpu_queries_time_limited_total",
+    "statements the coordinator ended for passing query_max_execution_time "
+    "(EXCEEDED_TIME_LIMIT)")
 RESULT_ROWS = REGISTRY.gauge(
     "trino_tpu_result_rows", "result rows held by FINISHED tracked queries")
 WORKERS = REGISTRY.gauge(

@@ -16,6 +16,13 @@ integers; it uses one of two layouts:
   (stable multi-key argsort, dead rows last); per-group sums are
   cumsum-then-boundary-difference (exact in int64), min/max are a segmented
   associative scan — all streaming ops, no scatter.
+- **run** (input ALREADY group-contiguous: one ascending key): nothing is
+  permuted, listed or gathered. Slot i is row i, a group's results sit on
+  the LAST row of its run, and sums and counts are prefix scans alone
+  (``_run_sums``). On v5e a gather of 62.9 M slots out of a 62.9 M-row
+  array takes 2 s (TPC-H Q18's group-by at SF 10 issued eight a statement
+  through the sorted layout's ``starts`` / ``ends``); a scan of the same
+  array takes 0.05 s.
 
 Float sums still use ``jax.ops.segment_sum`` (f32 scatter is fast on TPU and
 per-slot accumulation order is deterministic).
@@ -59,6 +66,9 @@ class GroupLayout:
     ends: Optional[jnp.ndarray] = None  # int32[capacity]
     num_groups: Optional[jnp.ndarray] = None  # scalar (sorted only)
     rep: Optional[jnp.ndarray] = None  # int[capacity] representative row (orig order)
+    # run layout: bool[n], True on the first row of every run of equal keys
+    # (and where live rows end); slot i is row i, nothing else is populated
+    run_start: Optional[jnp.ndarray] = None
 
     @property
     def is_direct(self) -> bool:
@@ -127,6 +137,58 @@ def sorted_layout(
     )
 
 
+def run_layout(run_start: jnp.ndarray) -> GroupLayout:
+    """Layout of a page that is already group-contiguous: ``run_start``
+    marks each run's first row. Only ``seg_sum`` and ``seg_count`` read it
+    (sum, count and avg of integer arguments); whoever builds it keeps to
+    those (Executor.group_structure)."""
+    n = run_start.shape[0]
+    return GroupLayout(n=n, capacity=n, run_start=run_start)
+
+
+def run_ends(layout: GroupLayout) -> jnp.ndarray:
+    """bool[n]: the last row of every run, where a run layout's per-group
+    results sit."""
+    return jnp.concatenate([layout.run_start[1:], jnp.ones((1,), bool)])
+
+
+def _run_first(run_start: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """Per row, ``v`` at the first row of the row's run, for int64 ``v`` of
+    any sign, with no gather: a running maximum over (row index, half of
+    the value) packed into one word finds the latest flagged row, once for
+    each 32-bit half. Row 0 always starts a run."""
+    pos = jnp.arange(v.shape[0], dtype=jnp.int64) << 32
+    m32 = jnp.int64(0xFFFFFFFF)
+    halves = [
+        scans.cummax(jnp.where(run_start, pos | half, jnp.int64(-1))) & m32
+        for half in (v & m32, (v >> 32) & m32)]
+    return halves[0] | (halves[1] << 32)
+
+
+def _run_sums(run_start: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """Per row, the sum of int ``x`` over the row's run up to and including
+    the row (exact: wraparound cancels mod 2^64); on a run's last row, the
+    run's sum."""
+    x = x.astype(jnp.int64)
+    c = scans.cumsum(x)
+    return c - _run_first(run_start, c - x)
+
+
+def _run_counts(run_start: jnp.ndarray, m: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """Per row, how many rows of its run up to and including it satisfy
+    ``m``. A count before the run never decreases, so one running maximum
+    carries it forward."""
+    n = run_start.shape[0]
+    if m is None:
+        pos = jnp.arange(n, dtype=jnp.int32)
+        first = scans.cummax(jnp.where(run_start, pos, 0))
+        return (pos - first + 1).astype(jnp.int64)
+    m = m.astype(jnp.int32)
+    c = scans.cumsum(m)
+    before = scans.cummax(jnp.where(run_start, c - m, 0))
+    return (c - before).astype(jnp.int64)
+
+
 def occupancy(layout: GroupLayout, live: Optional[jnp.ndarray]) -> jnp.ndarray:
     """bool[capacity]: slots holding at least one live row (the live mask is
     already baked into ``rep`` by direct_layout)."""
@@ -162,6 +224,8 @@ def seg_sum(
     x = vals.astype(out_dtype)
     if m is not None:
         x = jnp.where(m, x, jnp.zeros((), out_dtype))
+    if layout.run_start is not None:
+        return _run_sums(layout.run_start, x).astype(out_dtype)
     if layout.is_direct:
         return jnp.stack([jnp.sum(jnp.where(layout.gids == g, x, 0)) for g in range(layout.capacity)])
     if jnp.issubdtype(jnp.dtype(out_dtype), jnp.floating):
@@ -175,6 +239,8 @@ def seg_sum(
 def seg_count(layout: GroupLayout, m: Optional[jnp.ndarray]) -> jnp.ndarray:
     """Per-slot count of rows where mask ``m`` holds (int64). ``m`` is in
     layout space (see seg_sum)."""
+    if layout.run_start is not None:
+        return _run_counts(layout.run_start, m)
     ones = (
         jnp.ones((layout.n,), jnp.int64)
         if m is None

@@ -241,6 +241,12 @@ class QueryExecution:
         # coordinator — feeds the ledger's segment-fetch phase (outside
         # the query wall, beside client-drain)
         self.last_segment_fetch_at: Optional[float] = None
+        # query_max_execution_time: the timer that ends this statement
+        # through kill() once it has executed for that long (armed when
+        # the lifecycle starts, disarmed when run() returns), and the root
+        # span it marks
+        self._time_limit_timer: Optional[threading.Timer] = None
+        self._root_span = None
 
     def start(self) -> None:
         """Run the lifecycle on a fresh thread (legacy surface — the
@@ -262,9 +268,36 @@ class QueryExecution:
         if self.state.set("FAILED"):
             self._cancel_tasks()
 
+    def _arm_time_limit(self, session) -> None:
+        """``query_max_execution_time``: from now (the statement has left
+        the queue) it may execute for that long; then the coordinator ends
+        it through :meth:`kill` (FAILED, tasks cancelled, error name
+        ``EXCEEDED_TIME_LIMIT``) and keeps serving (reference:
+        QueryTracker.enforceTimeLimits over query.max-execution-time)."""
+        text = session.properties.get("query_max_execution_time")
+        if text is None:
+            return
+        from trino_tpu.client.properties import parse_duration
+
+        def expire() -> None:
+            if self.state.is_terminal():
+                return
+            from trino_tpu.obs import metrics as M
+
+            M.QUERIES_TIME_LIMITED.inc()
+            if self._root_span is not None:
+                self._root_span.set("time-limit", text)
+            self.kill(f"Query exceeded the maximum execution time limit "
+                      f"of {text} (EXCEEDED_TIME_LIMIT)")
+
+        timer = threading.Timer(parse_duration(text), expire)
+        timer.daemon = True
+        self._time_limit_timer = timer
+        timer.start()
+
     # ------------------------------------------------------------ lifecycle
     def run(self) -> None:
-        root_span = self.tracer.start_span(
+        root_span = self._root_span = self.tracer.start_span(
             "query", query_id=self.query_id, user=self.user)
         # the dispatch-queue span opened before this root existed (the
         # HTTP thread enqueued, a lane dequeued): adopt it so the trace
@@ -311,6 +344,8 @@ class QueryExecution:
                 pass
             self.state.set("FAILED")
         finally:
+            if self._time_limit_timer is not None:
+                self._time_limit_timer.cancel()
             self.ended_at = self.ended_at or time.time()
             self.tracer.end_span(root_span)  # idempotent safety net
             # the latch decides: a kill()/cancel() racing this thread may
@@ -330,6 +365,7 @@ class QueryExecution:
         # procedures (CALL) resolve the calling query through the session:
         # system.runtime.kill_query refuses to kill its own query
         session.query_id = self.query_id
+        self._arm_time_limit(session)
         from trino_tpu.exec.query import run_query
         from trino_tpu.sql.parser import ast
         from trino_tpu.sql.parser.parser import parse_statement

@@ -42,7 +42,7 @@ def _cuts(session, node: P.PlanNode) -> Tuple[int, bool]:
     create, is_replicated). Unknown node kinds count as many stages so the
     fast path never claims a plan the fragmenter itself would reject."""
     from trino_tpu.sql.planner.fragmenter import (
-        _colocated_join, _hash_distributed_final)
+        _colocated_aggregation, _colocated_join, _hash_distributed_final)
 
     if isinstance(node, P.TableScanNode):
         return 0, False
@@ -53,6 +53,8 @@ def _cuts(session, node: P.PlanNode) -> Tuple[int, bool]:
         n, rep = _cuts(session, node.source)
         if rep:
             return n, True
+        if _colocated_aggregation(session, node, node.source):
+            return n, False
         if not P.can_split_aggs(node.aggregates):
             return n + 1, True
         if _hash_distributed_final(session, node):

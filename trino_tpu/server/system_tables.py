@@ -320,6 +320,8 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("stagedBytes", 0)),
             int(r.get("prefixCompactions", 0)),
             int(r.get("compactedJoins", 0)),
+            int(r.get("colocatedAggs", 0)),
+            int(r.get("exchangedRows", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

@@ -28,7 +28,8 @@ from trino_tpu.exec.operator_stats import OperatorStats
 from trino_tpu.obs import metrics as M
 from trino_tpu.obs import trace as tracing
 from trino_tpu.obs.devprofiler import (
-    charge_to, copy_kernel_row, host_read, merge_kernel_rows, new_kernel_row)
+    charge_to, copy_kernel_row, count_charged, host_read, merge_kernel_rows,
+    new_kernel_row)
 from trino_tpu.server.buffer import OutputBuffer, PartitionedOutputBuffer
 from trino_tpu.server.statemachine import StateMachine, task_state_machine
 from trino_tpu.sql.planner import plan as P
@@ -305,7 +306,7 @@ class SqlTask:
                 yield
         finally:
             if (row["hostSyncs"] or row["compiles"] or row["aggPrograms"]
-                    or row["aggEager"]):
+                    or row["aggEager"] or row["exchangedRows"]):
                 with self._stats_lock:
                     merge_kernel_rows(self.kernel_stats, [row])
 
@@ -315,7 +316,8 @@ class SqlTask:
         ``task/output`` span: compact, partition, chunk, serialise,
         enqueue (a wait at the buffer's watermark included) or segment
         write. The coordinator, or the consuming task, spends this time
-        waiting."""
+        waiting. The live rows handed to the output buffer inside it are
+        the root operator's ``exchangedRows``."""
         with tracing.span("task/output"), self._charge_root(page):
             yield
 
@@ -498,6 +500,7 @@ class SqlTask:
         with self._stats_lock:
             self.output_rows += page.num_rows
             self.output_bytes += self.flushing_bytes
+        count_charged("exchangedRows", int(page.num_rows))
         self.state.set("FLUSHING")
         chunk_rows = self._chunk_rows(page)
         if req.output_partition_channels is not None:
@@ -696,9 +699,11 @@ class SqlTask:
             return
         from trino_tpu.exec.memory import page_bytes
 
+        live = int(out.live_count())
         with self._stats_lock:
-            self.output_rows += int(out.live_count())
+            self.output_rows += live
             self.output_bytes += page_bytes(out)
+        count_charged("exchangedRows", live)
         chunk_rows = self._chunk_rows(out)
         if self._result_writer is not None and part_channels is None:
             # spooled result output (streaming shapes): chunks roll into

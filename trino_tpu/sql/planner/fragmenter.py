@@ -26,6 +26,7 @@ import itertools
 from typing import List, Optional, Tuple
 
 from trino_tpu.sql.planner import plan as P
+from trino_tpu.sql.planner.optimizer import _trace_to_scan
 
 _frag_ids = itertools.count()
 
@@ -87,6 +88,39 @@ def _hash_distributed_final(session, node: P.AggregationNode) -> bool:
     return rows > stats._gather_max_rows(session)
 
 
+def _colocated_aggregation(session, node: P.AggregationNode, src) -> bool:
+    """True when the aggregation is whole inside each split of the ONE scan
+    beneath it: the scan is reached through filters, projects and compacts
+    only (no exchange, no join, nothing that moves a row to another task),
+    and every partitioning column the connector declares for the table is
+    among the group keys. Rows with equal partitioning columns sit in one
+    split (``spi.TablePartitioning``), so rows with equal group keys do,
+    and the groups of two splits are disjoint: the aggregation runs
+    ``single`` in the source fragment, with whatever filters its output
+    above it (reference: no remote exchange under an aggregation whose
+    source partitioning satisfies the grouping, AddExchanges over a
+    bucketed table). Unlike ``_colocated_join`` nothing has to align with
+    another scan, so a static constraint on the key does not matter."""
+    if session is None or node.step != "single" or not node.group_channels:
+        return False
+    under = src
+    while isinstance(under, (P.FilterNode, P.ProjectNode, P.CompactNode)):
+        under = under.source
+    if not isinstance(under, P.TableScanNode):
+        return False
+    conn = session.catalogs.get(under.catalog)
+    part = (conn.table_partitioning(under.schema, under.table)
+            if conn is not None else None)
+    if part is None or not part.columns:
+        return False
+    grouped = set()
+    for ch in node.group_channels:
+        traced = _trace_to_scan(src, ch)
+        if traced is not None:
+            grouped.add(traced[1])
+    return set(part.columns) <= grouped
+
+
 def _colocated_join(session, node: P.JoinNode, left, right) -> bool:
     """True when both join sides trace to scans whose connector-declared
     partitionings share a family on exactly the join keys, and neither
@@ -97,10 +131,11 @@ def _colocated_join(session, node: P.JoinNode, left, right) -> bool:
         return False
     if node.join_type not in ("inner", "semi", "anti", "left"):
         return False
-    from trino_tpu.sql.planner.optimizer import _trace_to_scan
+    def whole_in_its_split(agg: P.AggregationNode) -> bool:
+        return _colocated_aggregation(session, agg, agg.source)
 
-    lt = _trace_to_scan(left, node.left_keys[0])
-    rt = _trace_to_scan(right, node.right_keys[0])
+    lt = _trace_to_scan(left, node.left_keys[0], whole_in_its_split)
+    rt = _trace_to_scan(right, node.right_keys[0], whole_in_its_split)
     if lt is None or rt is None:
         return False
     (lscan, lcol), (rscan, rcol) = lt, rt
@@ -141,6 +176,12 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             if rep:
                 node.source = src
                 return node, True
+            if _colocated_aggregation(session, node, src):
+                # every group is whole inside one split: finish the
+                # aggregation where the table is scanned, no exchange
+                node.source = src
+                node.distribution = "colocated"
+                return node, False
             if not P.can_split_aggs(node.aggregates):
                 # DISTINCT aggregates can't be split partial/final: gather the
                 # raw rows, aggregate single-step above the exchange

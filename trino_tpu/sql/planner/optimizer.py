@@ -36,6 +36,8 @@ def optimize(root: P.OutputNode, session=None) -> P.OutputNode:
     check(root, "initial-plan")
     node = push_predicates(root.source, [])
     check(node, "optimizer:push_predicates")
+    node = sink_semi_joins(node)
+    check(node, "optimizer:sink_semi_joins")
     node = orient_joins(node, session)
     check(node, "optimizer:orient_joins")
     node, _ = prune_channels(node, set(range(len(node.output_types))))
@@ -272,26 +274,39 @@ def reoptimize_distribution(session, join: P.JoinNode, n_workers: int) -> str:
             else "broadcast")
 
 
-def _trace_to_scan(node: P.PlanNode, channel: int):
+def _trace_to_scan(node: P.PlanNode, channel: int, through_agg=None):
     """Follow ``channel`` down through row-preserving/identity mappings to
-    the originating scan column, or None."""
+    the originating scan column, or None. In a subtree the fragmenter has
+    cut, an exchanged input is a RemoteSourceNode and ends the trace.
+    ``through_agg(aggregation) -> bool`` lets the trace pass a group key
+    (the fragmenter: an aggregation finished in its source fragment keeps
+    its groups in the split that holds their keys); without it an
+    aggregation ends the trace."""
     if isinstance(node, P.TableScanNode):
         return node, node.column_names[channel]
-    if isinstance(node, P.FilterNode):
+    if isinstance(node, (P.FilterNode, P.CompactNode)):
         # row-preserving in the required direction: pruned scan rows could
         # only be rows the join drops anyway. LIMIT is NOT traceable — which
         # rows a limit admits depends on what the scan materialized, so
         # pruning would change results.
-        return _trace_to_scan(node.source, channel)
+        return _trace_to_scan(node.source, channel, through_agg)
     if isinstance(node, P.ProjectNode):
         e = node.expressions[channel]
         if isinstance(e, ir.ColumnRef):
-            return _trace_to_scan(node.source, e.index)
+            return _trace_to_scan(node.source, e.index, through_agg)
+        return None
+    if isinstance(node, P.AggregationNode):
+        if (through_agg is not None and channel < len(node.group_channels)
+                and through_agg(node)):
+            return _trace_to_scan(node.source, node.group_channels[channel],
+                                  through_agg)
         return None
     if isinstance(node, P.JoinNode):
         if node.join_type in ("semi", "anti") or channel < len(node.left.output_types):
-            return _trace_to_scan(node.left, channel)
-        return _trace_to_scan(node.right, channel - len(node.left.output_types))
+            return _trace_to_scan(node.left, channel, through_agg)
+        return _trace_to_scan(node.right,
+                              channel - len(node.left.output_types),
+                              through_agg)
     return None
 
 
@@ -600,6 +615,63 @@ def _push_into_join(node: P.JoinNode, conjuncts: List[ir.Expr]) -> P.PlanNode:
 
 def prune_output(node: P.PlanNode) -> P.PlanNode:
     return node
+
+
+# ------------------------------------------------------- semi-join sinking
+
+
+def sink_semi_joins(node: P.PlanNode) -> P.PlanNode:
+    """Move a semi-join under the inner joins it filters (reference role:
+    PredicatePushDown treating a SemiJoinNode's output symbol as a
+    filter of its source side). ``x IN (subquery)`` keeps a left row by a
+    predicate on the left's key channels alone, so it commutes with every
+    inner join whose OTHER side does not supply the key:
+    ``semi(A join B, key of A) = semi(A) join B``. The planner puts the
+    semi-join on top of whatever the FROM clause had become when its
+    conjunct was reached (``_plan_predicate_subquery``); left there it
+    filters LAST, after every join has carried the rows it drops. Followed
+    through Projects (a key that is a bare column) and inner joins only;
+    a key that spans both sides of a join, comes from the nullable side of
+    an outer join, or is computed stays where it was. Anti-joins never
+    move: ``NOT IN`` keeps its null semantics where the planner put it.
+    Channel layouts are untouched: a semi-join's output is its left's."""
+    node = _replace_sources(node, [sink_semi_joins(s) for s in node.sources])
+    if (isinstance(node, P.JoinNode) and node.join_type == "semi"
+            and node.filter is None and node.left_keys):
+        moved = _place_semi(node.left, list(node.left_keys), node, False)
+        if moved is not None:
+            return moved
+    return node
+
+
+def _place_semi(target: P.PlanNode, keys: List[int], semi: P.JoinNode,
+                crossed: bool) -> Optional[P.PlanNode]:
+    """``target`` with ``semi`` applied at the deepest place its keys reach,
+    or None where no join was crossed on the way (the plan stays as it
+    is)."""
+    if isinstance(target, P.ProjectNode):
+        exprs = [target.expressions[k] for k in keys]
+        if all(isinstance(e, ir.ColumnRef) for e in exprs):
+            src = _place_semi(target.source, [e.index for e in exprs], semi,
+                              crossed)
+            if src is None:
+                return None
+            return P.ProjectNode(src, target.expressions, target.names)
+    elif (isinstance(target, P.JoinNode) and target.join_type == "inner"
+            and not target.singleton):
+        nleft = len(target.left.output_types)
+        if all(k < nleft for k in keys):
+            target.left = _place_semi(target.left, keys, semi, True)
+            return target
+        if all(k >= nleft for k in keys):
+            target.right = _place_semi(
+                target.right, [k - nleft for k in keys], semi, True)
+            return target
+    if not crossed:
+        return None
+    return P.JoinNode(
+        join_type="semi", left=target, right=semi.right, left_keys=keys,
+        right_keys=list(semi.right_keys), distribution=semi.distribution)
 
 
 # ----------------------------------------------------------------- pruning

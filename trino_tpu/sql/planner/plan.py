@@ -236,6 +236,11 @@ class AggregationNode(PlanNode):
     aggregates: List[AggregateCall] = None
     step: str = "single"
     names: List[str] = None
+    # 'colocated': a single-step aggregation the fragmenter finished inside
+    # the source fragment that scans its table, because the group keys
+    # include the table's partitioning columns (every group is whole inside
+    # one split): no partial/final cut, no exchange under it
+    distribution: Optional[str] = None
 
     @property
     def sources(self):
@@ -715,7 +720,9 @@ def format_plan(node: PlanNode, indent: int = 0, executor=None,
     elif isinstance(node, ProjectNode):
         detail = f" {[f'{n}:={e!r}' for n, e in zip(node.names, node.expressions)]}"
     elif isinstance(node, AggregationNode):
-        detail = f" [{node.step}] keys={node.group_channels} aggs={[a.function for a in self_aggs(node)]}"
+        detail = (
+            f" [{node.step}{'/' + node.distribution if node.distribution else ''}]"
+            f" keys={node.group_channels} aggs={[a.function for a in self_aggs(node)]}")
     elif isinstance(node, JoinNode):
         detail = (
             f" [{node.join_type}{'/' + node.distribution if node.distribution else ''}]"
