@@ -108,6 +108,21 @@ def test_rows_equal_the_partial_final_plans(cluster, sql, monkeypatch):
     assert sum(k["exchangedRows"] for k in cut) >= len(rows)
     assert ("distinct" in sql) == (
         sum(k["exchangedRows"] for k in cut) > len(rows))
+    # a page that crosses leaves the device once: the rows that hand rows
+    # on fetched each page whole, and no statement reads a column again to
+    # measure, partition or serialise it
+    for kernels in (taken, cut):
+        assert all(k["outputFetches"] for k in kernels if k["exchangedRows"])
+        sites = {s for k in kernels for s in k["hostSyncSites"]}
+        assert "output-fetch" in sites
+        assert not sites & {"serialize", "partition", "row-byte-estimate"}
+    # the same two counters as system.runtime.kernels serves them
+    _cols, table = client.execute(
+        "select sum(exchanged_rows), sum(output_fetches) "
+        "from system.runtime.kernels "
+        f"where query_id = '{client.query_id}'")
+    assert table == [[sum(k["exchangedRows"] for k in cut),
+                      sum(k["outputFetches"] for k in cut)]]
 
 
 # ------------------------------------------- (e) query_max_execution_time

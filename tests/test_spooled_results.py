@@ -1,4 +1,4 @@
-"""Spooled result protocol: segment store lifecycle, serde v3, the
+"""Spooled result protocol: segment store lifecycle, the serde's blocks, the
 worker-direct/coordinator spool paths, parallel client fetch, faults.
 
 Reference: Trino 455's spooled client protocol — result segments are
@@ -23,7 +23,7 @@ from trino_tpu.client.remote import SegmentFetchError, StatementClient
 from trino_tpu.data.dictionary import Dictionary
 from trino_tpu.data.page import Column, Page
 from trino_tpu.data.serde import (
-    CODEC_NONE, CODEC_ZLIB, MAGIC, deserialize_page, serialize_page)
+    CODEC_NONE, CODEC_ZLIB, MAGIC, VERSION, deserialize_page, serialize_page)
 from trino_tpu.obs import metrics as M
 from trino_tpu.server import wire
 from trino_tpu.server.segments import SegmentStore, parse_range
@@ -89,7 +89,7 @@ def test_serde_incompressible_column_stores_raw():
     # header: magic/version/codec/ncols/nrows, then block codec byte
     magic, version, codec, ncols, nrows = struct.unpack_from("<IBBHI",
                                                              blob, 0)
-    assert (magic, version, ncols) == (MAGIC, 3, 1)
+    assert (magic, version, ncols) == (MAGIC, VERSION, 1)
     block_codec, block_len = struct.unpack_from("<BI", blob, 12)
     assert block_codec == CODEC_NONE  # zlib did not shrink it -> raw
     _pages_equal(page, deserialize_page(blob))
@@ -105,10 +105,14 @@ def test_serde_incompressible_column_stores_raw():
 
 def test_serde_reads_legacy_v2_frames():
     """Spool files written by the previous (whole-body zlib) format must
-    still deserialize."""
+    still deserialize: at segment scale a frame of fixed-width columns, whose
+    blocks no later version changed, under one zlib pass; with a vocabulary,
+    the bytes an older process wrote (tests/legacy_frames.py)."""
+    from tests.legacy_frames import FRAMES, ROWS
     from trino_tpu.data.serde import _serialize_column
 
-    page = _segment_scale_page(5_000)
+    whole = _segment_scale_page(5_000)
+    page = Page([c for c in whole.columns if c.dictionary is None])
     parts = []
     for col in page.columns:
         _serialize_column(col, page.num_rows, parts)
@@ -116,6 +120,8 @@ def test_serde_reads_legacy_v2_frames():
     v2 = struct.pack("<IBBHI", MAGIC, 2, CODEC_ZLIB, page.channel_count,
                      page.num_rows) + body
     _pages_equal(page, deserialize_page(v2))
+    assert FRAMES["v2"][4] == 2
+    assert deserialize_page(FRAMES["v2"]).to_pylist() == ROWS
 
 
 # ---------------------------------------------------- segment store tier

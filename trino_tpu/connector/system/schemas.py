@@ -234,6 +234,10 @@ SYSTEM_TABLES = {
         # live rows a task handed to its output buffer, on the row of its
         # fragment's root operator: what crossed an exchange
         ("exchanged_rows", "bigint"),
+        # pages that task's output path fetched whole, every array of the
+        # page in one batched read (site output-fetch), on the same row:
+        # after it the path works on the host copy and reads nothing more
+        ("output_fetches", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

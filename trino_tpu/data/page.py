@@ -23,7 +23,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.data.dictionary import NULL_CODE, Dictionary
-from trino_tpu.obs.devprofiler import host_read
+from trino_tpu.obs.devprofiler import host_read, host_read_all
 
 
 @dataclasses.dataclass
@@ -205,6 +205,24 @@ class Column:
         if nulls is not None:
             out = [None if isnull else v for v, isnull in zip(out, nulls)]
         return out
+
+
+def _column_leaves(c: Column, out: list) -> None:
+    """The arrays of ``c``'s tree (values, nulls, hi, children) appended to
+    ``out``, absent ones as None, in the order ``_column_from_leaves``
+    takes them back."""
+    out.extend((c.values, c.nulls, c.hi))
+    for k in c.children or ():
+        _column_leaves(k, out)
+
+
+def _column_from_leaves(c: Column, leaves) -> Column:
+    """``c`` with its arrays replaced by the next ones of ``leaves``."""
+    values, nulls, hi = next(leaves), next(leaves), next(leaves)
+    kids = (None if c.children is None
+            else [_column_from_leaves(k, leaves) for k in c.children])
+    return dataclasses.replace(c, values=values, nulls=nulls, hi=hi,
+                               children=kids)
 
 
 def fits_int32(vrange) -> bool:
@@ -426,6 +444,45 @@ def host_take(c: Column, idx: np.ndarray, device: bool = True,
     )
 
 
+# a column of at least this many slots whose live rows are under an eighth
+# of them is gathered on the device before it is read (``to_pylist``)
+DEVICE_TAKE_MIN_ROWS = 1 << 16
+
+
+def _live_rows_to_host(columns: Sequence[Column], idx: np.ndarray,
+                       site: str) -> List[Column]:
+    """The rows ``idx`` (ascending) of ``columns`` as host columns. A large
+    flat device column with few live rows (a point lookup keeps one row of
+    four 1.5 M-row columns: 24 MB to read, 7.75 ms of a 35 ms statement) is
+    gathered ON the device at ``idx`` padded to a power of two (one gather
+    program a bucket, not one a result length), and all those gathers are
+    read in one batch; anything else goes through ``host_take`` as before."""
+    n = len(idx)
+    on_device = [
+        i for i, c in enumerate(columns)
+        if not c.type.is_nested and not isinstance(c.values, np.ndarray)
+        and len(c) >= DEVICE_TAKE_MIN_ROWS and 8 * n <= len(c)]
+    out = [None if i in on_device
+           else host_take(c, idx, device=False, site=site)
+           for i, c in enumerate(columns)]
+    if on_device:
+        padded = np.zeros(1 << max(n - 1, 0).bit_length(), idx.dtype)
+        padded[:n] = idx
+        take = jnp.asarray(padded)
+
+        def gather(x):
+            return None if x is None else jnp.take(x, take, mode="clip")
+
+        taken = Page([
+            dataclasses.replace(c, values=gather(c.values),
+                                nulls=gather(c.nulls), hi=gather(c.hi))
+            for c in (columns[i] for i in on_device)])
+        for i, c in zip(on_device,
+                        taken.to_host(site).slice_rows(0, n).columns):
+            out[i] = c
+    return out
+
+
 @dataclasses.dataclass
 class Page:
     """A batch of rows: one Column per channel + optional selection mask.
@@ -497,23 +554,47 @@ class Page:
 
         return Page([col_of(t, 1) for t in types], jnp.zeros((1,), bool))
 
-    def compact(self) -> "Page":
+    def to_host(self, site: str) -> "Page":
+        """This page with every array of its tree (``sel``, each column's
+        values, nulls, hi limb and nested children) as numpy, fetched in
+        ONE batched device -> host read counted once under ``site``
+        (``devprofiler.host_read_all``). A page that is already on the
+        host is returned as it is. What works on the copy (``compact``,
+        ``slice_rows``, the serde, ``host_take(..., device=False)``) moves
+        nothing in either direction."""
+        leaves = [self.sel]
+        for c in self.columns:
+            _column_leaves(c, leaves)
+        if all(x is None or isinstance(x, np.ndarray) for x in leaves):
+            return self
+        fetched = iter(host_read_all(leaves, site))
+        sel = next(fetched)
+        return dataclasses.replace(
+            self, sel=sel,
+            columns=[_column_from_leaves(c, fetched) for c in self.columns])
+
+    def compact(self, device: bool = True) -> "Page":
         """Drop dead rows (host-side gather). Used at wire boundaries: the
         serde (data/serde.py) carries no selection mask, so pages compact
         once before serialization — the DCN tier's analog of the reference
-        compacting pages into the PartitionedOutputBuffer."""
+        compacting pages into the PartitionedOutputBuffer.
+        ``device=False`` leaves the gathered columns on the host (the
+        output path, which compacts its one host copy: ``to_host``)."""
         if self.sel is None:
             return self
         live = host_read(self.sel, "compact")
         idx = np.nonzero(live)[0]
-        return Page([host_take(c, idx) for c in self.columns], None, self.replicated)
+        return Page([host_take(c, idx, device=device) for c in self.columns],
+                    None, self.replicated)
 
     def slice_rows(self, lo: int, hi: int) -> "Page":
         """Row-range view [lo, hi) of a compacted page (sel must be None) —
-        the producer-side page chunker of the streaming output path."""
+        the producer-side page chunker of the streaming output path. A
+        slice lives where its page does: of a host copy it is numpy views."""
         assert self.sel is None, "slice_rows requires a compacted page"
         cols = [
-            host_take(c, np.arange(lo, min(hi, len(c)), dtype=np.int64))
+            host_take(c, np.arange(lo, min(hi, len(c)), dtype=np.int64),
+                      device=not isinstance(c.values, np.ndarray))
             if c.type.is_nested
             else Column(
                 c.type,
@@ -530,21 +611,19 @@ class Page:
 
     def row_byte_estimate(self) -> int:
         """Rough serialized bytes per row (dtype widths; dictionaries are
-        amortized) — sizes output chunks. It reads each column to the
-        host to learn its dtype (the chip's transfer guard found it: whole
-        columns, 24 MB a point lookup): counted under ``row-byte-estimate``
-        so that the issue that takes the read away can size it."""
-        site = "row-byte-estimate"
+        amortized) — sizes output chunks. From the arrays' metadata: a
+        device array states its dtype without a read (until PR 35 each
+        column was fetched whole to learn it: 24 MB a point lookup)."""
         total = 0
         for c in self.columns:
-            total += host_read(c.values, site).dtype.itemsize
+            total += c.values.dtype.itemsize
             if c.nulls is not None:
                 total += 1
             if c.children is not None and self.num_rows:
                 # amortize flattened children over the parent row count
                 for k in c.children:
                     total += max(
-                        1, (len(k) * host_read(k.values, site).dtype.itemsize)
+                        1, (len(k) * k.values.dtype.itemsize)
                         // self.num_rows)
         return max(total, 1)
 
@@ -570,8 +649,8 @@ class Page:
         (measured ~0.7ms per point query on the serving path)."""
         if self.sel is not None:
             idx = np.nonzero(host_read(self.sel, "result-rows"))[0]
-            page = Page([host_take(c, idx, device=False, site="result-rows")
-                         for c in self.columns], None, self.replicated)
+            page = Page(_live_rows_to_host(self.columns, idx, "result-rows"),
+                        None, self.replicated)
         else:
             page = self
         cols = [c.to_python() for c in page.columns]

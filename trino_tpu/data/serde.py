@@ -9,6 +9,16 @@ to add one). Used by the DCN streaming shuffle tier, the spooled exchange,
 and the spooled result segments (SURVEY.md §2.6) — intra-slice repartition
 never serializes (it rides ICI inside the compiled program).
 
+**The codec follows the destination** (the reference compresses a pipelined
+exchange only when asked: ``exchange.compression-codec``, default ``NONE``).
+A frame that goes only into a task's ``OutputBuffer``, to be pulled by the
+next task, is written ``CODEC_NONE``: zlib level 1 runs at some 75 MB/s,
+slower than any link the frame could cross, and the consumer has to undo
+it (0.5 s out and 0.17 s in a q3 statement at SF 10). A frame that goes to
+disk (the FTE spool, a result segment) keeps ``CODEC_ZLIB``, block by
+block where zlib shrinks the block. The caller knows where its bytes go
+and passes the codec; no property chooses it.
+
 Version 3 compresses each COLUMN block independently and stores a block
 RAW when zlib does not shrink it (the reference's
 ``PageSerializer`` marker-byte contract: an incompressible block skips
@@ -16,8 +26,13 @@ the codec). Float/int entropy columns — exactly the shape of a big
 result export — previously paid compress+inflate both ways for nothing;
 now they pay neither, and the per-codec byte counters
 (``trino_tpu_serde_bytes_total{direction,codec}``) make the realized
-compression ratio observable. Version 2 payloads (whole-body zlib)
-still deserialize — spool files written by an older process stay
+compression ratio observable. Version 4 (written now) is version 3 with a
+varchar column's vocabulary as ONE block: the entries' byte lengths as one
+``u32`` array, then all of them as one UTF-8 run, written with one join and
+one encode and read with one decode and slices (a string at a time, 1.5 M
+customer names took 0.73 s to write and 0.46 s to read). Version 2
+payloads (whole-body zlib) and version 3 payloads (a length before each
+entry) still deserialize — spool files written by an older process stay
 readable.
 
 Format (little-endian):
@@ -29,7 +44,8 @@ Format (little-endian):
     has_nulls: u8; if 1: packed bitmap ceil(n/8) bytes
     dtype_code: u8 (PHYSICAL dtype — may be narrower than the logical type)
     values: n * itemsize bytes
-    if varchar: dict_len u32, then dict_len strings (u32 len + utf8)
+    if varchar: dict_len u32, dict_len x u32 byte lengths, then the
+      entries' utf8 end to end (versions 2, 3: dict_len x (u32 len + utf8))
 """
 from __future__ import annotations
 
@@ -46,6 +62,7 @@ from trino_tpu.data.page import Column, Page
 from trino_tpu.obs.devprofiler import host_read
 
 MAGIC = 0x7E51_00D5
+VERSION = 4  # written; 2 and 3 are still read
 CODEC_NONE = 0
 CODEC_ZLIB = 1
 
@@ -80,6 +97,44 @@ def _referenced_vocabulary(codes: np.ndarray, vocab):
     return out, [vocab[i] for i in used.tolist()]
 
 
+def _serialize_vocabulary(vocab, parts: List[bytes]) -> None:
+    """``dict_len u32``, the entries' byte lengths as one ``u32`` array,
+    then the entries end to end as one UTF-8 run: one join and one encode
+    whatever the count."""
+    text = "".join(vocab)
+    blob = text.encode()
+    if len(blob) == len(text):  # ASCII: a string's length is its bytes'
+        lengths = np.fromiter(map(len, vocab), np.uint32, len(vocab))
+    else:
+        lengths = np.fromiter((len(s.encode()) for s in vocab), np.uint32,
+                              len(vocab))
+    parts.append(struct.pack("<I", len(vocab)))
+    parts.append(lengths.astype("<u4", copy=False).tobytes())
+    parts.append(blob)
+
+
+def _deserialize_vocabulary(body: bytes, off: int, dlen: int):
+    """(entries, end offset) of a version 4 vocabulary block at ``off``,
+    just after its ``dict_len``: one decode, then slices at the entries'
+    CHARACTER offsets (the byte offsets, where the run is ASCII; else the
+    count of UTF-8 lead bytes before each)."""
+    if not dlen:
+        return [], off
+    lengths = np.frombuffer(body, dtype="<u4", count=dlen, offset=off)
+    off += 4 * dlen
+    ends = np.cumsum(lengths, dtype=np.int64)
+    total = int(ends[-1])
+    text = body[off:off + total].decode()
+    if len(text) != total:
+        raw = np.frombuffer(body, dtype=np.uint8, count=total, offset=off)
+        chars = np.concatenate(
+            [np.zeros(1, np.int64), np.cumsum((raw & 0xC0) != 0x80)])
+        ends = chars[ends]
+    ends = ends.tolist()
+    return ([text[a:b] for a, b in zip([0] + ends[:-1], ends)],
+            off + total)
+
+
 def _serialize_column(col: Column, n: int, parts: List[bytes]) -> None:
     name = str(col.type).encode()
     parts.append(struct.pack("<H", len(name)))
@@ -108,11 +163,7 @@ def _serialize_column(col: Column, n: int, parts: List[bytes]) -> None:
         parts.append(struct.pack("<B", dtype_code))
         parts.append(vals_np.tobytes())
     if vocab is not None:
-        parts.append(struct.pack("<I", len(vocab)))
-        for s in vocab:
-            b = s.encode()
-            parts.append(struct.pack("<I", len(b)))
-            parts.append(b)
+        _serialize_vocabulary(vocab, parts)
     if col.type.is_nested:
         # children: u32 flat row count, then the child column recursively
         # (reference: ArrayBlockEncoding/MapBlockEncoding nest the element
@@ -127,7 +178,7 @@ def serialize_page(page: Page, codec: int = CODEC_ZLIB) -> bytes:
 
     n = page.num_rows
     out: List[bytes] = [
-        struct.pack("<IBBHI", MAGIC, 3, codec, page.channel_count, n)]
+        struct.pack("<IBBHI", MAGIC, VERSION, codec, page.channel_count, n)]
     logical = 0
     wire_by_codec = {CODEC_NONE: 0, CODEC_ZLIB: 0}
     for col in page.columns:
@@ -170,12 +221,13 @@ def deserialize_page(data: bytes) -> Page:
             body = zlib.decompress(body)
         off = 0
         for _ in range(ncols):
-            col, off = _deserialize_column(body, off, nrows)
+            col, off = _deserialize_column(body, off, nrows, version)
             columns.append(col)
         return Page(columns)
-    if version != 3:
+    if version not in (3, VERSION):
         raise ValueError(
-            f"unsupported page format version {version} (expected 2 or 3)")
+            f"unsupported page format version {version} "
+            f"(expected 2, 3 or {VERSION})")
     off = 12
     logical = 0
     wire_by_codec = {CODEC_NONE: 0, CODEC_ZLIB: 0}
@@ -191,7 +243,7 @@ def deserialize_page(data: bytes) -> Page:
         elif block_codec != CODEC_NONE:
             raise ValueError(f"unknown column block codec {block_codec}")
         logical += len(block)
-        col, _end = _deserialize_column(block, 0, nrows)
+        col, _end = _deserialize_column(block, 0, nrows, version)
         columns.append(col)
     for bc, nbytes in wire_by_codec.items():
         if nbytes:
@@ -201,7 +253,7 @@ def deserialize_page(data: bytes) -> Page:
     return Page(columns)
 
 
-def _deserialize_column(body: bytes, off: int, nrows: int):
+def _deserialize_column(body: bytes, off: int, nrows: int, version: int):
     (name_len,) = struct.unpack_from("<H", body, off)
     off += 2
     typ = T.parse_type(body[off : off + name_len].decode())
@@ -230,12 +282,15 @@ def _deserialize_column(body: bytes, off: int, nrows: int):
     if typ.is_varchar:
         (dlen,) = struct.unpack_from("<I", body, off)
         off += 4
-        vocab = []
-        for _ in range(dlen):
-            (slen,) = struct.unpack_from("<I", body, off)
-            off += 4
-            vocab.append(body[off : off + slen].decode())
-            off += slen
+        if version >= 4:
+            vocab, off = _deserialize_vocabulary(body, off, dlen)
+        else:  # a length before each entry
+            vocab = []
+            for _ in range(dlen):
+                (slen,) = struct.unpack_from("<I", body, off)
+                off += 4
+                vocab.append(body[off : off + slen].decode())
+                off += slen
         dictionary = Dictionary(vocab)
     children = None
     if typ.is_nested:
@@ -243,7 +298,7 @@ def _deserialize_column(body: bytes, off: int, nrows: int):
         for _ in T.type_children(typ):
             (crows,) = struct.unpack_from("<I", body, off)
             off += 4
-            child, off = _deserialize_column(body, off, crows)
+            child, off = _deserialize_column(body, off, crows, version)
             children.append(child)
     return (
         Column(

@@ -52,7 +52,11 @@ finished inside the source fragment that scans its table
 (sql/planner/fragmenter.py ``_colocated_aggregation``: no partial/final
 cut, no exchange under it). The row of a fragment's ROOT operator counts
 ``exchangedRows``: the live rows its task handed to its output buffer
-(server/task.py ``SqlTask._output_path``), what crosses an exchange. A
+(server/task.py ``SqlTask._output_path``), what crosses an exchange, and
+``outputFetches``: the pages that path fetched whole, every leaf of the
+page's tree in ONE batched read (:func:`host_read_all`, site
+``output-fetch``), after which it works on the host copy and never goes
+back to the device. A
 scan's row counts what the device
 cache did for it (devcache/keys.py ``cached_stage``): ``cacheHits`` /
 ``cacheMisses``
@@ -111,7 +115,7 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "aggPrograms": 0, "aggEager": 0,
            "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0,
            "prefixCompactions": 0, "compactedJoins": 0,
-           "colocatedAggs": 0, "exchangedRows": 0}
+           "colocatedAggs": 0, "exchangedRows": 0, "outputFetches": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -148,7 +152,7 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "d2hBytes", "compiles", "aggPrograms", "aggEager",
                       "cacheHits", "cacheMisses", "stagedBytes",
                       "prefixCompactions", "compactedJoins",
-                      "colocatedAggs", "exchangedRows"):
+                      "colocatedAggs", "exchangedRows", "outputFetches"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))
@@ -213,22 +217,47 @@ def host_read(x, site: str):
         return np.asarray(x)
     import jax  # lint: allow(jnp-in-host-module) the served path's one device->host read lives with the ledger that counts it; imported on first use, never at module import
 
-    from trino_tpu.obs import trace as tracing
-
     if not isinstance(x, jax.Array):
         return np.asarray(x)
     if isinstance(x, jax.core.Tracer):
         return x  # under a trace there is nothing to read: the caller's
         # conversion raises jax's own concretization error
+    return host_read_all([x], site)[0]
+
+
+def host_read_all(arrays, site: str) -> list:
+    """``arrays`` with the device arrays among them as numpy, fetched in ONE
+    batched read: every copy is started (``copy_to_host_async``) before the
+    first is taken, and the batch is guarded, timed, counted and stored as
+    :func:`host_read` says, as ONE ``hostSyncs`` with the summed
+    ``d2hBytes`` under ``site``. Whatever else the list holds (numpy
+    arrays, None) stays as it is; a list with no device array in it counts
+    nothing."""
+    import numpy as np
+
+    import jax  # lint: allow(jnp-in-host-module) as host_read, whose read this is
+
+    from trino_tpu.obs import trace as tracing
+
+    out = list(arrays)
+    on_device = [i for i, x in enumerate(out)
+                 if isinstance(x, jax.Array)
+                 and not isinstance(x, jax.core.Tracer)]
+    if not on_device:
+        return out
     ann = tracing.annotation("host/sync")
     start = time.time()
     t0 = time.perf_counter()
     with jax.transfer_guard_device_to_host("allow"):
-        out = np.asarray(x)
+        if len(on_device) > 1:
+            for i in on_device:
+                out[i].copy_to_host_async()
+        for i in on_device:
+            out[i] = np.asarray(out[i])
     seconds = time.perf_counter() - t0
     if ann is not None:
         ann.__exit__(None, None, None)
-    nbytes = int(out.nbytes)
+    nbytes = sum(int(out[i].nbytes) for i in on_device)
     row = _CHARGED.get()
     if row is not None:
         row["hostSyncs"] += 1

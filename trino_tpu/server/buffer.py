@@ -162,6 +162,13 @@ class OutputBuffer:
         with self._lock:
             return sum(len(p) for p in self._pages)
 
+    @property
+    def full(self) -> bool:
+        """At or over the watermark: the next ``enqueue`` parks until a
+        consumer acknowledges pages away."""
+        with self._lock:
+            return self._bytes >= self._max_bytes
+
 
 class PartitionedOutputBuffer:
     """Per-partition DISTINCT page streams: buffer id p serves partition p
@@ -215,6 +222,11 @@ class PartitionedOutputBuffer:
     @property
     def buffered_bytes(self) -> int:
         return sum(p.buffered_bytes for p in self._parts)
+
+    @property
+    def full(self) -> bool:
+        """Some partition is at its watermark: the producer parks on it."""
+        return any(p.full for p in self._parts)
 
     @property
     def stalled_seconds(self) -> float:

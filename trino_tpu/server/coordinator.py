@@ -2374,7 +2374,11 @@ class QueryExecution:
         """Block until every task of the given (already-scheduled) build
         fragments reports FLUSHING or later — its body is done and its
         output is buffered/spooled, so probe tasks can start pulling
-        immediately (reference: PhasedExecutionSchedule's stage phases)."""
+        immediately (reference: PhasedExecutionSchedule's stage phases) —
+        or reports its output buffer FULL: a split-at-a-time build whose
+        frames pass the watermark parks in ``enqueue`` while still
+        RUNNING, and only the fragment this wait holds back can drain it
+        (customer's 45 MB of raw frames in Q18 at SF 10)."""
         deadline = time.monotonic() + self.PHASE_WAIT_TIMEOUT
         for fid in dep_ids:
             for loc in self.fragment_tasks.get(fid, ()):
@@ -2388,8 +2392,9 @@ class QueryExecution:
                             info = json.loads(body)
                             self._note_task_status(loc.task_id, info)
                             state = info.get("state")
-                            if state in ("FLUSHING", "FINISHED", "FAILED",
-                                         "CANCELED"):
+                            if info.get("outputFull") or state in (
+                                    "FLUSHING", "FINISHED", "FAILED",
+                                    "CANCELED"):
                                 break
                     except Exception:  # noqa: BLE001 — retry until deadline
                         pass

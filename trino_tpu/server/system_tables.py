@@ -322,6 +322,7 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("compactedJoins", 0)),
             int(r.get("colocatedAggs", 0)),
             int(r.get("exchangedRows", 0)),
+            int(r.get("outputFetches", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

@@ -22,7 +22,7 @@ import traceback
 from typing import Dict, List, Optional
 
 from trino_tpu.data.page import Page
-from trino_tpu.data.serde import serialize_page
+from trino_tpu.data.serde import CODEC_NONE, CODEC_ZLIB, serialize_page
 from trino_tpu.exec.executor import Executor, operator_kind, page_platform
 from trino_tpu.exec.operator_stats import OperatorStats
 from trino_tpu.obs import metrics as M
@@ -293,33 +293,51 @@ class SqlTask:
                 1 for d in ex.scan_cache.values() if d == "miss")
 
     @contextlib.contextmanager
-    def _charge_root(self, page: Page):
+    def _charge_root(self, page: Page, host_copy: bool = False):
         """Charge this thread's device->host reads to the kernel row of
         the fragment's root node, which produced ``page``: work the task
         does on a page once the executor that made it has been retired
-        (the output path, the streaming fold's state pages)."""
+        (the output path, the streaming fold's state pages). The output
+        path's own ``host_copy`` of a page says nothing of where the
+        root's launches left it."""
         root = self.request.fragment_root
         row = new_kernel_row(str(root.id), operator_kind(root), "eager")
-        row["platform"] = page_platform(page)
+        row["platform"] = "" if host_copy else page_platform(page)
         try:
             with charge_to(row):
                 yield
         finally:
             if (row["hostSyncs"] or row["compiles"] or row["aggPrograms"]
-                    or row["aggEager"] or row["exchangedRows"]):
+                    or row["aggEager"] or row["exchangedRows"]
+                    or row["outputFetches"]):
                 with self._stats_lock:
                     merge_kernel_rows(self.kernel_stats, [row])
 
     @contextlib.contextmanager
-    def _output_path(self, page: Page):
+    def _output_path(self, page: Page, host_copy: bool = False):
         """The worker's output path after the fragment body, as one
-        ``task/output`` span: compact, partition, chunk, serialise,
+        ``task/output`` span: fetch, compact, partition, chunk, serialise,
         enqueue (a wait at the buffer's watermark included) or segment
         write. The coordinator, or the consuming task, spends this time
         waiting. The live rows handed to the output buffer inside it are
-        the root operator's ``exchangedRows``."""
-        with tracing.span("task/output"), self._charge_root(page):
+        the root operator's ``exchangedRows``, the pages it fetched its
+        ``outputFetches``."""
+        with tracing.span("task/output"), self._charge_root(page, host_copy):
             yield
+
+    @staticmethod
+    def _host_compacted(page: Page) -> Page:
+        """The output path's ONE host copy of ``page``, dead rows dropped:
+        the page's whole tree leaves the device in one batched read (site
+        ``output-fetch``, counted on the root's row as ``outputFetches``),
+        and everything after it (the gather that compacts, the partition
+        ids, the per-partition gathers, the chunk slices, the serde) is
+        numpy on that copy; nothing goes back up and nothing is read
+        twice. A page that is already such a copy is returned as it is."""
+        host = page.to_host("output-fetch")
+        if host is not page:
+            count_charged("outputFetches")
+        return host.compact(device=False)
 
     def stats_snapshot(self) -> dict:
         """Point-in-time task stats for ``GET /v1/task/{id}/status`` —
@@ -408,6 +426,10 @@ class SqlTask:
             self.state.set("FAILED")
         finally:
             self.ended_at = time.monotonic()
+            # a terminal task stays in the manager's history (up to
+            # MAX_TASK_HISTORY): it must not keep its last executor, and
+            # with it the pulled pages on the device, alive that long
+            self._live_executor = None
             self._observe_operator_metrics()
             if self.peak_memory_bytes:
                 from trino_tpu.obs.memledger import (MEMORY_LEDGER,
@@ -490,12 +512,13 @@ class SqlTask:
         self.state.set("FINISHED")
 
     def _write_output(self, page: Page) -> None:
-        """The bulk body's output path: compact, then by the task's shape
-        partition, spool or stream the chunks into the output buffer."""
+        """The bulk body's output path: one host copy, compacted, then by
+        the task's shape partition, spool or stream the chunks into the
+        output buffer."""
         from trino_tpu.exec.memory import page_bytes
 
         req = self.request
-        page = page.compact()
+        page = self._host_compacted(page)
         self.flushing_bytes = page_bytes(page)  # held through the drain
         with self._stats_lock:
             self.output_rows += page.num_rows
@@ -507,20 +530,23 @@ class SqlTask:
             # hash-partitioned shuffle producer: split the output by
             # key hash (same splitmix64 combine as the device exchange,
             # so every producer places a key identically) and enqueue
-            # each partition into its consumer's stream. Under FTE the
-            # per-partition streams spool FIRST (durability before
-            # visibility — retried consumers re-read partition files).
+            # each partition into its consumer's stream, a frame as soon
+            # as it is encoded. Under FTE the per-partition streams spool
+            # FIRST (durability before visibility — retried consumers
+            # re-read partition files).
             parts = self._partition_pages(page)
-            part_frames = [
-                [serialize_page(c)
-                 for c in _chunk_pages(part.compact(), chunk_rows)]
-                for part in parts
-            ]
             if spool_directory():
+                part_frames = [
+                    [serialize_page(c, CODEC_ZLIB)
+                     for c in _chunk_pages(part, chunk_rows)]
+                    for part in parts
+                ]
                 self._spool_partitioned(part_frames)
-            for pid, frames in enumerate(part_frames):
-                for pb in frames:
-                    self.output.enqueue_partition(pid, pb)
+                for pid, frames in enumerate(part_frames):
+                    for pb in frames:
+                        self.output.enqueue_partition(pid, pb)
+            else:
+                self._enqueue_partitions(parts, chunk_rows)
             self.output.set_complete()
             return
         if self._result_writer is not None:
@@ -530,7 +556,7 @@ class SqlTask:
             # parks on a consumer that, by design, is not coming
             with tracing.span("segment/write") as sp:
                 for c in _chunk_pages(page, chunk_rows):
-                    self._result_writer.add(serialize_page(c),
+                    self._result_writer.add(serialize_page(c, CODEC_ZLIB),
                                             int(c.num_rows))
                 self._finish_result_spool()
                 sp.set("segments", len(self.result_segments))
@@ -547,15 +573,25 @@ class SqlTask:
         # exchanges do.
         if spool_directory():
             page_frames = [
-                serialize_page(c) for c in _chunk_pages(page, chunk_rows)
+                serialize_page(c, CODEC_ZLIB)
+                for c in _chunk_pages(page, chunk_rows)
             ]
             self._spool(page_frames)
             for pb in page_frames:
                 self.output.enqueue(pb)
         else:
             for c in _chunk_pages(page, chunk_rows):
-                self.output.enqueue(serialize_page(c))  # blocks at watermark
+                # blocks at watermark
+                self.output.enqueue(serialize_page(c, CODEC_NONE))
         self.output.set_complete()
+
+    def _enqueue_partitions(self, parts: List[Page], chunk_rows: int) -> None:
+        """Each partition's chunks into its consumer's stream, raw (the
+        pipelined pull), each frame enqueued as soon as it is encoded."""
+        for pid, part in enumerate(parts):
+            for c in _chunk_pages(part, chunk_rows):
+                self.output.enqueue_partition(
+                    pid, serialize_page(c, CODEC_NONE))
 
     # ------------------------------------------------------- streaming loop
     @staticmethod
@@ -614,12 +650,14 @@ class SqlTask:
         return self._streamable_leaf(root, P.TableScanNode)
 
     def _partition_pages(self, page: Page) -> List[Page]:
-        """Hash-partition one output page into consumer_count per-partition
-        pages, applying the adaptive skew salting when the re-planner
+        """Hash-partition one output page (the output path's compacted
+        host copy) into consumer_count per-partition pages, which stay on
+        the host, applying the adaptive skew salting when the re-planner
         annotated this producer: hot partitions spread round-robin (probe
         side) or replicate into every partition (build side) — the
-        producer half of the salted repartition join."""
-        from trino_tpu.exec.memory import partition_page_host
+        producer half of the salted repartition join. A partition with no
+        row is a page of no rows (``_chunk_pages`` yields nothing)."""
+        from trino_tpu.data.page import host_take
 
         import numpy as np
 
@@ -644,33 +682,26 @@ class SqlTask:
             pids, self._spread_cursor = spread_partition_ids(
                 pids, spread, req.consumer_count,
                 start=getattr(self, "_spread_cursor", 0))
-        parts = partition_page_host(
-            page, req.output_partition_channels, req.consumer_count,
-            pid=pids)
-        replicate = getattr(req, "skew_replicate_partitions", None)
-        if replicate:
-            hot = {h: parts[h] for h in replicate if 0 <= h < len(parts)}
-            out = []
-            for q, part in enumerate(parts):
-                for h, hp in hot.items():
-                    if h != q and hp.live_count() > 0:
-                        part = Page.concat_pages(part, hp)
-                out.append(part)
-            parts = out
-        # detection accounting straight off the (post-spread) pid array:
-        # one bincount, and replicated hot-partition copies no longer
+        assert page.sel is None, "_partition_pages takes a compacted page"
+        pids = np.asarray(pids)
+        rows = [np.nonzero(pids == p)[0] for p in range(req.consumer_count)]
+        # detection accounting straight off the (post-spread) pid array,
+        # before replication: replicated hot-partition copies do not
         # inflate the skew signal the re-planner reads
-        n = page.num_rows
-        live = (np.ones(n, bool) if page.sel is None
-                else host_read(page.sel, "partition").astype(bool))
-        counts = np.bincount(np.asarray(pids)[live],
-                             minlength=req.consumer_count)
         with self._stats_lock:
             if self.partition_rows is None:
                 self.partition_rows = [0] * req.consumer_count
-            for pid in range(req.consumer_count):
-                self.partition_rows[pid] += int(counts[pid])
-        return parts
+            for pid, idx in enumerate(rows):
+                self.partition_rows[pid] += len(idx)
+        replicate = getattr(req, "skew_replicate_partitions", None)
+        if replicate:
+            # a hot partition's rows follow every other partition's own
+            hot = [h for h in dict.fromkeys(replicate) if 0 <= h < len(rows)]
+            rows = [np.concatenate([own] + [rows[h] for h in hot if h != q])
+                    for q, own in enumerate(rows)]
+        return [Page([host_take(c, idx, device=False, site="partition")
+                      for c in page.columns], None, page.replicated)
+                for idx in rows]
 
     def _finish_result_spool(self) -> None:
         """Seal the result-segment writer: roll the last partial segment
@@ -691,15 +722,16 @@ class SqlTask:
         """Partition-aware enqueue of one output page (shared by the
         streaming paths: per-batch chains, per-split scans, and the fold
         path's finalization)."""
-        with self._output_path(out):
+        with self._output_path(out, host_copy=True):
             self._enqueue_chunks(out, part_channels)
 
     def _enqueue_chunks(self, out: Page, part_channels) -> None:
-        if out.num_rows == 0 or out.live_count() == 0:
+        out = self._host_compacted(out)
+        if out.num_rows == 0:
             return
         from trino_tpu.exec.memory import page_bytes
 
-        live = int(out.live_count())
+        live = int(out.num_rows)
         with self._stats_lock:
             self.output_rows += live
             self.output_bytes += page_bytes(out)
@@ -711,17 +743,15 @@ class SqlTask:
             # stream loop never blocks on an output-buffer watermark
             with tracing.span("segment/write") as sp:
                 for c in _chunk_pages(out, chunk_rows):
-                    self._result_writer.add(serialize_page(c),
+                    self._result_writer.add(serialize_page(c, CODEC_ZLIB),
                                             int(c.num_rows))
-                sp.set("rows", int(out.live_count()))
+                sp.set("rows", live)
             return
         if part_channels is not None:
-            for pid, part in enumerate(self._partition_pages(out)):
-                for c in _chunk_pages(part.compact(), chunk_rows):
-                    self.output.enqueue_partition(pid, serialize_page(c))
+            self._enqueue_partitions(self._partition_pages(out), chunk_rows)
         else:
             for c in _chunk_pages(out, chunk_rows):
-                self.output.enqueue(serialize_page(c))
+                self.output.enqueue(serialize_page(c, CODEC_NONE))
 
     def _try_split_streaming(self, req: TaskRequest, session) -> bool:
         """Execute a scan-rooted streamable fragment ONE SPLIT AT A TIME,
@@ -748,7 +778,7 @@ class SqlTask:
                 t0 = time.perf_counter()
                 page = ex.execute_checked(req.fragment_root)
                 with self._output_path(page):
-                    out = page.compact()
+                    out = self._host_compacted(page)
                 split_s = time.perf_counter() - t0
                 device_s += split_s
                 staged_rows += sum(ex.scan_stats.values())
@@ -813,7 +843,7 @@ class SqlTask:
             t0 = time.perf_counter()
             page = ex.execute_checked(req.fragment_root)
             with self._output_path(page):
-                out = page.compact()
+                out = self._host_compacted(page)
             batch_s = time.perf_counter() - t0
             device_clock[0] += batch_s
             self._retire_executor(ex, input_rows=batch_rows, device_s=batch_s)
@@ -892,7 +922,7 @@ class SqlTask:
                     final = ex.aggregate_final(node, running)
                     ex.raise_errors()
                 with self._output_path(final):
-                    out = final.compact()
+                    out = self._host_compacted(final)
                 final_s = time.perf_counter() - t0
                 device_clock[0] += final_s
                 record_agg_stats(ex, final_s, int(running.num_rows), out,
@@ -984,6 +1014,9 @@ class SqlTask:
             "state": self.state.get(),
             "failure": self.failure,
             "bufferedBytes": self.output.buffered_bytes,
+            # at its watermark: the task goes no further until a consumer
+            # pulls (the phased scheduler stops waiting for such a build)
+            "outputFull": self.output.full,
             "memoryBytes": self.memory_bytes,
             # spooled result protocol: the segments this task wrote (the
             # coordinator assembles the statement manifest from these)
