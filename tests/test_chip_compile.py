@@ -212,3 +212,49 @@ def test_spmd_hash_partitioned_q3_compiles_with_all_to_all(topo):
         assert "all-to-all" in dq.fn.lower(shapes).compile().as_text()
     finally:
         stats.GATHER_AGG_MAX_ROWS_PER_DEVICE, stats.BROADCAST_BUILD_MAX = saved
+
+
+@pytest.mark.parametrize("what", ["dense probe", "composite keys",
+                                  "key range"])
+def test_q9s_joins_compile_at_sf10_shapes(one_chip, what):
+    """TPC-H Q9 at SF 10 as PR 36 plans it. Its first join probes 60 x 2^20
+    lineitem slots against the 42.8 K green parts through a direct-address
+    table over ``p_partkey``'s 2 M-key range (the range measured from the
+    build, which came through an exchange); its partsupp join is a fused
+    sort-merge on two key columns over the Compact's 2^21 slots and a
+    2^18-slot build; a build's key range is three reductions over the
+    column (orders' 15 x 2^20 slots the largest)."""
+    from trino_tpu.ops import fused_join, join as join_ops
+
+    def arr(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    if what == "dense probe":
+        def probe(build_key, build_sel, probe_key):
+            table = join_ops.dense_unique_table(
+                (build_key, None), build_sel, 1, 2_000_000)
+            return join_ops.dense_probe_unique(table, (probe_key, None), 1)
+
+        compiled = jax.jit(probe).lower(
+            arr(65_536, jnp.int64), arr(65_536, jnp.bool_),
+            arr(62_914_560, jnp.int32)).compile()
+        assert " sort(" not in compiled.as_text()
+    elif what == "composite keys":
+        def probe(b0, b1, bsel, p0, p1):
+            return fused_join.fused_probe_unique(
+                [(b0, None), (b1, None)], bsel, [(p0, None), (p1, None)])
+
+        compiled = jax.jit(probe).lower(
+            arr(262_144, jnp.int64), arr(262_144, jnp.int64),
+            arr(262_144, jnp.bool_),
+            arr(2_097_152, jnp.int64), arr(2_097_152, jnp.int64)).compile()
+    else:
+        def measure(vals, live):
+            info = jnp.iinfo(vals.dtype)
+            return (jnp.sum(live.astype(jnp.int32)),
+                    jnp.where(live, vals, info.max).min(),
+                    jnp.where(live, vals, info.min).max())
+
+        compiled = jax.jit(measure).lower(
+            arr(15_728_640, jnp.int64), arr(15_728_640, jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -7,6 +7,7 @@ benchmark's other statements left byte for byte as the parent planned them,
 served Q18 equal to both oracles, and ``query_max_execution_time``."""
 import json
 import os
+import re
 import sqlite3
 
 import numpy as np
@@ -86,12 +87,27 @@ with open(os.path.join(HERE, "parent_plans.json"), encoding="utf-8") as _f:
     PARENT_PLANS = json.load(_f)
 
 
+# the cases a later PR planned otherwise and argued (PERF.md section 6, PR 36:
+# q3 at SF 1, customer's filtered rows against the broadcast limit): that
+# PR's text, held by tests/test_q9_deployment.py; the parent's file stays
+with open(os.path.join(HERE, "q9_argued_plans.json"), encoding="utf-8") as _f:
+    ARGUED_PLANS = json.load(_f)
+
+
+def without_estimates(text):
+    """EXPLAIN prints each join's estimated probe and build rows since
+    PR 36; the plans pinned before it are compared without them."""
+    return re.sub(r" est=\[probe \d+, build \d+\]", "", text)
+
+
 @pytest.mark.parametrize("case", sorted(PARENT_PLANS))
 def test_the_other_cells_statements_plan_as_the_parent_planned_them(case):
     """q1, q3, q6 and the point lookup at tiny, SF 1 and SF 10: the
     distributed plan's text is the parent's (``parent_plans.json``: EXPLAIN
     (TYPE DISTRIBUTED) of the benchmark's templates, taken from 8d84bdd
-    before this change), byte for byte."""
+    before this change), byte for byte once the estimates EXPLAIN has
+    printed on a join since PR 36 are taken out; a case in ``ARGUED_PLANS``
+    reads the text argued for it instead."""
     from benchmark import spec
 
     schema, name, binding = case.split("/", 2)
@@ -101,7 +117,8 @@ def test_the_other_cells_statements_plan_as_the_parent_planned_them(case):
            else t.sql.format(**binding))
     s = Session({"catalog": "tpch", "schema": schema})
     rows = run_query(s, "EXPLAIN (TYPE DISTRIBUTED) " + sql).rows
-    assert "\n".join(r[0] for r in rows) == PARENT_PLANS[case]
+    assert without_estimates("\n".join(r[0] for r in rows)) == \
+        ARGUED_PLANS.get(case, PARENT_PLANS[case])
 
 
 # ---------------------------------- (b) the semi-join rule, against sqlite

@@ -174,6 +174,8 @@ def test_an_unset_or_unreached_limit_changes_nothing(cluster, limit):
     assert q.state.get() == "FINISHED"
     assert (q._time_limit_timer is None) == (limit is None)
     if limit is not None:
+        # run() cancels the timer as it returns, after FINISHED is visible
+        q._time_limit_timer.join(timeout=5.0)
         assert not q._time_limit_timer.is_alive()        # disarmed
 
 

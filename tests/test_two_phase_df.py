@@ -91,6 +91,40 @@ def test_weak_domains_enforce_on_device(monkeypatch):
     assert got == run_query(Session(), Q3).rows
 
 
+def test_traced_tier_collects_and_applies_its_domains(monkeypatch):
+    """The CompiledQuery tier's own executor registers each build's keys
+    (``traced_domains``) and its probe scans mask against them: results
+    alone would not show the collection switched off."""
+    import jax.numpy as jnp
+
+    from trino_tpu.exec import compiled as C
+    from trino_tpu.exec.page_tree import unflatten_page
+
+    monkeypatch.setattr(C, "HOST_APPLY_MAX_SEL", 0.0)
+    cq = _build(Q3)
+    assert cq._device_df
+    pages, i = {}, 0
+    for nid, count in cq._layout:
+        pages[nid] = unflatten_page(
+            cq.input_specs[nid], cq.input_arrays[i:i + count])
+        i += count
+    ex = C.PreloadedExecutor(
+        cq.session, pages, dict(cq.capacity_hints), cq._device_df)
+    ex.execute(cq.root)  # the body the jit traces, run on concrete arrays
+    assert ex.traced_domains
+    assert {(j, k) for es in cq._device_df.values() for _, j, k, _ in es} \
+        <= set(ex.traced_domains)
+    scans = {n.id: n for n in P.walk_plan(cq.root)
+             if isinstance(n, P.TableScanNode)}
+    for nid in cq._device_df:
+        staged = pages[nid]
+        before = (staged.num_rows if staged.sel is None
+                  else int(jnp.sum(staged.sel)))
+        narrowed = ex._exec_TableScanNode(scans[nid])
+        assert narrowed.sel is not None
+        assert int(jnp.sum(narrowed.sel)) < before, scans[nid].table
+
+
 def test_q18_having_subquery_collapses_probe():
     cq = _build(Q18)
     rows = _scan_rows_by_table(cq.session, cq)

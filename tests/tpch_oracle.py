@@ -308,7 +308,7 @@ def q8(schema="tiny"):
     return [(y, _divq(num[y], den[y], 4)) for y in sorted(den)]
 
 
-def q9(schema="tiny"):
+def q9(schema="tiny", color="green"):
     part = load_table(schema, "part", ["p_partkey", "p_name"])
     supp = load_table(schema, "supplier", ["s_suppkey", "s_nationkey"])
     ps = load_table(schema, "partsupp", ["ps_partkey", "ps_suppkey", "ps_supplycost"])
@@ -316,7 +316,7 @@ def q9(schema="tiny"):
     orders = load_table(schema, "orders", ["o_orderkey", "o_orderdate"])
     nation = load_table(schema, "nation", ["n_nationkey", "n_name"])
     nname = {n["n_nationkey"]: n["n_name"] for n in nation}
-    green = {p["p_partkey"] for p in part if _like(p["p_name"], "%green%")}
+    green = {p["p_partkey"] for p in part if _like(p["p_name"], f"%{color}%")}
     snat = {s["s_suppkey"]: nname[s["s_nationkey"]] for s in supp}
     cost = {(r["ps_partkey"], r["ps_suppkey"]): r["ps_supplycost"] for r in ps}
     odate = {o["o_orderkey"]: o["o_orderdate"] for o in orders}

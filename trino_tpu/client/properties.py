@@ -184,8 +184,10 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "device_cache_max_bytes",
             "per-staging admission cap against the device table cache: "
             "entries above min(this, the server-wide budget) are staged "
-            "but not retained (the shared budget itself is fixed at "
-            "process scope — one session cannot resize it)",
+            "but not retained, and counted (cacheBypasses on the scan's "
+            "kernel row, trino_tpu_device_cache_bypass_total) (the shared "
+            "budget itself is fixed at process scope — one session cannot "
+            "resize it)",
             int, 1 << 30, _positive,
         ),
         PropertyMetadata(

@@ -43,12 +43,18 @@ class TableMetadata:
 class ColumnStats:
     """CBO column statistics (reference: spi/statistics/ColumnStatistics).
     ``low``/``high`` are storage-repr bounds (scaled ints for decimals,
-    epoch days for dates); ``ndv`` estimates distinct values."""
+    epoch days for dates); ``ndv`` estimates distinct values.
+    ``vocabulary`` is every string a dictionary-coded column can hold, for
+    a connector that knows it and whose codes are spread about evenly over
+    it: the planner evaluates a predicate over that one column on the
+    vocabulary and takes matching / total as its selectivity
+    (sql/planner/stats.py dictionary_selectivity)."""
 
     low: Optional[int] = None
     high: Optional[int] = None
     ndv: Optional[int] = None
     null_fraction: float = 0.0
+    vocabulary: Optional[tuple] = None
 
     @property
     def vrange(self) -> Optional[tuple]:

@@ -238,6 +238,14 @@ SYSTEM_TABLES = {
         # page in one batched read (site output-fetch), on the same row:
         # after it the path works on the host copy and reads nothing more
         ("output_fetches", "bigint"),
+        # row capacities of the probe and build pages of every execution
+        # of a join, on the Join's row: what its place in the join order
+        # makes it carry (static shapes, nothing read)
+        ("join_probe_slots", "bigint"),
+        ("join_build_slots", "bigint"),
+        # scans the device cache was on for and did not keep (over the
+        # admission cap, or no key could be made), on the scan's row
+        ("cache_bypasses", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

@@ -61,7 +61,8 @@ class TpchConnector(spi.Connector):
         if vr is None and ndv is None:
             return None
         low, high = vr if vr is not None else (None, None)
-        return spi.ColumnStats(low=low, high=high, ndv=ndv)
+        return spi.ColumnStats(low=low, high=high, ndv=ndv,
+                               vocabulary=gen.column_vocabulary(table, column))
 
     _PRIMARY_KEYS = {
         "region": ["r_regionkey"],

@@ -17,7 +17,8 @@ queries (e.g. '%special%requests%', '%green%') are preserved by construction.
 from __future__ import annotations
 
 import datetime
-from typing import Dict, List, Tuple
+import functools
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -699,12 +700,30 @@ def column_ndv(table: str, column: str, sf: float):
     # bounded-domain columns: min(span, rows)
     if vr is not None:
         return min(vr[1] - vr[0] + 1, rows)
-    vocab_sizes = {
-        "c_mktsegment": 5, "o_orderpriority": 5, "o_orderstatus": 3,
-        "l_returnflag": 3, "l_linestatus": 2, "l_shipinstruct": 4,
-        "l_shipmode": 7, "p_brand": 25, "p_mfgr": 5, "p_type": 150,
-        "p_container": 40, "n_name": 25, "r_name": 5,
-    }
-    if column in vocab_sizes:
-        return vocab_sizes[column]
+    if column in VOCAB_SIZES:
+        return VOCAB_SIZES[column]
     return None
+
+
+# columns drawn from a fixed vocabulary, whatever the scale factor
+VOCAB_SIZES = {
+    "c_mktsegment": 5, "o_orderpriority": 5, "o_orderstatus": 3,
+    "l_returnflag": 3, "l_linestatus": 2, "l_shipinstruct": 4,
+    "l_shipmode": 7, "p_brand": 25, "p_mfgr": 5, "p_type": 150,
+    "p_container": 40, "n_name": 25, "r_name": 5,
+    "p_name": len(PART_COLORS) ** 2,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def column_vocabulary(table: str, column: str) -> Optional[tuple]:
+    """Every string a fixed-vocabulary column can hold (the dictionary any
+    generated range of it carries), or None for any other column. The
+    draws are uniform over the vocabulary, near enough for an estimate
+    (p_name's two words are drawn from 92 of the 93 colours)."""
+    if column not in VOCAB_SIZES:
+        return None
+    data = generate(table, 0.01, 0, 1, [column])[column]
+    if data.dictionary is None:
+        return None
+    return tuple(data.dictionary.values)

@@ -153,28 +153,41 @@ def cached_stage(session, node, constraint, applied_domains, shard, loader):
     ``loader() -> (value, rows, nbytes, splits)``; returns
     ``(CacheEntry, "hit"|"miss"|"bypass")`` — bypass wraps the loaded
     artifact in a transient (never-admitted) entry so callers read one
-    shape. The executing scan's kernel row (obs/devprofiler.py) is charged
-    the disposition (``cacheHits`` / ``cacheMisses``; a bypass is neither)
-    and ``stagedBytes``, what this scan copied host -> device: 0 on a
-    hit."""
+    shape. A table staged under a key and larger than the admission cap
+    (the session's ``device_cache_max_bytes``, the pool's budget) is
+    returned and NOT kept: that is a bypass too, and with the cache on a
+    bypass says so. The executing scan's kernel row (obs/devprofiler.py) is
+    charged the disposition (``cacheHits`` / ``cacheMisses`` /
+    ``cacheBypasses``, the last only where the cache is on) and
+    ``stagedBytes``, what this scan copied host -> device: 0 on a hit."""
     import time
 
     from trino_tpu.devcache.cache import DEVICE_CACHE, CacheEntry
+    from trino_tpu.obs import metrics as M
     from trino_tpu.obs import trace as tracing
     from trino_tpu.obs.devprofiler import count_charged
 
     key = scan_cache_key(session, node, constraint, applied_domains,
                          shard=shard)
+    reason = None
     if key is None:
         value, rows, nbytes, splits = loader()
         now = time.time()
         ent, disposition = CacheEntry(
             None, value, rows, int(nbytes), splits,
             created_at=now, last_used_at=now), "bypass"
+        if shard is not None and cache_enabled(session):
+            reason = "unkeyed"
     else:
+        admit = admit_budget(session)
         with tracing.span("device-cache/lookup", table=node.table) as sp:
             ent, disposition = DEVICE_CACHE.lookup_or_stage(
-                key, loader, admit_bytes=admit_budget(session))
+                key, loader, admit_bytes=admit)
+            cap = DEVICE_CACHE.max_bytes
+            if admit is not None:
+                cap = min(cap, admit)
+            if disposition == "miss" and ent.nbytes > cap:
+                disposition, reason = "bypass", "over-cap"
             sp.set("result", disposition)
             sp.set("bytes", ent.nbytes)
     if disposition == "hit":
@@ -182,6 +195,9 @@ def cached_stage(session, node, constraint, applied_domains, shard, loader):
     else:
         if disposition == "miss":
             count_charged("cacheMisses")
+        elif reason is not None:
+            count_charged("cacheBypasses")
+            M.DEVICE_CACHE_BYPASS.inc(1, reason)
         count_charged("stagedBytes", ent.nbytes)
     return ent, disposition
 

@@ -80,9 +80,11 @@ class PreloadedExecutor(Executor):
         # visit, consumed by probe scans later in the same trace
         self.traced_domains: Dict[Tuple[int, int], tuple] = {}
 
-    def _collect_dynamic_filters(self, node: P.JoinNode, build: Page) -> None:
+    def _collect_dynamic_filters(self, node: P.JoinNode, build: Page,
+                                 measured: Dict) -> None:
         """Traced collection: no host syncs, just remember the build-side
-        key column (+liveness) for probe scans to mask against."""
+        key column (+liveness) for probe scans to mask against
+        (``measured`` is the eager tier's host read: empty here)."""
         for i in node.dyn_filter_keys:
             ch = node.right_keys[i]
             col = build.columns[ch]

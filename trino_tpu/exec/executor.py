@@ -37,7 +37,8 @@ from trino_tpu.exec.page_tree import (
 from trino_tpu.obs import metrics as M
 from trino_tpu.obs import trace as tracing
 from trino_tpu.obs.devprofiler import (
-    charge_to, count_charged, host_read, merge_platforms, new_kernel_row)
+    charge_to, count_charged, host_read, host_read_all, merge_platforms,
+    new_kernel_row)
 from trino_tpu.ops import aggregate as agg_ops
 from trino_tpu.ops import expr_lower as L
 from trino_tpu.ops import fused_join as fused_ops
@@ -1747,13 +1748,21 @@ class Executor:
         # Build side FIRST (the reference's phased build-before-probe
         # ordering) so its key domains can dynamically narrow probe scans.
         right = self.execute(node.right)
+        measured = {}  # a traced tier reads nothing: its domains are arrays
+        if self.eager_tier and node.left_keys:
+            right, measured = self._measure_build_keys(node, right)
         if self.enable_dynamic_filtering and node.dyn_filter_keys:
-            self._collect_dynamic_filters(node, right)
+            self._collect_dynamic_filters(node, right, measured)
         left = self.execute(node.left)
         return self._dispatch_join(node, left, right, compact_into)
 
     def _dispatch_join(self, node: P.JoinNode, left: Page, right: Page,
                        compact_into: Optional[P.CompactNode] = None) -> Page:
+        if self.eager_tier:
+            # the slots this join's place in the ORDER makes it carry,
+            # whatever the kernels then do with them (static shapes)
+            count_charged("joinProbeSlots", left.num_rows)
+            count_charged("joinBuildSlots", right.num_rows)
         if node.left_keys and self.eager_tier:
             # eager tier: spill-partition when the working set exceeds the
             # device budget (traced tiers bound memory via capacity hints)
@@ -1811,9 +1820,61 @@ class Executor:
     DYNAMIC_FILTER_MAX_SET = 1024  # in-set domain cap (reference: the
     # small/large domain-compaction thresholds of DynamicFilterConfig)
 
-    def _collect_dynamic_filters(self, node: P.JoinNode, build: Page) -> None:
-        """Extract build-side key domains host-side (one device sync per
-        key) for probe scans annotated by the optimizer."""
+    def _measure_build_keys(self, node: P.JoinNode, build: Page):
+        """(build, key index -> (live count, min, max)) of its integer key
+        columns, reduced on the device and fetched in ONE read of three
+        scalars a key (site ``dynamic-filter-domain``): what a dynamic
+        filter's domain starts from, and, for a key column whose static
+        range did not survive an exchange, the range the direct-address
+        join tier needs (``_dense_join_cols``; a 42,779-row build of part
+        keys would otherwise send 62.9 M probe slots through a sort).
+        Only keys one of the two will use are measured; a range measured
+        for a column that had none is set on a copy of the page."""
+        wanted = {}
+        dense = len(node.right_keys) == 1 and (
+            node.right_unique or node.join_type in ("semi", "anti"))
+        for i, ch in enumerate(node.right_keys):
+            col = build.columns[ch]
+            if (col.type.is_varchar or col.hi is not None
+                    or col.children is not None or not build.num_rows
+                    or not jnp.issubdtype(col.values.dtype, jnp.integer)):
+                continue
+            df = (self.enable_dynamic_filtering
+                  and i in (node.dyn_filter_keys or ()))
+            if df or (dense and col.vrange is None):
+                wanted[i] = col
+        if not wanted:
+            return build, {}
+        scalars = []
+        for col in wanted.values():
+            live = build.sel
+            if col.nulls is not None:
+                live = ~col.nulls if live is None else live & ~col.nulls
+            vals = jnp.asarray(col.values)
+            info = jnp.iinfo(vals.dtype)
+            if live is None:
+                scalars += [vals.shape[0], vals.min(), vals.max()]
+            else:
+                scalars += [jnp.sum(live.astype(jnp.int32)),
+                            jnp.where(live, vals, info.max).min(),
+                            jnp.where(live, vals, info.min).max()]
+        got = [int(x) for x in host_read_all(scalars, "dynamic-filter-domain")]
+        measured, columns = {}, list(build.columns)
+        for n, (i, col) in enumerate(wanted.items()):
+            count, lo, hi = got[3 * n:3 * n + 3]
+            measured[i] = (count, lo, hi)
+            if col.vrange is None and count:
+                columns[node.right_keys[i]] = dataclasses.replace(
+                    col, vrange=(lo, hi))
+        return dataclasses.replace(build, columns=columns), measured
+
+    def _collect_dynamic_filters(self, node: P.JoinNode, build: Page,
+                                 measured: Dict) -> None:
+        """Build-side key domains for the probe scans the optimizer
+        annotated. A build of at most ``DYNAMIC_FILTER_MAX_SET`` live keys
+        gives its key set (the column read to the host); a larger one its
+        range, from ``measured`` alone, and NO domain where that range
+        narrows nothing (``_range_narrows``)."""
         from trino_tpu.connector.predicate import Domain
 
         for i in node.dyn_filter_keys:
@@ -1821,6 +1882,12 @@ class Executor:
             col = build.columns[ch]
             if col.type.is_varchar:
                 continue  # dictionary codes are page-local, not portable
+            if i in measured and measured[i][0] > self.DYNAMIC_FILTER_MAX_SET:
+                _count, lo, hi = measured[i]
+                if self._range_narrows(node, i, lo, hi):
+                    self.dyn_domains[(node.id, i)] = Domain.range(
+                        low=lo, high=hi)
+                continue
             site = "dynamic-filter-domain"
             vals = host_read(col.values, site)
             live = (
@@ -1838,6 +1905,29 @@ class Executor:
             else:
                 dom = Domain.range(low=lv.min().item(), high=lv.max().item())
             self.dyn_domains[(node.id, i)] = dom
+
+    def _range_narrows(self, node: P.JoinNode, i: int, lo: int, hi: int) -> bool:
+        """False where [lo, hi] keeps as much of the probe column's own
+        range (the connector's column statistics) as the planner assumes
+        of a predicate it knows nothing about
+        (``stats.UNKNOWN_FILTER_COEFFICIENT``) or more: 42,779 green part
+        keys span 1 .. 2,000,000 of ``l_partkey``'s 1 .. 2,000,000. Such
+        a domain prunes no split, costs a pass over every scanned row to
+        apply, and would key the scan's cached artifact by binding. True
+        where the probe column's range is not known."""
+        from trino_tpu.sql.planner import stats
+        from trino_tpu.sql.planner.optimizer import _trace_to_scan
+
+        traced = _trace_to_scan(node.left, node.left_keys[i])
+        if traced is None:
+            return True
+        scan, column = traced
+        conn = self.session.catalogs.get(scan.catalog)
+        cs = conn.column_stats(scan.schema, scan.table, column) if conn else None
+        if cs is None or cs.vrange is None:
+            return True
+        kept = min(hi, cs.high) - max(lo, cs.low) + 1
+        return kept < stats.UNKNOWN_FILTER_COEFFICIENT * (cs.high - cs.low + 1)
 
     def hint_capacity(self, key: str, emit_counts) -> int:
         """Static output capacity for an expansion join or exchange, by hint

@@ -9,7 +9,7 @@ from __future__ import annotations
 from trino_tpu.exec.executor import Executor, QueryResult
 from trino_tpu.sql.parser import ast
 from trino_tpu.sql.parser.parser import parse_statement
-from trino_tpu.sql.planner.optimizer import optimize
+from trino_tpu.sql.planner.optimizer import optimize, stamp_join_estimates
 from trino_tpu.sql.planner.plan import format_plan
 from trino_tpu.sql.planner.planner import Planner
 
@@ -30,8 +30,8 @@ def plan_sql(session, sql: str):
         stmt = expand_udfs(stmt, udfs)
     with tracing.span("analyze/plan"):
         root = Planner(session).plan(stmt)
-    with tracing.span("optimize"):
-        return optimize(root, session)
+    with tracing.span("optimize") as sp:
+        return optimize(root, session, span=sp)
 
 
 def run_query(session, sql: str) -> QueryResult:
@@ -250,6 +250,7 @@ def explain_query(session, sql, mode: str = "logical", stmt=None) -> str:
             stmt = stmt.statement
     root = Planner(session).plan(stmt)
     root = optimize(root, session)
+    stamp_join_estimates(root, session)
     from trino_tpu.matview.substitute import substitute_plan
 
     root, mv_notes = substitute_plan(session, root)
@@ -571,6 +572,7 @@ def explain_analyze(session, stmt, verbose: bool = False) -> str:
     t_plan = _time.perf_counter()
     root = Planner(session).plan(stmt)
     root = optimize(root, session)
+    stamp_join_estimates(root, session)
     from trino_tpu.matview.substitute import substitute_plan
 
     root, mv_notes = substitute_plan(session, root)

@@ -61,7 +61,13 @@ scan's row counts what the device
 cache did for it (devcache/keys.py ``cached_stage``): ``cacheHits`` /
 ``cacheMisses``
 (lookups by disposition; a bypass counts neither) and ``stagedBytes``,
-the bytes it copied host -> device (0 on a hit).
+the bytes it copied host -> device (0 on a hit), and ``cacheBypasses``:
+scans the cache was on for and did not keep, because the staged table is
+over the admission cap (``device_cache_max_bytes``, the pool's budget)
+or no key could be made for it. A Join's row counts ``joinProbeSlots``
+and ``joinBuildSlots``: the row capacities (static shapes, no read) of
+the probe and build pages of every execution, the work its place in the
+join ORDER makes it carry whatever the kernels do.
 
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
@@ -115,7 +121,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "aggPrograms": 0, "aggEager": 0,
            "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0,
            "prefixCompactions": 0, "compactedJoins": 0,
-           "colocatedAggs": 0, "exchangedRows": 0, "outputFetches": 0}
+           "colocatedAggs": 0, "exchangedRows": 0, "outputFetches": 0,
+           "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -152,7 +159,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "d2hBytes", "compiles", "aggPrograms", "aggEager",
                       "cacheHits", "cacheMisses", "stagedBytes",
                       "prefixCompactions", "compactedJoins",
-                      "colocatedAggs", "exchangedRows", "outputFetches"):
+                      "colocatedAggs", "exchangedRows", "outputFetches",
+                      "joinProbeSlots", "joinBuildSlots", "cacheBypasses"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

@@ -473,6 +473,12 @@ DEVICE_CACHE_MISSES = REGISTRY.counter(
     "trino_tpu_device_cache_misses_total",
     "cache-eligible table stagings that transferred host pages to device "
     "and (budget permitting) filled the cache")
+DEVICE_CACHE_BYPASS = REGISTRY.counter(
+    "trino_tpu_device_cache_bypass_total",
+    "table stagings the enabled device cache did not keep, by reason: "
+    "over-cap (the staged table is larger than min(device_cache_max_bytes, "
+    "the pool's budget)), unkeyed (unversioned connector, open "
+    "transaction, unstable handle)", ("reason",))
 DEVICE_CACHE_EVICTIONS = REGISTRY.counter(
     "trino_tpu_device_cache_evictions_total",
     "device-cache entries dropped (LRU byte budget, revocable-tier yield "

@@ -707,8 +707,8 @@ class QueryExecution:
             with parameter_types(ptypes):
                 with tracing.span("analyze/plan"):
                     root = Planner(session).plan(inner)
-                with tracing.span("optimize"):
-                    return optimize(root, session)
+                with tracing.span("optimize") as sp:
+                    return optimize(root, session, span=sp)
 
         return self._through_plan_cache(
             session, ps.statement, ps.plan_cache_sql(ptypes), plan_fn)
@@ -1768,7 +1768,8 @@ class QueryExecution:
         from trino_tpu.exec.operator_stats import (
             merge_operator_dicts, wall_time_header)
         from trino_tpu.sql.planner.fragmenter import format_fragments
-        from trino_tpu.sql.planner.optimizer import optimize
+        from trino_tpu.sql.planner.optimizer import (
+            optimize, stamp_join_estimates)
         from trino_tpu.sql.planner.planner import Planner
 
         inner = stmt.statement
@@ -1780,8 +1781,9 @@ class QueryExecution:
         t_plan = _time.perf_counter()
         with tracing.span("analyze/plan"):
             root = Planner(session).plan(inner)
-        with tracing.span("optimize"):
-            root = optimize(root, session)
+        with tracing.span("optimize") as sp:
+            root = optimize(root, session, span=sp)
+        stamp_join_estimates(root, session)
         root, _versions = self._substitute_matviews(session, root, None)
         plan_s = _time.perf_counter() - t_plan
         t_exec = _time.perf_counter()

@@ -323,6 +323,9 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("colocatedAggs", 0)),
             int(r.get("exchangedRows", 0)),
             int(r.get("outputFetches", 0)),
+            int(r.get("joinProbeSlots", 0)),
+            int(r.get("joinBuildSlots", 0)),
+            int(r.get("cacheBypasses", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

@@ -163,12 +163,17 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
     _frag_ids = itertools.count()
     fragments: List[PlanFragment] = []
 
-    def cut(node: P.PlanNode, fragments: List[PlanFragment]) -> Tuple[P.PlanNode, bool]:
-        """Returns (node-in-current-fragment, is_replicated)."""
+    def cut(node: P.PlanNode, fragments: List[PlanFragment],
+            keep_under: int = 0) -> Tuple[P.PlanNode, bool]:
+        """Returns (node-in-current-fragment, is_replicated). ``keep_under``
+        comes down the probe side of a join that can run colocated: the
+        estimated live rows of that join's OTHER table, which cross an
+        exchange if the rows beneath leave the splits they were scanned
+        in (0: no such join above)."""
         if isinstance(node, P.TableScanNode):
             return node, False
         if isinstance(node, (P.FilterNode, P.ProjectNode, P.LimitNode, P.CompactNode)):
-            src, rep = cut(node.source, fragments)
+            src, rep = cut(node.source, fragments, keep_under)
             node.source = src
             return node, rep
         if isinstance(node, P.AggregationNode):
@@ -241,7 +246,31 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             )
             return final, True
         if isinstance(node, P.JoinNode):
-            left, lrep = cut(node.left, fragments)
+            from trino_tpu.sql.planner import stats
+
+            # both decided on the whole subtrees, before the cut puts
+            # exchange sources of unknown size into them
+            repartition = (
+                session is not None and bool(node.left_keys)
+                and node.join_type in ("inner", "semi", "anti", "left")
+                and stats.join_repartitions(session, node, 1))
+            # a join under the probe side of a colocated join keeps its
+            # probe where it was scanned and takes a build over the limit
+            # by broadcast all the same, while that build is SMALLER than
+            # what repartitioning would send across an exchange in its
+            # place: it costs the join above its colocation, and then the
+            # other table of that join crosses whole (TPC-H Q9: a 171 K-row
+            # partsupp build against 15 M orders rows). A build that is
+            # no smaller repartitions as it would anywhere else.
+            if repartition and keep_under:
+                repartition = stats.estimate_live_rows(
+                    session, node.right) >= keep_under
+            below = keep_under
+            if session is not None and _colocated_join(
+                    session, node, node.left, node.right):
+                below = max(below, stats.estimate_live_rows(
+                    session, node.right))
+            left, lrep = cut(node.left, fragments, below)
             right, rrep = cut(node.right, fragments)
             if (session is not None and not lrep and not rrep
                     and _colocated_join(session, node, left, right)):
@@ -254,41 +283,36 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                 node.left, node.right = left, right
                 node.distribution = "colocated"
                 return node, False
-            if (session is not None and not lrep and not rrep
-                    and node.left_keys and node.join_type in ("inner", "semi",
-                                                              "anti", "left")):
-                from trino_tpu.sql.planner import stats
-
-                if stats.join_repartitions(session, node, 1):
-                    # co-partitioned join (FIXED_HASH_DISTRIBUTION both
-                    # sides): probe and build tasks partition their output
-                    # pages by key hash; hash-stage task p joins partition
-                    # p of each side locally — equal keys co-locate, so the
-                    # union of per-partition joins is the exact join and NO
-                    # process ever materializes a whole side (reference:
-                    # PagePartitioner.java:134-149 + partitioned join
-                    # distribution).
-                    lfid = next(_frag_ids)
-                    fragments.append(PlanFragment(
-                        lfid, "source", left,
-                        output_partition_channels=list(node.left_keys)))
-                    rfid = next(_frag_ids)
-                    fragments.append(PlanFragment(
-                        rfid, "source", right,
-                        output_partition_channels=list(node.right_keys)))
-                    node.left = RemoteSourceNode(
-                        fragment_id=lfid, types=left.output_types,
-                        names=left.output_names, exchange_type="partitioned")
-                    node.right = RemoteSourceNode(
-                        fragment_id=rfid, types=right.output_types,
-                        names=right.output_names, exchange_type="partitioned")
-                    node.distribution = "partitioned"
-                    jfid = next(_frag_ids)
-                    fragments.append(PlanFragment(jfid, "hash", node))
-                    return RemoteSourceNode(
-                        fragment_id=jfid, types=node.output_types,
-                        names=node.output_names, exchange_type="gather",
-                    ), True
+            if repartition and not lrep and not rrep:
+                # co-partitioned join (FIXED_HASH_DISTRIBUTION both
+                # sides): probe and build tasks partition their output
+                # pages by key hash; hash-stage task p joins partition
+                # p of each side locally — equal keys co-locate, so the
+                # union of per-partition joins is the exact join and NO
+                # process ever materializes a whole side (reference:
+                # PagePartitioner.java:134-149 + partitioned join
+                # distribution).
+                lfid = next(_frag_ids)
+                fragments.append(PlanFragment(
+                    lfid, "source", left,
+                    output_partition_channels=list(node.left_keys)))
+                rfid = next(_frag_ids)
+                fragments.append(PlanFragment(
+                    rfid, "source", right,
+                    output_partition_channels=list(node.right_keys)))
+                node.left = RemoteSourceNode(
+                    fragment_id=lfid, types=left.output_types,
+                    names=left.output_names, exchange_type="partitioned")
+                node.right = RemoteSourceNode(
+                    fragment_id=rfid, types=right.output_types,
+                    names=right.output_names, exchange_type="partitioned")
+                node.distribution = "partitioned"
+                jfid = next(_frag_ids)
+                fragments.append(PlanFragment(jfid, "hash", node))
+                return RemoteSourceNode(
+                    fragment_id=jfid, types=node.output_types,
+                    names=node.output_names, exchange_type="gather",
+                ), True
             node.left = left
             if not rrep:
                 # build side broadcast: its own source fragment

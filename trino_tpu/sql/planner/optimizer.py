@@ -26,7 +26,9 @@ from trino_tpu.sql.planner import plan as P
 from trino_tpu.sql.planner.planner import combine_conjuncts, ir_conjuncts
 
 
-def optimize(root: P.OutputNode, session=None) -> P.OutputNode:
+def optimize(root: P.OutputNode, session=None, span=None) -> P.OutputNode:
+    """``span``: the caller's ``optimize`` span; with a tracer on, the join
+    order and the vocabulary selectivities are described on it."""
     # plan-IR sanity checking between passes (reference: PlanSanityChecker
     # interposed on every PlanOptimizer): a pass that breaks a channel
     # invariant is named by the failing phase instead of corrupting rows
@@ -40,6 +42,9 @@ def optimize(root: P.OutputNode, session=None) -> P.OutputNode:
     check(node, "optimizer:sink_semi_joins")
     node = orient_joins(node, session)
     check(node, "optimizer:orient_joins")
+    if session is not None:
+        node = reduce_large_builds(node, session)
+        check(node, "optimizer:reduce_large_builds")
     node, _ = prune_channels(node, set(range(len(node.output_types))))
     check(node, "optimizer:prune_channels")
     node = merge_identity_projects(node)
@@ -58,6 +63,8 @@ def optimize(root: P.OutputNode, session=None) -> P.OutputNode:
     if session is not None:
         node = insert_compactions(node, session)
         check(node, "optimizer:insert_compactions")
+        if span is not None and span.span_id is not None:  # a tracer is on
+            _describe_order(node, session, span)
     out = P.OutputNode(node, root.column_names)
     check(out, "optimizer:output")
     return out
@@ -413,6 +420,124 @@ def orient_joins(node: P.PlanNode, session) -> P.PlanNode:
             nms,
         )
     return node  # M:N join: executor uses the two-pass expansion kernel
+
+
+def reduce_large_builds(node: P.PlanNode, session) -> P.PlanNode:
+    """Hand a join's large build the filter of a small one. Where an inner
+    join's build is too large to broadcast (``stats.join_repartitions``)
+    and its probe side has ALREADY been joined, on a column that one of
+    this join's probe keys also traces to, with a filtered build small
+    enough to broadcast whose key is unique, every row this join's probe
+    carries holds a value of that column the small build kept. A row of
+    the large build whose key is not among them matches nothing, so the
+    large build is semi-joined with the small one's keys where it is
+    scanned: ``lineitem x part(green) x partsupp`` on ``l_partkey`` gives
+    ``partsupp`` ``ps_partkey in (select p_partkey from part where ...)``,
+    8 M rows to 171 K at SF 10, and the small build is scanned twice. The
+    equality is implied by the two edges (reference role: EqualityInference
+    feeding PredicatePushDown, with a semi-join where the reference has a
+    dynamic filter); results cannot change, and the join above keeps its
+    match share (``JoinNode.implied``)."""
+    import copy
+
+    from trino_tpu.sql.planner import stats
+
+    node = _replace_sources(
+        node, [reduce_large_builds(s, session) for s in node.sources])
+    if not (isinstance(node, P.JoinNode) and node.join_type == "inner"
+            and node.left_keys and not node.singleton
+            and stats.join_repartitions(session, node, 1)):
+        return node
+    for probe_ch, build_ch in zip(node.left_keys, node.right_keys):
+        traced = _trace_to_scan(node.left, probe_ch)
+        donor = traced and _filtering_build(node.left, traced, session)
+        if not donor:
+            continue
+        build, key = donor
+        keys = P.ProjectNode(
+            copy.deepcopy(build),
+            [ir.ColumnRef(build.output_types[key], key,
+                          build.output_names[key])],
+            [build.output_names[key]])
+        node.right = P.JoinNode(
+            join_type="semi", left=node.right, right=keys,
+            left_keys=[build_ch], right_keys=[0], implied=True)
+        break
+    return node
+
+
+def _filtering_build(probe: P.PlanNode, traced, session):
+    """(build subtree, its key channel) of an inner N:1 join on ``probe``'s
+    left spine whose probe key traces to the scan column ``traced`` and
+    whose build is filtered and broadcastable, or None."""
+    from trino_tpu.sql.planner import stats
+
+    while True:
+        if isinstance(probe, (P.ProjectNode, P.FilterNode, P.CompactNode)):
+            probe = probe.source
+            continue
+        if not (isinstance(probe, P.JoinNode) and probe.join_type == "inner"
+                and not probe.singleton):
+            return None
+        if (probe.right_unique and len(probe.left_keys) == 1
+                and probe.filter is None):
+            at = _trace_to_scan(probe.left, probe.left_keys[0])
+            if (at is not None and at[0] is traced[0] and at[1] == traced[1]
+                    and not stats.join_repartitions(session, probe, 1)
+                    and stats.estimate_live_rows(session, probe.right)
+                    < stats.estimate_rows(session, probe.right)):
+                return probe.right, probe.right_keys[0]
+        probe = probe.left
+
+
+def stamp_join_estimates(node: P.PlanNode, session) -> None:
+    """Stamp every join with the live-row estimates of its two inputs, for
+    the EXPLAIN paths to print (``est=[probe n, build m]``). Called where a
+    plan is about to be formatted, before the fragmenter puts exchange
+    sources of unknown size into it: a statement that is only run never
+    pays for it."""
+    from trino_tpu.sql.planner import stats
+
+    for n in P.walk_plan(node):
+        if isinstance(n, P.JoinNode):
+            n.est_probe_rows = stats.estimate_live_rows(session, n.left)
+            n.est_build_rows = stats.estimate_live_rows(session, n.right)
+
+
+def _describe_order(node: P.PlanNode, session, span) -> None:
+    """Put on the caller's ``optimize`` span the join order the plan ended
+    with (the relations down the probe spine, each with its estimated live
+    rows) and every selectivity read off a column's vocabulary."""
+    from trino_tpu.sql.planner import stats
+
+    vocab: List[str] = []
+    for n in P.walk_plan(node):
+        if isinstance(n, P.FilterNode):
+            for conj in ir_conjuncts(n.predicate):
+                hit = stats.dictionary_selectivity(session, conj, n.source)
+                if hit is not None:
+                    vocab.append(f"{conj!r}: {hit[0]}/{hit[1]}")
+    spine: List[P.JoinNode] = []    # the joins down the probe side, top first
+    n = node
+    while n is not None:
+        if isinstance(n, P.JoinNode):
+            spine.append(n)
+            n = n.left
+        else:
+            n = n.sources[0] if len(n.sources) == 1 else None
+
+    def relation(n: P.PlanNode) -> str:
+        scans = [x.table for x in P.walk_plan(n)
+                 if isinstance(x, P.TableScanNode)]
+        return (f"{'+'.join(scans) or type(n).__name__}"
+                f"={stats.estimate_live_rows(session, n)}")
+
+    if spine:
+        span.set("join-order", " ".join(
+            [relation(spine[-1].left)]
+            + [relation(j.right) for j in reversed(spine)]))
+    if vocab:
+        span.set("dictionary-selectivity", "; ".join(vocab))
 
 
 def _covered(keys: List[int], unique_sets: List[frozenset]) -> bool:
@@ -812,6 +937,7 @@ def prune_channels(node: P.PlanNode, needed: Set[int]) -> Tuple[P.PlanNode, Dict
             right_keys=[rmap[c] for c in node.right_keys],
             filter=node_filter, distribution=node.distribution,
             right_unique=node.right_unique, singleton=node.singleton,
+            implied=node.implied,
         )
         if semi:
             return new_node, lmap

@@ -384,6 +384,14 @@ class JoinNode(PlanNode):
     # scans applied: every surviving probe row has >= 1 build match, so
     # cardinality estimation skips the key-match discount
     df_exact: bool = False
+    # a semi-join the optimizer derived from the probe side of the join
+    # above it (optimizer.reduce_large_builds): it drops only build rows no
+    # probe row can match, so that join's match share is the unreduced
+    # build's
+    implied: bool = False
+    # the optimizer's live-row estimates of the two inputs, for EXPLAIN
+    est_probe_rows: Optional[int] = None
+    est_build_rows: Optional[int] = None
 
     @property
     def sources(self):
@@ -728,6 +736,9 @@ def format_plan(node: PlanNode, indent: int = 0, executor=None,
             f" [{node.join_type}{'/' + node.distribution if node.distribution else ''}]"
             f" L{node.left_keys} = R{node.right_keys}"
             + (f" filter={node.filter!r}" if node.filter is not None else "")
+            + (" implied" if node.implied else "")
+            + (f" est=[probe {node.est_probe_rows}, build {node.est_build_rows}]"
+               if node.est_probe_rows is not None else "")
         )
     elif isinstance(node, (SortNode, TopNNode)):
         detail = f" by={node.sort_channels}" + (

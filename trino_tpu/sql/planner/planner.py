@@ -50,6 +50,26 @@ def split_conjuncts(e: Optional[ast.Expression]) -> List[ast.Expression]:
     return [e]
 
 
+def _identifiers(e) -> Optional[List[ast.Identifier]]:
+    """Every identifier of an AST expression, or None where it holds a
+    subquery or a lambda (whose names are not the FROM list's)."""
+    out: List[ast.Identifier] = []
+
+    def walk(x) -> bool:
+        if isinstance(x, ast.Identifier):
+            out.append(x)
+        elif isinstance(x, (ast.Query, ast.Lambda, ast.Relation)):
+            return False
+        elif isinstance(x, ast.Node):
+            return all(walk(getattr(x, f.name))
+                       for f in dataclasses.fields(x))
+        elif isinstance(x, (tuple, list)):
+            return all(walk(y) for y in x)
+        return True
+
+    return out if walk(e) else None
+
+
 def ir_conjuncts(e: Optional[ir.Expr]) -> List[ir.Expr]:
     if e is None:
         return []
@@ -564,8 +584,15 @@ class Planner:
     def _reorder_implicit_joins(self, from_rel, spec, ctes):
         """Reorder a FROM comma-list (a chain of implicit/cross joins) so
         every join has an equi edge when one exists: start from the largest
-        relation (the fact), repeatedly append the SMALLEST relation
-        connected by a WHERE equality to the relations already joined.
+        relation (the fact), repeatedly append the relation whose join with
+        what is already joined is estimated SMALLEST, among those a WHERE
+        equality connects to it. Every size is the relation's FILTERED
+        estimate: its row count times the selectivity of the WHERE
+        conjuncts that name it alone (``stats.predicate_selectivity`` over
+        its own scan), so a selective filter on a dimension (TPC-H Q9's
+        ``p_name like '%green%'``: 2.1% of ``part``) brings that dimension
+        next to the fact, where its join cuts the rows every later join
+        carries.
 
         Reference role: ReorderJoins + DetermineJoinDistributionType in
         miniature — without it, a FROM list like TPC-DS q64's (18 relations
@@ -596,11 +623,11 @@ class Planner:
             return from_rel
         if any(self._unwrap_unnest(r)[0] is not None for r in rels):
             return from_rel  # UNNEST is lateral: list order is a data dependency
-        names, sizes, ndv_fns = [], [], []
+        names, rows, ndv_fns = [], [], []
         for r in rels:
             n, s, nf = self._relation_columns_and_size(r, ctes)
             names.append(n)
-            sizes.append(s)
+            rows.append(s)
             ndv_fns.append(nf)
 
         def owner(ident: ast.Identifier):
@@ -615,27 +642,41 @@ class Planner:
             return hits[0] if len(hits) == 1 else None
 
         edges = []  # (rel_a, rel_b, col_a, col_b)
+        alone: Dict[int, List] = {}  # relation -> the conjuncts naming it alone
         for conj in split_conjuncts(spec.where):
+            idents = _identifiers(conj)
+            owners = ({owner(i) for i in idents} if idents else {None})
             if (isinstance(conj, ast.Comparison) and conj.op == "="
                     and isinstance(conj.left, ast.Identifier)
-                    and isinstance(conj.right, ast.Identifier)):
+                    and isinstance(conj.right, ast.Identifier)
+                    and len(owners) == 2 and None not in owners):
                 a, b = owner(conj.left), owner(conj.right)
-                if a is not None and b is not None and a != b:
-                    edges.append((a, b, conj.left.parts[-1].lower(),
-                                  conj.right.parts[-1].lower()))
+                edges.append((a, b, conj.left.parts[-1].lower(),
+                              conj.right.parts[-1].lower()))
+            elif len(owners) == 1 and None not in owners:
+                alone.setdefault(owners.pop(), []).append(conj)
         if not edges:
             return from_rel
+        sizes = [
+            max(1.0, rows[i] * self._filtered_share(rels[i], alone.get(i), ctes))
+            for i in range(len(rels))]
 
         def edge_ndv(i, col):
             ndv = ndv_fns[i](col)
-            return ndv if ndv else sizes[i]
+            return ndv if ndv else rows[i]
 
         def join_estimate(cur_rows, cand, prefix):
-            """|prefix ⨝ cand| ≈ cur * |cand| / Π max(ndv_left, ndv_right)
-            over the connecting equi edges — the textbook containment
-            formula (reference: JoinStatsRule). Chooses the SELECTIVE edge
-            (suppkey, ndv 10k) over the exploding one (nationkey, ndv 25)
-            where plain smallest-relation-first cannot tell them apart."""
+            """|prefix ⨝ cand| ≈ cur * |cand filtered| / d, the textbook
+            containment formula (reference: JoinStatsRule), d the number of
+            distinct key combinations: over the connecting equi edges the
+            product of max(ndv_left, ndv_right), and never more than the
+            candidate has ROWS (two columns that are together a key,
+            ``ps_partkey`` x ``ps_suppkey``, have 8 M combinations in 8 M
+            rows, not 2 M x 100 K). So joining a relation on its key
+            estimates cur x the share of it its filters keep. Chooses the
+            SELECTIVE edge (suppkey, ndv 10k) over the exploding one
+            (nationkey, ndv 25) where plain smallest-relation-first cannot
+            tell them apart."""
             denom = 1.0
             connected = False
             for a, b, ca, cb in edges:
@@ -647,7 +688,7 @@ class Planner:
                     connected = True
             if not connected:
                 return cur_rows * sizes[cand], False
-            return cur_rows * sizes[cand] / denom, True
+            return cur_rows * sizes[cand] / min(denom, max(rows[cand], 1)), True
 
         remaining = set(range(len(rels)))
         start = max(remaining, key=lambda i: sizes[i])
@@ -661,7 +702,9 @@ class Planner:
             ]
             connected = [s for s in scored if s[2]]
             pool = connected or scored
-            nxt, est, _ = min(pool, key=lambda s: (s[1], sizes[s[0]]))
+            # whole rows: x * n / n is not x in floating point, and a tie
+            # must stay a tie for the smaller relation to win it
+            nxt, est, _ = min(pool, key=lambda s: (round(s[1]), sizes[s[0]]))
             order.append(nxt)
             prefix.add(nxt)
             cur_rows = max(est, 1.0)
@@ -672,6 +715,32 @@ class Planner:
         for i in order[1:]:
             out = ast.Join(join_type="implicit", left=out, right=rels[i])
         return out
+
+    def _filtered_share(self, r, conjuncts, ctes) -> float:
+        """The share of base table ``r`` that the WHERE conjuncts naming it
+        alone keep, by the optimizer's own selectivity rules over its scan;
+        1.0 for a relation that is not a plain table or a conjunct the
+        analyzer refuses here (the ordering is best-effort)."""
+        table = r.relation if isinstance(r, ast.AliasedRelation) else r
+        if not conjuncts or not isinstance(table, ast.Table) or (
+                len(table.parts) == 1 and table.parts[0].lower() in ctes):
+            return 1.0
+        from trino_tpu.sql.planner import stats
+
+        try:
+            rp = self.plan_relation(r, None, ctes)
+            if not isinstance(rp.node, P.TableScanNode):
+                return 1.0
+            share = 1.0
+            for conj in conjuncts:
+                analyzer = ExprAnalyzer(rp.scope)
+                pred = analyzer.analyze(conj)
+                if not analyzer.outer_refs:
+                    share *= stats.predicate_selectivity(
+                        self.session, pred, rp.node)
+            return share
+        except Exception:  # noqa: BLE001 — best-effort attribution
+            return 1.0
 
     def _relation_alias(self, r) -> Optional[str]:
         if isinstance(r, ast.AliasedRelation):
@@ -729,7 +798,7 @@ class Planner:
                 return {c.name.lower() for c in meta.columns}, rows, ndv
             except Exception:  # noqa: BLE001 — best-effort attribution
                 return set(), 10_000, self._no_ndv
-        return set(), 10_000
+        return set(), 10_000, self._no_ndv
 
     @staticmethod
     def _unwrap_unnest(r: ast.Relation):
