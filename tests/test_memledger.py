@@ -241,21 +241,38 @@ def test_memory_context_owner_deltas_never_double_count():
 def test_staging_scratch_attributed_and_released():
     import numpy as np
 
-    from trino_tpu.exec.staging import blocked_transfer
+    from trino_tpu.exec.staging import PagePuts
 
-    # small block size forces the blocked (double-buffered) path, which
-    # is the one that holds transient device scratch worth attributing
-    transfer = blocked_transfer(block_bytes=1024)
+    # a page's puts are the staging owner's while in flight: one reserve
+    # a put when it is issued, released by the page's one wait
     mark = _mark()
-    out = transfer(np.arange(1024, dtype=np.int64))
+    with PagePuts() as puts:
+        out = puts.put(np.arange(1024, dtype=np.int64))
+        puts.put(np.zeros(512, bool))
     assert out.shape == (1024,)
     events = [r for r in _events_since(mark) if r["owner"] == "staging"]
-    kinds = [e["kind"] for e in events]
-    assert kinds == ["reserve", "release"]
-    assert events[0]["bytes"] == events[1]["bytes"] > 0
+    assert [(e["kind"], e["bytes"]) for e in events] == [
+        ("reserve", 8192), ("reserve", 512), ("release", 8192),
+        ("release", 512)]
     row = next(r for r in MEMORY_LEDGER.owner_rows()
                if r["owner"] == "staging" and r["pool"] == POOL_DEVICE)
     assert row["bytes"] == 0  # scratch never outlives the transfer
+
+
+def test_staging_scratch_released_when_a_page_fails():
+    import numpy as np
+
+    from trino_tpu.exec.staging import PagePuts
+
+    mark = _mark()
+    with pytest.raises(ValueError):
+        with PagePuts() as puts:
+            puts.put(np.arange(256, dtype=np.int64))
+            raise ValueError("a later column of the page failed")
+    events = [r for r in _events_since(mark) if r["owner"] == "staging"]
+    assert [(e["kind"], e["bytes"]) for e in events] == [
+        ("reserve", 2048), ("release", 2048)]
+    assert puts.count == 1
 
 
 # ------------------------------------------------------------- postmortem

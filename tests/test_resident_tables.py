@@ -29,7 +29,7 @@ from trino_tpu.devcache import DEVICE_CACHE, HOST_CACHE, keys
 from trino_tpu.obs import metrics as M
 from trino_tpu.obs.timeline import compute_timeline
 
-FIELDS = ("cacheHits", "cacheMisses", "stagedBytes")
+FIELDS = ("cacheHits", "cacheMisses", "stagedBytes", "stagingPuts")
 Q3 = """select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
        o_orderdate, o_shippriority
 from customer, orders, lineitem
@@ -176,11 +176,12 @@ def test_kernel_rows_count_what_the_cache_did(q3_runs, which):
     scans = len(run["snapshot"])
     if which == 0:
         assert (got["cacheHits"], got["cacheMisses"]) == (0, scans)
-        # a miss copies exactly what it admits
+        # a miss copies exactly what it admits, an array of it a put
         assert got["stagedBytes"] == run["bytes"]
+        assert got["stagingPuts"] >= 2 * scans
     else:
         assert got == {"cacheHits": scans, "cacheMisses": 0,
-                       "stagedBytes": 0}
+                       "stagedBytes": 0, "stagingPuts": 0}
         assert got["cacheHits"] >= 3
 
 
@@ -192,6 +193,7 @@ def test_cache_off_counts_no_lookup_and_stages_every_time(q3_runs, which):
     assert (got["cacheHits"], got["cacheMisses"]) == (0, 0)
     # a bypass copies its scans every time: the bytes a miss admits
     assert got["stagedBytes"] == q3_runs[0]["bytes"] > 0
+    assert got["stagingPuts"] == q3_runs[0]["counters"]["stagingPuts"]
     assert "device-staging/cache-lookup" not in run["timeline_off"]["detail"]
     assert "device-cache/lookup" not in run["spans_off"]
 

@@ -470,37 +470,34 @@ def test_staging_subphase_spans_and_ledger_mapping():
     # host-cache span reports full hits and no scan fan-out follows it
     hc = [sp for sp in tracer.spans() if sp.name == "staging/host-cache"]
     assert hc[-1].attributes["hits"] == hc[-1].attributes["splits"]
+    # the transfer says where its time went and what crossed: two int64
+    # columns of 4,000 rows (no range to narrow by), one put each, no
+    # bucket (eager)
+    xfer = [sp for sp in tracer.spans() if sp.name == "staging/transfer"]
+    attrs = xfer[-1].attributes
+    assert (attrs["puts"], attrs["bytes"]) == (2, 2 * 4000 * 8)
+    for key in ("host_s", "put_s", "wait_s"):
+        assert 0 <= attrs[key] <= xfer[-1].duration
 
 
-def test_blocked_transfer_bit_identical():
-    """The double-buffered blocked path (arrays over two blocks) is
-    bitwise identical to a single-shot put, counts its blocks, respects
-    the BLOCKED_MAX_BYTES single-shot carve-out, and handles the 2-D
-    SPMD stacked shape (rows = last axis)."""
+def test_page_puts_bit_identical_one_put_an_array():
+    """``PagePuts`` puts each array once, bitwise identical, 1-D columns
+    and the 2-D SPMD stacked shape alike, and counts what it issued."""
     from trino_tpu.exec import staging
+    from trino_tpu.obs.devprofiler import charge_to, new_kernel_row
 
     rng = np.random.default_rng(5)
-    prof = staging.StageProfile()
-    xfer = staging.blocked_transfer(prof, block_bytes=1 << 12)
     flat = rng.integers(-1 << 40, 1 << 40, size=5000, dtype=np.int64)
-    out = np.asarray(xfer(flat))
-    assert out.dtype == flat.dtype and np.array_equal(out, flat)
-    assert prof.transfer_blocks >= 3  # the blocked path actually ran
     stacked = rng.integers(0, 1 << 20, size=(4, 3000), dtype=np.int64)
-    out2 = np.asarray(xfer(stacked))
-    assert out2.shape == stacked.shape and np.array_equal(out2, stacked)
-    # over the cap: single-shot (no extra blocks counted), still exact
-    before = prof.transfer_blocks
-    cap = staging.BLOCKED_MAX_BYTES
-    try:
-        staging.BLOCKED_MAX_BYTES = 1 << 10
-        big = rng.integers(0, 1 << 30, size=4000, dtype=np.int64)
-        out3 = np.asarray(staging.blocked_transfer(
-            prof, block_bytes=1 << 12)(big))
-        assert np.array_equal(out3, big)
-        assert prof.transfer_blocks == before
-    finally:
-        staging.BLOCKED_MAX_BYTES = cap
+    row = new_kernel_row("0", "TableScan", "eager")
+    with charge_to(row), staging.PagePuts() as puts:
+        outs = [puts.put(flat), puts.put(stacked)]
+    for out, want in zip(outs, (flat, stacked)):
+        got = np.asarray(out)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got, want)
+    assert (puts.count, puts.nbytes) == (2, flat.nbytes + stacked.nbytes)
+    assert row["stagingPuts"] == 2
 
 
 def test_staging_phase_seconds_metric():
@@ -659,10 +656,7 @@ def test_row_bucket_gives_sibling_splits_one_shape():
 
 
 def test_bucketed_page_is_the_exact_page_plus_a_dead_tail():
-    import jax.numpy as jnp
-
     from trino_tpu.connector.spi import ColumnData
-    from trino_tpu.data.page import Page
     from trino_tpu.exec import staging
 
     n = 1000
@@ -674,13 +668,206 @@ def test_bucketed_page_is_the_exact_page_plus_a_dead_tail():
         ColumnData(T.BIGINT, rng.integers(-1 << 40, 1 << 40, n),
                    nulls=rng.random(n) < 0.1),
     ]
-    exact = staging.page_from_host_columns(types, host, jnp.asarray)
-    padded_host, live = staging.pad_to_row_bucket(types, host)
-    padded = staging.page_from_host_columns(types, padded_host, jnp.asarray)
-    assert exact.sel is None and exact.num_rows == n == live
+    exact, _ = staging.put_page(types, host)
+    padded, _ = staging.put_page(types, host, bucket_rows=True)
+    assert exact.sel is None and exact.num_rows == n
     assert padded.num_rows == staging.row_bucket(n) > n
-    padded = Page(padded.columns, jnp.arange(padded.num_rows) < live,
-                  live_prefix=True)  # as staged_scan_page marks the tail
-    assert padded.live_count() == n
+    assert padded.live_prefix and padded.live_count() == n
     assert padded.columns[0].ascending  # dead rows are a tail
     assert padded.compact().to_pylist() == exact.to_pylist()
+
+
+# ------------------------------ one host pass and one put an array (PR 37)
+def _parent_arrays(types, host, bucket_rows):
+    """The parent's staged arrays (PR 36's ``pad_to_row_bucket`` then
+    ``page_from_host_columns`` then the mask), in numpy: an all-flat page
+    on a worker is padded with zero rows to ``row_bucket``, THEN a fitting
+    int64 is narrowed to int32, and the mask is an ``arange`` compared
+    with the live rows; nested and two-limb columns go as they are.
+    Returns ``(leaves, sel)``, leaves in ``_leaves`` order."""
+    from trino_tpu.data.page import fits_int32
+    from trino_tpu.exec import staging
+
+    def as_is(cd):
+        out = [np.asarray(cd.values),
+               None if cd.nulls is None else np.asarray(cd.nulls),
+               None if cd.hi is None else np.asarray(cd.hi)]
+        for kid in cd.children or ():
+            out += as_is(kid)
+        return out
+
+    flat = [not (t.is_nested or cd.hi is not None)
+            for t, cd in zip(types, host)]
+    live = len(host[0].values)
+    extra = staging.row_bucket(live) - live if bucket_rows and all(
+        flat) else 0
+
+    def pad(a):
+        a = np.asarray(a)
+        return np.concatenate([a, np.zeros(extra, a.dtype)]) if extra else a
+
+    leaves = []
+    for is_flat, cd in zip(flat, host):
+        if not is_flat:
+            leaves += as_is(cd)
+            continue
+        vals = pad(cd.values)
+        if vals.dtype == np.int64 and fits_int32(cd.vrange):
+            vals = vals.astype(np.int32)
+        leaves += [vals, None if cd.nulls is None else pad(cd.nulls), None]
+    sel = np.arange(live + extra) < live if extra else None
+    return leaves, sel
+
+
+def _leaves(col):
+    out = [np.asarray(col.values),
+           None if col.nulls is None else np.asarray(col.nulls),
+           None if col.hi is None else np.asarray(col.hi)]
+    for kid in col.children or ():
+        out += _leaves(kid)
+    return out
+
+
+def _staging_case(name):
+    """(types, host ColumnData list, bucket_rows) for one case."""
+    from decimal import Decimal
+
+    from trino_tpu.connector.spi import ColumnData, column_data_from_column
+    from trino_tpu.data.page import Column
+
+    rng = np.random.default_rng(11)
+    n = 1000
+
+    def cd_of(typ, values):
+        return column_data_from_column(Column.from_python(typ, values))
+
+    keys = ColumnData(T.BIGINT, np.arange(n, dtype=np.int64) * 3,
+                      vrange=(0, 3 * n), sorted=True)
+    if name == "int64-fits-int32":
+        return [T.BIGINT], [keys], True
+    if name == "int64-wide":
+        wide = rng.integers(-1 << 40, 1 << 40, n)
+        return [T.BIGINT], [ColumnData(T.BIGINT, wide,
+                                       vrange=(-1 << 40, 1 << 40))], True
+    if name == "dictionary-codes":
+        return [T.VARCHAR, T.BIGINT], [cd_of(T.VARCHAR, [
+            ("AUTO", "BUILDING", "MACHINERY")[i % 3] for i in range(n)]),
+            keys], True
+    if name == "nullable":
+        vals = rng.integers(0, 500, n)
+        return [T.BIGINT, T.DOUBLE], [
+            ColumnData(T.BIGINT, vals, nulls=rng.random(n) < 0.2,
+                       vrange=(0, 500)),
+            ColumnData(T.DOUBLE, rng.random(n), nulls=rng.random(n) < 0.3),
+        ], True
+    if name == "two-limb-decimal":
+        dec = T.decimal(30, 2)
+        return [dec, T.BIGINT], [cd_of(dec, [
+            None if i % 7 == 0 else Decimal(i) * Decimal("1234567.89")
+            for i in range(n)]), keys], True
+    if name == "nested":
+        arr = T.array_of(T.BIGINT)
+        return [arr, T.BIGINT], [cd_of(arr, [
+            None if i % 5 == 0 else list(range(i % 4)) for i in range(n)]),
+            keys], True
+    if name == "own-bucket":
+        m = 4096
+        return [T.BIGINT, T.INTEGER], [
+            ColumnData(T.BIGINT, np.arange(m, dtype=np.int64),
+                       vrange=(0, m), sorted=True),
+            ColumnData(T.INTEGER, rng.integers(0, 9, m).astype(np.int32)),
+        ], True
+    if name == "empty":
+        return [T.BIGINT, T.VARCHAR], None, True
+    assert name == "eager-no-bucket"
+    return [T.BIGINT, T.BIGINT], [keys, ColumnData(
+        T.BIGINT, rng.integers(0, 50, n), vrange=(0, 50))], False
+
+
+STAGING_CASES = ("int64-fits-int32", "int64-wide", "dictionary-codes",
+                 "nullable", "two-limb-decimal", "nested", "own-bucket",
+                 "empty", "eager-no-bucket")
+
+
+@pytest.mark.parametrize("width", [1, 4])
+@pytest.mark.parametrize("name", STAGING_CASES)
+def test_put_page_is_the_parent_s_page_bit_for_bit(name, width):
+    """Every array, dtype, shape, the mask, ``live_prefix``, ``vrange``,
+    ``ascending`` and the dictionary of the one-pass page are the parent's;
+    it issued one put an array of the page."""
+    from trino_tpu.data.page import Page
+    from trino_tpu.exec import staging
+
+    types, host, bucket_rows = _staging_case(name)
+    page, puts = staging.put_page(types, host, bucket_rows=bucket_rows,
+                                  width=width)
+    if host is None:
+        want = Page.all_dead(types)
+        assert puts.count == 0 and page.num_rows == want.num_rows == 1
+        _assert_same_arrays(
+            [a for c in page.columns for a in _leaves(c)],
+            [a for c in want.columns for a in _leaves(c)])
+        assert np.array_equal(np.asarray(page.sel), np.asarray(want.sel))
+        return
+    leaves, sel = _parent_arrays(types, host, bucket_rows)
+    got = [a for c in page.columns for a in _leaves(c)]
+    _assert_same_arrays(got, leaves)
+    if sel is None:
+        assert page.sel is None and not page.live_prefix
+    else:
+        assert page.live_prefix
+        _assert_same_arrays([np.asarray(page.sel)], [sel])
+    for col, cd in zip(page.columns, host):
+        assert col.dictionary is cd.dictionary and col.vrange == cd.vrange
+        assert col.ascending == bool(cd.sorted)
+    arrays = sum(a is not None for a in leaves) + (sel is not None)
+    assert puts.count == arrays
+    assert puts.nbytes == sum(a.nbytes for a in leaves if a is not None) + (
+        0 if sel is None else sel.nbytes)
+
+
+def test_a_length_that_is_its_own_bucket_is_put_without_a_copy():
+    from trino_tpu.connector.spi import ColumnData
+    from trino_tpu.exec import staging
+
+    codes = np.arange(4096, dtype=np.int32)
+    assert staging._one_pass(codes, 4096, np.int32) is codes
+    page, puts = staging.put_page(
+        [T.INTEGER], [ColumnData(T.INTEGER, codes)], bucket_rows=True)
+    assert page.sel is None and puts.count == 1
+
+
+def test_a_worker_scan_charges_one_put_an_array_and_none_on_a_hit():
+    """A fresh staging of an N-array page charges N ``stagingPuts`` to the
+    scan's kernel row, a device-cache hit none, and the cache entry holds
+    the parent's bytes: the same arrays, the same ``nbytes``."""
+    from trino_tpu.exec import staging
+    from trino_tpu.obs.devprofiler import charge_to, new_kernel_row
+    from trino_tpu.server.task import FragmentExecutor
+
+    s = _session(staging_split_bytes=1 << 12)
+    _tables(s)
+    _root, scans = _scan_node(s, "select l_orderkey, l_price from lineitem")
+    node = scans[0]
+    conn = s.catalogs["memory"]
+    splits = conn.get_splits("db", "lineitem", 4)
+    assert len(splits) > 1
+    rows, pages = [], []
+    for _ in range(2):
+        row = new_kernel_row("0", "TableScan", "eager")
+        with charge_to(row):
+            pages.append(FragmentExecutor(s, {node.id: splits}, {})
+                         ._exec_TableScanNode(node))
+        rows.append(row)
+    host = staging.assemble_host_columns(
+        node.column_names, node.column_types,
+        [conn.scan(sp, list(node.column_names)) for sp in splits])
+    leaves, sel = _parent_arrays(node.column_types, host, True)
+    assert sel is not None  # 4,000 rows stage at a 4,096-row bucket
+    want = [a for a in leaves if a is not None] + [sel]
+    (entry,) = [e for e in DEVICE_CACHE.snapshot() if e["table"] == "lineitem"]
+    assert rows[0]["stagingPuts"] == len(want) == 3
+    assert rows[0]["stagedBytes"] == entry["bytes"] == sum(
+        a.nbytes for a in want)
+    assert (rows[1]["cacheHits"], rows[1]["stagingPuts"],
+            rows[1]["stagedBytes"]) == (1, 0, 0)

@@ -246,6 +246,9 @@ SYSTEM_TABLES = {
         # scans the device cache was on for and did not keep (over the
         # admission cap, or no key could be made), on the scan's row
         ("cache_bypasses", "bigint"),
+        # host -> device puts a fresh staging issued, one an array of the
+        # page (exec/staging.py PagePuts), on the scan's row: 0 on a hit
+        ("staging_puts", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

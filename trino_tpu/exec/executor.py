@@ -119,24 +119,6 @@ def _key_lowereds(c: Column, force_two_limb: bool = False) -> List[join_ops.Lowe
     return [(hi, valid), (lo ^ jnp.int64(-(2**63)), valid)]
 
 
-def _column_from_data(cd) -> Column:
-    """ColumnData -> device Column, recursing into nested children."""
-    return Column(
-        cd.type,
-        jnp.asarray(np.asarray(cd.values)),
-        jnp.asarray(cd.nulls) if cd.nulls is not None else None,
-        cd.dictionary,
-        cd.vrange,
-        ascending=bool(getattr(cd, "sorted", False)),
-        children=(
-            [_column_from_data(k) for k in cd.children]
-            if cd.children is not None
-            else None
-        ),
-        hi=jnp.asarray(cd.hi) if cd.hi is not None else None,
-    )
-
-
 def scan_constraint_with(node: "P.TableScanNode", dyn_domains):
     """Effective TupleDomain for a scan: static pushdown ∩ available
     dynamic-filter domains (reference: DynamicFilter.getCurrentPredicate).

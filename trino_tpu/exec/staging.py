@@ -19,13 +19,14 @@ compiled phase-1 in ``exec/executor.py``, worker fragments in
   :data:`~trino_tpu.devcache.hostcache.HOST_CACHE` (single-flight), hits
   skip the connector entirely, so an HBM eviction or a re-sharding pays
   transfer only (``staging/host-cache`` span);
-- **double-buffered host->device transfer** — ``blocked_transfer`` chunks
-  the assembled columns into byte-bounded row blocks and issues the async
-  ``jax.device_put`` for block k+1 before block k is consumed by the
-  device-side assembly, bounding pinned-host pressure and overlapping
-  PCIe/ICI DMA with host work on real accelerators (CPU meshes degrade to
-  a plain copy); the pre-transfer projection (scan's column list) and the
-  host-applied constraint pruning mean only needed columns/rows cross;
+- **one host pass and one put an array** — ``put_page`` makes each
+  column ready in ONE host pass (narrowed and padded to its row bucket
+  at once, or put as it is where it already is), the columns side by
+  side on the shared pool, then issues every array's
+  ``jax.device_put`` before it waits, once, for the whole page
+  (``PagePuts``); the pre-transfer projection (scan's column list) and
+  the host-applied constraint pruning mean only needed columns/rows
+  cross;
 - **adaptive split sizing** — ``target_split_count`` derives the
   ``get_splits`` target from estimated table bytes / the
   ``staging_split_bytes`` session property, so tiny tables don't pay
@@ -42,6 +43,7 @@ semantics (``phase1_s + df_apply_s``, drift-tested).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -60,18 +62,6 @@ DEFAULT_SPLIT_BYTES = 64 << 20
 # fan-out ceiling: beyond this, per-split constant costs (gencache entry
 # churn, dictionary merges) dominate any remaining overlap win
 MAX_TARGET_SPLITS = 64
-# target bytes per double-buffered transfer block
-TRANSFER_BLOCK_BYTES = 32 << 20
-# above this, a column transfers single-shot instead of blocked: the
-# blocked path's device-side concat transiently holds blocks + output
-# (~2x the column) — a peak the eviction machinery cannot see — so giant
-# columns keep the 1x-peak path until the hardware round sizes a real
-# bound (env TRINO_TPU_STAGING_BLOCKED_MAX_BYTES)
-BLOCKED_MAX_BYTES = int(os.environ.get(
-    "TRINO_TPU_STAGING_BLOCKED_MAX_BYTES") or 256 << 20)
-# double-buffer depth: un-materialized device_puts allowed in flight
-# before the next block issues (bounds pinned-host/DMA-staging memory)
-_INFLIGHT_PUTS = 2
 # shared scan pool capacity (all sessions of this process; per-staging
 # concurrency is bounded separately by staging_parallelism)
 POOL_WORKERS = max(4, int(os.environ.get("TRINO_TPU_STAGING_POOL") or 16))
@@ -203,7 +193,6 @@ class StageProfile:
     fanout_wall_s: float = 0.0
     decode_wall_s: float = 0.0
     transfer_wall_s: float = 0.0
-    transfer_blocks: int = 0
 
     def overlap(self) -> float:
         if self.fanout_wall_s <= 0:
@@ -355,73 +344,66 @@ def assemble_host_columns(column_names, column_types, datas):
     return cols
 
 
-def blocked_transfer(profile: Optional[StageProfile] = None,
-                     block_bytes: int = TRANSFER_BLOCK_BYTES):
-    """A ``transfer(np.ndarray) -> device array`` that double-buffers:
-    rows chunk into ~``block_bytes`` blocks, every block's async
-    ``jax.device_put`` is issued before the first is consumed, and the
-    device-side concat assembles them — so DMA of block k+1 overlaps the
-    consumption of block k, and the result is bitwise identical to a
-    single-shot put. Arrays at/below two blocks take the single-shot fast
-    path (no device-side copy for the small-table common case), and
-    arrays over BLOCKED_MAX_BYTES do too: the blocked path's device-side
-    concat transiently holds blocks + output (~2x the column) regardless
-    of the put window — see the constant. The in-flight PUT window is
-    what is double-buffered: at most _INFLIGHT_PUTS un-materialized
-    host->device copies exist at once, bounding pinned-host/DMA-staging
-    pressure while the transfer engine runs ahead of the consumer. The
-    rows axis is the LAST axis (flat columns are 1-D; SPMD stacked
-    shards are [ndev, rows])."""
-    import jax
-    import jax.numpy as jnp
+class PagePuts:
+    """The host->device puts of one staged page, used as a context: each
+    array is put ONCE (``jax.device_put``, asynchronous), every array of
+    the page is issued before any is waited on, and leaving the context
+    waits once for all of them, also when a put failed. Per put: one
+    memory-ledger ``reserve`` under the ``staging`` owner while it is in
+    flight, released by the wait, and one flow-ledger record on the
+    ``staging-transfer`` link. Calling-thread wall seconds: ``host_s``
+    making the host arrays ready, ``put_s`` issuing, ``wait_s`` the one
+    wait; ``nbytes`` is what crossed, pad included."""
 
-    from trino_tpu.obs.flowledger import FLOW_LEDGER
-    from trino_tpu.obs.memledger import MEMORY_LEDGER, POOL_DEVICE
+    def __init__(self):
+        self.host_s = self.put_s = self.wait_s = 0.0
+        self.nbytes = self.count = 0
+        self._inflight: List[Tuple[object, int, float]] = []
 
-    def transfer(arr: np.ndarray):
-        arr = np.asarray(arr)
-        n = arr.shape[-1] if arr.ndim else 0
-        row_bytes = (arr.nbytes // n) if n else 0
-        block_rows = max(1, block_bytes // max(1, row_bytes)) if n else 0
+    def put(self, arr: np.ndarray, sharding=None):
+        import jax
+
+        from trino_tpu.obs.memledger import MEMORY_LEDGER, POOL_DEVICE
+
         t0 = time.perf_counter()
-        if not n or n <= 2 * block_rows or arr.nbytes > BLOCKED_MAX_BYTES:
-            out = jnp.asarray(arr)
-            FLOW_LEDGER.record_transfer(
-                "staging-transfer", "staging", int(arr.nbytes),
-                time.perf_counter() - t0, pages=1, src="host", dst="device",
-                direction="send", status="single-shot")
-            return out
-        axis = arr.ndim - 1
-        # the blocked path's transient scratch (blocks + concat output,
-        # ~2x the column — the BLOCKED_MAX_BYTES comment) is attributed
-        # to the ledger's staging owner for its lifetime: this is
-        # device-pool pressure the eviction machinery cannot see
-        MEMORY_LEDGER.record_event(
-            "reserve", POOL_DEVICE, "staging", int(arr.nbytes))
-        try:
-            blocks = []
-            for bi, i in enumerate(range(0, n, block_rows)):
-                idx = (slice(None),) * axis + (slice(i, i + block_rows),)
-                # force block bi - _INFLIGHT_PUTS resident BEFORE issuing
-                # block bi, so at most _INFLIGHT_PUTS un-materialized puts
-                # ever exist at once (forcing after the issue would briefly
-                # hold one extra)
-                if bi >= _INFLIGHT_PUTS:
-                    blocks[bi - _INFLIGHT_PUTS].block_until_ready()
-                blocks.append(jax.device_put(arr[idx]))
-            if profile is not None:
-                profile.transfer_blocks += len(blocks)
-            out = jnp.concatenate(blocks, axis=axis)
-            FLOW_LEDGER.record_transfer(
-                "staging-transfer", "staging", int(arr.nbytes),
-                time.perf_counter() - t0, pages=len(blocks), src="host",
-                dst="device", direction="send", status="blocked")
-            return out
-        finally:
-            MEMORY_LEDGER.record_event(
-                "release", POOL_DEVICE, "staging", int(arr.nbytes))
+        out = jax.device_put(arr, sharding)
+        self.put_s += time.perf_counter() - t0
+        nbytes = int(arr.nbytes)
+        MEMORY_LEDGER.record_event("reserve", POOL_DEVICE, "staging", nbytes)
+        self._inflight.append((out, nbytes, t0))
+        self.nbytes += nbytes
+        self.count += 1
+        return out
 
-    return transfer
+    def __enter__(self) -> "PagePuts":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        """Block until every put is on the device, then charge the
+        executing scan's kernel row ``stagingPuts`` with the puts
+        (obs/devprofiler.py)."""
+        import jax
+
+        from trino_tpu.obs.devprofiler import count_charged
+        from trino_tpu.obs.flowledger import FLOW_LEDGER
+        from trino_tpu.obs.memledger import MEMORY_LEDGER, POOL_DEVICE
+
+        t0 = time.perf_counter()
+        try:
+            jax.block_until_ready([out for out, _, _ in self._inflight])
+        finally:
+            done = time.perf_counter()
+            for _out, nbytes, _issued in self._inflight:
+                MEMORY_LEDGER.record_event(
+                    "release", POOL_DEVICE, "staging", nbytes)
+        self.wait_s = done - t0
+        for _out, nbytes, issued in self._inflight:
+            FLOW_LEDGER.record_transfer(
+                "staging-transfer", "staging", nbytes, done - issued,
+                pages=1, src="host", dst="device", direction="send",
+                status="page")
+        count_charged("stagingPuts", self.count)
+        self._inflight = []
 
 
 def row_bucket(rows: int) -> int:
@@ -436,58 +418,96 @@ def row_bucket(rows: int) -> int:
     return -(-rows // granule) * granule
 
 
-def pad_to_row_bucket(column_types, host_cols):
-    """``(host_cols padded with zero rows to row_bucket, live rows)``, or
-    ``(host_cols, None)`` where nothing is padded: an empty scan, a length
-    that is its own bucket, or a nested / two-limb column (their child
-    layout is recursive)."""
-    if not host_cols or any(typ.is_nested or cd.hi is not None
-                            for typ, cd in zip(column_types, host_cols)):
-        return host_cols, None
-    rows = len(host_cols[0].values)
-    extra = row_bucket(rows) - rows
-    if not extra:
-        return host_cols, None
-
-    def pad(arr):
-        arr = np.asarray(arr)
-        return np.concatenate([arr, np.zeros(extra, arr.dtype)])
-
-    return [dataclasses.replace(
-        cd, values=pad(cd.values),
-        nulls=None if cd.nulls is None else pad(cd.nulls))
-        for cd in host_cols], rows
+def _one_pass(arr: np.ndarray, rows: int, dtype) -> np.ndarray:
+    """``arr`` as ``rows`` rows of ``dtype``: ``arr`` itself where it
+    already is, else ONE host pass into a fresh array (an unsafe cast: the
+    caller proved the values fit) with a zeroed tail."""
+    if arr.dtype == dtype and len(arr) == rows:
+        return arr
+    out = np.empty(rows, dtype)
+    np.copyto(out[:len(arr)], arr, casting="unsafe")
+    out[len(arr):] = 0
+    return out
 
 
-def page_from_host_columns(column_types, host_cols, transfer):
-    """Host ColumnData list -> device Page: physical int32 narrowing for
-    provably-fitting int64 columns (table-wide vrange, the
+def _live_mask(rows: int, live: int) -> np.ndarray:
+    mask = np.zeros(rows, bool)
+    mask[:live] = True
+    return mask
+
+
+def _put_column(cd, put):
+    """A nested / two-limb ColumnData as a device Column, every array put
+    as it is (their child layout is recursive: no narrowing, no pad)."""
+    from trino_tpu.data.page import Column
+
+    return Column(
+        cd.type,
+        put(np.asarray(cd.values)),
+        put(np.asarray(cd.nulls)) if cd.nulls is not None else None,
+        cd.dictionary,
+        cd.vrange,
+        ascending=bool(getattr(cd, "sorted", False)),
+        children=([_put_column(k, put) for k in cd.children]
+                  if cd.children is not None else None),
+        hi=put(np.asarray(cd.hi)) if cd.hi is not None else None,
+    )
+
+
+def put_page(column_types, host_cols, bucket_rows: bool = False,
+             width: int = 1) -> Tuple[object, PagePuts]:
+    """Host ColumnData list -> device Page, each array made ready in ONE
+    host pass and put ONCE, one wait for the page. A provably-fitting
+    int64 column is narrowed to int32 (table-wide vrange, the
     data/page.py rule: table-wide ranges keep every split and shard
-    dtype-uniform), then the injected transfer per array.
-    Nested and two-limb columns take the single-shot path (their
-    children/limb layout is recursive)."""
+    dtype-uniform). With ``bucket_rows`` (the worker tier, which compiles
+    each operator program once per shape) the page is ``row_bucket`` rows
+    long, the pad zeros and a dead tail under a ``live_prefix`` mask, as
+    ``compact_to`` leaves one; a page holding a nested or two-limb column
+    is not padded. The host arrays are made on the shared staging pool,
+    ``width`` at a time (numpy's copies release the interpreter lock).
+    Returns ``(Page, PagePuts)``; ``host_cols`` None is the all-dead
+    page."""
     from trino_tpu.data.page import Column, Page, fits_int32
-    from trino_tpu.exec.executor import _column_from_data
 
-    if host_cols is None:
-        return Page.all_dead(column_types)
-    cols = []
-    for typ, cd in zip(column_types, host_cols):
-        if typ.is_nested or cd.hi is not None:
-            cols.append(_column_from_data(cd))
-            continue
-        vals = np.asarray(cd.values)
-        if vals.dtype == np.int64 and fits_int32(cd.vrange):
-            vals = vals.astype(np.int32)
-        cols.append(Column(
-            typ,
-            transfer(vals),
-            transfer(np.asarray(cd.nulls)) if cd.nulls is not None else None,
-            cd.dictionary,
-            cd.vrange,
-            ascending=bool(getattr(cd, "sorted", False)),
-        ))
-    return Page(cols)
+    with PagePuts() as puts:
+        if host_cols is None:
+            return Page.all_dead(column_types), puts
+        flat = [not (typ.is_nested or cd.hi is not None)
+                for typ, cd in zip(column_types, host_cols)]
+        live = len(host_cols[0].values)
+        rows = row_bucket(live) if bucket_rows and all(flat) else live
+        jobs = []
+        for is_flat, cd in zip(flat, host_cols):
+            if not is_flat:
+                continue
+            vals = np.asarray(cd.values)
+            dtype = (np.int32 if vals.dtype == np.int64
+                     and fits_int32(cd.vrange) else vals.dtype)
+            jobs.append(functools.partial(_one_pass, vals, rows, dtype))
+            if cd.nulls is not None:
+                nulls = np.asarray(cd.nulls)
+                jobs.append(functools.partial(
+                    _one_pass, nulls, rows, nulls.dtype))
+        if rows != live:
+            jobs.append(functools.partial(_live_mask, rows, live))
+        t0 = time.perf_counter()
+        host = _map_ordered(lambda i: jobs[i](), len(jobs), width)
+        puts.host_s = time.perf_counter() - t0
+        arrays = iter([puts.put(a) for a in host])
+        cols = []
+        for is_flat, typ, cd in zip(flat, column_types, host_cols):
+            if not is_flat:
+                cols.append(_put_column(cd, puts.put))
+                continue
+            cols.append(Column(
+                typ, next(arrays),
+                next(arrays) if cd.nulls is not None else None,
+                cd.dictionary, cd.vrange,
+                ascending=bool(getattr(cd, "sorted", False))))
+        page = (Page(cols, next(arrays), live_prefix=True) if rows != live
+                else Page(cols))
+    return page, puts
 
 
 def staged_scan_page(session, node, conn, splits, constraint,
@@ -496,11 +516,11 @@ def staged_scan_page(session, node, conn, splits, constraint,
                      bucket_rows: bool = False,
                      ) -> Tuple[object, int, StageProfile]:
     """The whole pipeline for one scan: parallel split reads (host tier
-    consulted per split) -> host assembly -> double-buffered transfer.
+    consulted per split) -> host assembly -> ``put_page``.
     Returns ``(Page, scanned_rows, StageProfile)``. This is the loader
     body behind every device-cache miss in the eager/compiled and worker
-    tiers (the SPMD tier shares stage_splits + blocked_transfer but owns
-    its shard stacking). ``bucket_rows`` (the worker tier, which compiles
+    tiers (the SPMD tier shares stage_splits + PagePuts but owns its
+    shard stacking). ``bucket_rows`` (the worker tier, which compiles
     each operator program once per shape) stages at ``row_bucket`` rows."""
     datas, prof = stage_splits(session, node, conn, splits, constraint,
                                prune=prune, applied_domains=applied_domains)
@@ -515,18 +535,14 @@ def staged_scan_page(session, node, conn, splits, constraint,
     M.STAGING_PHASE_SECONDS.inc(prof.decode_wall_s, "decode")
     t0 = time.perf_counter()
     with tracing.span("staging/transfer", table=node.table) as sp:
-        from trino_tpu.data.page import Page
-
-        live = None
-        if bucket_rows:
-            host_cols, live = pad_to_row_bucket(node.column_types, host_cols)
-        transfer = blocked_transfer(prof)
-        page = page_from_host_columns(node.column_types, host_cols, transfer)
-        if live is not None:  # the pad is a dead tail, as compact_to leaves
-            page = Page(page.columns,
-                        transfer(np.arange(page.num_rows) < live),
-                        live_prefix=True)
+        page, puts = put_page(node.column_types, host_cols,
+                              bucket_rows=bucket_rows,
+                              width=prof.parallelism)
         prof.transfer_wall_s = time.perf_counter() - t0
-        sp.set("blocks", prof.transfer_blocks)
+        sp.set("host_s", round(puts.host_s, 6))
+        sp.set("put_s", round(puts.put_s, 6))
+        sp.set("wait_s", round(puts.wait_s, 6))
+        sp.set("puts", puts.count)
+        sp.set("bytes", puts.nbytes)
     M.STAGING_PHASE_SECONDS.inc(prof.transfer_wall_s, "transfer")
     return page, scanned, prof

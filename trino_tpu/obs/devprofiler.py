@@ -64,7 +64,9 @@ cache did for it (devcache/keys.py ``cached_stage``): ``cacheHits`` /
 the bytes it copied host -> device (0 on a hit), and ``cacheBypasses``:
 scans the cache was on for and did not keep, because the staged table is
 over the admission cap (``device_cache_max_bytes``, the pool's budget)
-or no key could be made for it. A Join's row counts ``joinProbeSlots``
+or no key could be made for it, and ``stagingPuts``: the host -> device
+puts a fresh staging issued (exec/staging.py ``PagePuts``: one an array
+of the page; 0 on a hit). A Join's row counts ``joinProbeSlots``
 and ``joinBuildSlots``: the row capacities (static shapes, no read) of
 the probe and build pages of every execution, the work its place in the
 join ORDER makes it carry whatever the kernels do.
@@ -122,7 +124,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "cacheHits": 0, "cacheMisses": 0, "stagedBytes": 0,
            "prefixCompactions": 0, "compactedJoins": 0,
            "colocatedAggs": 0, "exchangedRows": 0, "outputFetches": 0,
-           "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0}
+           "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0,
+           "stagingPuts": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -160,7 +163,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "cacheHits", "cacheMisses", "stagedBytes",
                       "prefixCompactions", "compactedJoins",
                       "colocatedAggs", "exchangedRows", "outputFetches",
-                      "joinProbeSlots", "joinBuildSlots", "cacheBypasses"):
+                      "joinProbeSlots", "joinBuildSlots", "cacheBypasses",
+                      "stagingPuts"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

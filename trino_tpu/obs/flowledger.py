@@ -60,8 +60,8 @@ LINK_CLASSES = (
                           # storage by this process's segment store
     "segment-fetch",      # segment bytes served to a client (full GET
                           # or a Range slice)
-    "staging-transfer",   # host->device DMA blocks issued by the
-                          # staging pipeline's blocked_transfer
+    "staging-transfer",   # host->device puts issued by the staging
+                          # pipeline's PagePuts, one record a put
     "client-drain",       # statement-protocol result bytes serialized
                           # to a draining client
     "control",            # cluster-internal JSON control calls

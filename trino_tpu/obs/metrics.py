@@ -523,8 +523,9 @@ STAGING_PHASE_SECONDS = REGISTRY.counter(
     "trino_tpu_staging_phase_seconds_total",
     "staging pipeline wall seconds by sub-phase: scan (parallel split "
     "read+decode fan-out), decode (host assembly: concat + dictionary "
-    "merge + physical narrowing), transfer (double-buffered host->device "
-    "blocks), host-cache (host-tier probe)", ("phase",))
+    "merge), transfer (one host pass an array: narrowing + pad, one "
+    "host->device put an array, one wait a page), host-cache (host-tier "
+    "probe)", ("phase",))
 # fused sort-merge join tier (ops/fused_join.py): kernel selections per
 # join execution, labeled by the tier the cost gate chose
 FUSED_JOIN_SELECTIONS = REGISTRY.counter(

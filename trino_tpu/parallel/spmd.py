@@ -514,19 +514,21 @@ def stage_sharded_scans(session, root: P.OutputNode, n_devices: int,
                 session, node, n_devices, constraint, dyn_domains, profile)
             # cache-resident arrays live ON DEVICE: transfer here (a
             # no-op for already-device arrays), so a warm hit hands back
-            # HBM-resident shards with zero host work. The stacked
-            # [ndev, rows] shard arrays move in double-buffered blocks
-            # along the rows axis (exec/staging.blocked_transfer).
+            # HBM-resident shards with zero host work. Each stacked
+            # [ndev, rows] shard array is put once, already sharded along
+            # the mesh axis where there is a mesh, and the scan waits once
+            # for all of them (exec/staging.PagePuts).
             t0 = _time.perf_counter()
             with _tracing.span("staging/transfer", table=node.table) as sp:
-                if mesh is not None:
-                    arrays = [jax.device_put(a, _mesh_sharding(mesh))
+                sharding = _mesh_sharding(mesh) if mesh is not None else None
+                with _staging.PagePuts() as puts:
+                    arrays = [puts.put(a, sharding)
+                              if isinstance(a, np.ndarray)
+                              else jax.device_put(a, sharding)
                               for a in arrays]
-                else:
-                    xfer = _staging.blocked_transfer()
-                    arrays = [xfer(a) if isinstance(a, np.ndarray)
-                              else jnp.asarray(a) for a in arrays]
                 sp.set("arrays", len(arrays))
+                sp.set("puts", puts.count)
+                sp.set("bytes", puts.nbytes)
             _M.STAGING_PHASE_SECONDS.inc(_time.perf_counter() - t0,
                                          "transfer")
             nbytes = sum(int(a.size) * a.dtype.itemsize for a in arrays)
@@ -614,7 +616,7 @@ def _stage_scan_shards(session, node, n_devices: int, constraint,
         for name, typ in zip(node.column_names, node.column_types):
             cd = data[name]
             vals = np.asarray(cd.values)
-            # physical narrowing, same rule as staging.page_from_host_columns:
+            # physical narrowing, same rule as staging.put_page:
             # table-wide ranges keep every shard dtype-uniform
             if vals.dtype == np.int64 and page_mod.fits_int32(cd.vrange):
                 vals = vals.astype(np.int32)

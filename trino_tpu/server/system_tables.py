@@ -326,6 +326,7 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("joinProbeSlots", 0)),
             int(r.get("joinBuildSlots", 0)),
             int(r.get("cacheBypasses", 0)),
+            int(r.get("stagingPuts", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

@@ -101,8 +101,8 @@ class FragmentExecutor(Executor):
         def load():
             # the pipelined engine (exec/staging.py): the task's assigned
             # splits scan in parallel on the shared pool, each consulting
-            # the host-RAM tier, and the assembled columns transfer in
-            # double-buffered blocks. STAGING_SECONDS keeps its worker
+            # the host-RAM tier, and the assembled columns cross in one
+            # put an array, one wait a page. STAGING_SECONDS keeps its worker
             # semantics: the whole fresh scan+assemble+transfer wall
             # (device-cache hits never reach this loader).
             t0 = time.perf_counter()
