@@ -252,6 +252,11 @@ SYSTEM_TABLES = {
         # the bytes of the frames a task handed to its output buffer, on
         # the row of its fragment's root operator, beside exchanged_rows
         ("exchanged_bytes", "bigint"),
+        # eager-tier Filter predicates / Project expression lists that ran
+        # as ONE compiled program (exec/executor.py expression_program) /
+        # that dispatched their primitives one by one
+        ("expr_programs", "bigint"),
+        ("expr_eager", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
+import threading
 import time
 import types
 from typing import Dict, List, Optional, Tuple
@@ -27,11 +29,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu import types as T
+from trino_tpu.cache.determinism import NONDETERMINISTIC_FUNCTIONS
+from trino_tpu.data.dictionary import Dictionary
 from trino_tpu.data.page import Column, Page
 from trino_tpu.exec import memory as _mem
 from trino_tpu.exec.operator_stats import OperatorStats
 from trino_tpu.exec.page_tree import (
-    StaticSpec, attach_dictionaries, flatten_page, static_spec,
+    StaticSpec, WordsRead, attach_dictionaries, flatten_page, static_spec,
     unflatten_page,
 )
 from trino_tpu.obs import metrics as M
@@ -40,6 +44,7 @@ from trino_tpu.obs.devprofiler import (
     charge_to, count_charged, host_read, host_read_all, merge_platforms,
     new_kernel_row)
 from trino_tpu.ops import aggregate as agg_ops
+from trino_tpu.ops import expr_key
 from trino_tpu.ops import expr_lower as L
 from trino_tpu.ops import fused_join as fused_ops
 from trino_tpu.ops import groupby as gb
@@ -50,6 +55,8 @@ from trino_tpu.ops import segments as seg
 from trino_tpu.ops import sort as sort_ops
 from trino_tpu.sql import ir
 from trino_tpu.sql.planner import plan as P
+
+_log = logging.getLogger(__name__)
 
 
 class QueryError(RuntimeError):
@@ -566,10 +573,12 @@ class Executor:
     # --------------------------------------------------------------- filter
     def _exec_FilterNode(self, node: P.FilterNode) -> Page:
         page = self.execute(node.source)
+        fused = self._as_expression_program("filter", (node.predicate,), page)
+        if fused is not None:
+            (sel,) = fused
+            return Page(page.columns, sel, page.replicated)
         lv = self._lower(node.predicate, page)
-        passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
-        sel = passed if page.sel is None else (page.sel & passed)
-        return Page(page.columns, sel, page.replicated)
+        return Page(page.columns, _filtered_sel(page.sel, lv), page.replicated)
 
     def _exec_CompactNode(self, node: P.CompactNode) -> Page:
         """Squeeze live rows into a smaller static-capacity page
@@ -671,17 +680,44 @@ class Executor:
 
     def _exec_ProjectNode(self, node: P.ProjectNode) -> Page:
         page = self.execute(node.source)
-        cols = []
-        for e in node.expressions:
-            if isinstance(e, ir.ColumnRef):
-                # pass-through: reuse the column wholesale (keeps vrange,
-                # dictionary, and sort-order metadata; skips re-lowering)
-                cols.append(page.columns[e.index])
-                continue
-            lv = self._lower(e, page)
-            cols.append(_col_from_lowered(e.type, lv))
+        # pass-through: reuse the column wholesale (keeps vrange,
+        # dictionary, and sort-order metadata; skips re-lowering)
+        computed = tuple(e for e in node.expressions
+                         if not isinstance(e, ir.ColumnRef))
+        fused = (self._as_expression_program("project", computed, page)
+                 if computed else None)
+        if fused is None:
+            fused = [_col_from_lowered(e.type, self._lower(e, page))
+                     for e in computed]
+        fused = iter(fused)
+        cols = [page.columns[e.index] if isinstance(e, ir.ColumnRef)
+                else next(fused) for e in node.expressions]
         return Page(cols, page.sel, page.replicated,
                     live_prefix=page.live_prefix)
+
+    def _as_expression_program(self, kind: str, exprs, page: Page):
+        """A Filter's predicate (``kind`` "filter": the new selection) or a
+        Project's computed expressions ("project": their columns) over
+        ``page`` as ONE compiled program (``expression_program``), in
+        place of the lowering's primitives dispatched one by one; None
+        where the lowering has to run as it stands: a traced tier (already
+        inside a program), nested input columns, a non-deterministic call,
+        arithmetic the lowering keeps out of programs (``expr_key.Unfused``:
+        int64 calendar math over a column, int128 long division), or a
+        lowering that cannot be traced (it makes a dictionary out of the
+        page's words, or reads a value it cannot be handed). The executing
+        operator's kernel row counts which (``exprPrograms`` /
+        ``exprEager``)."""
+        out = None
+        if self.eager_tier:
+            out = _run_expression_program(kind, exprs, page)
+        if out is None:
+            count_charged("exprEager")
+            return None
+        count_charged("exprPrograms")
+        result, errors = out
+        self.errors.extend(errors)
+        return result
 
     # -------------------------------------------------------------- unnest
     def _exec_UnnestNode(self, node: P.UnnestNode) -> Page:
@@ -2652,6 +2688,398 @@ def direct_aggregation(spec: _AggregationSpec, arrays):
     return (out_arrays,
             static_spec(out_spec, tuple(code for code, _ in body.errors)),
             [flag for _, flag in body.errors])
+
+
+def _filtered_sel(sel, lv: L.LoweredVal):
+    """A page's selection AND a lowered predicate (NULL is not TRUE)."""
+    passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
+    return passed if sel is None else (sel & passed)
+
+
+@dataclasses.dataclass(frozen=True)
+class _ExpressionSpec:
+    """The static side of ``expression_program``. Its expressions read the
+    channels of the page the program is handed (the referenced ones,
+    renumbered) and hold an ``expr_key.Literal`` for every literal the
+    lowering only computes with and every column-free subtree folded on the
+    host: the values are arguments, so bindings share a program.
+    Of the page's value ranges it holds which channels have one
+    (``bounded``) and the ANSWERS the lowering asked of those bounds and
+    of the literals' (``expr_key``): the int64 or two-limb choice, not the
+    range. A table the lowering builds from a dictionary's words is an
+    argument too; ``tables`` names them."""
+
+    kind: str  # "filter" | "project"
+    expressions: Tuple[ir.Expr, ...]
+    page: StaticSpec
+    bounded: Tuple[int, ...]
+    answers: Tuple[Tuple[tuple, object], ...]
+    tables: Tuple[tuple, ...]
+
+    def __post_init__(self):
+        # jit hashes its static argument on every call: once here, not
+        # over the expression trees a page
+        object.__setattr__(self, "_hash", hash((
+            self.kind, self.expressions, self.page, self.bounded,
+            self.answers, self.tables)))
+
+    def __hash__(self):
+        return self._hash
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def expression_program(spec: _ExpressionSpec, arrays, literals, tables):
+    """The lowering of ``spec.expressions`` over one page as one XLA
+    program: (a filter's new selection, or a project's columns' arrays;
+    their StaticSpec, whose notes are the deferred errors' codes and each
+    column's bound term; the errors' flags)."""
+    page = unflatten_page(spec.page.page_spec(), arrays)
+    for c in spec.bounded:
+        page.columns[c].vrange = expr_key.Sym(("col", c))
+    ctx = L.LowerCtx(page.columns, page.num_rows, page.sel, tuple(literals))
+    with expr_key.answering(dict(spec.answers),
+                            dict(zip(spec.tables, tables))):
+        lowered = [L.lower(e, ctx) for e in spec.expressions]
+    codes = tuple(code for code, _ in ctx.errors)
+    flags = [flag for _, flag in ctx.errors]
+    if spec.kind == "filter":
+        (lv,) = lowered
+        return ([_filtered_sel(page.sel, lv)],
+                StaticSpec((), False, (codes, ())), flags)
+    out_arrays, columns, bounds = [], [], []
+    for e, lv in zip(spec.expressions, lowered):
+        col = _col_from_lowered(e.type, lv)
+        if col.children is not None:
+            raise NotImplementedError("a nested column leaves the program")
+        columns.append((col.type, col.nulls is not None, col.hi is not None,
+                        col.dictionary))
+        out_arrays += [a for a in (col.values, col.nulls, col.hi)
+                       if a is not None]
+        bounds.append(None if col.vrange is None
+                      else expr_key.term_of(col.vrange[1]))
+    return (out_arrays, StaticSpec(tuple(columns), False,
+                                   (codes, tuple(bounds))), flags)
+
+
+class _NotAProgram(Exception):
+    """An expression the seam does not hand to a program."""
+
+
+@dataclasses.dataclass
+class _Learnt:
+    """What the traces of one expression list over one page spec taught:
+    the literals the lowering reads on the host (kept in the expression as
+    they are), the tree of questions asked of the bounds (each node: the
+    question asked there and the node each answer leads to), the tables it
+    builds from dictionaries' words (question -> (function, the
+    dictionaries it reads, stand-ins for the page's among them)), and
+    whether the lowering cannot be traced at all. Shared by every executor
+    of the process, like the programs themselves, under ``_LEARNT_LOCK``."""
+
+    held: set = dataclasses.field(default_factory=set)
+    questions: dict = dataclasses.field(default_factory=dict)
+    tables: dict = dataclasses.field(default_factory=dict)
+    declined: bool = False
+    # (a path of answers, the held literals) -> the spec they make with
+    # the tables built now
+    specs: dict = dataclasses.field(default_factory=dict)
+
+
+@dataclasses.dataclass(eq=False)
+class _Listed:
+    """An expression list as its programs see it: the channels it reads,
+    the canonical expressions (``_canonical``, hashed once) and their
+    arguments."""
+
+    channels: List[int]
+    canon: Tuple[ir.Expr, ...]
+    args: list
+    canon_hash: int = dataclasses.field(init=False)
+
+    def __post_init__(self):
+        self.canon_hash = hash(self.canon)
+
+    def __hash__(self):
+        return self.canon_hash
+
+    def __eq__(self, other):
+        return self is other or (isinstance(other, _Listed)
+                                 and self.canon_hash == other.canon_hash
+                                 and self.canon == other.canon)
+
+
+_LEARNT: Dict[tuple, _Learnt] = {}
+# (kind, ids of a plan's expression objects) -> (the objects, their
+# _Listed or None): a plan's expressions serve every page of a statement,
+# and a cached plan every run of it
+_LISTED: Dict[tuple, tuple] = {}
+_LEARNT_LOCK = threading.Lock()
+_LEARNT_MAX = 4096
+_TRACES_MAX = 32  # traces one call may make to learn its answers
+
+
+@dataclasses.dataclass(frozen=True)
+class _Arg:
+    """A scalar argument of a program: a literal, or a column-free subtree
+    folded on the host (``_folded``). ``expr`` is what its stand-in
+    replaced, put back where the lowering reads the value on the host."""
+
+    expr: ir.Expr
+    value: np.ndarray  # 0-d, the dtype the eager lowering gives it
+    bound: Optional[int]
+    errors: Tuple[str, ...] = ()  # a folded subtree's deferred errors
+
+
+def _canonical(e: ir.Expr, remap: dict, args: list) -> ir.Expr:
+    """``e`` over the referenced page's channels, every literal the
+    lowering may compute with, and every column-free subtree that folds,
+    an ``expr_key.Literal`` (its argument appended to ``args``)."""
+    if isinstance(e, ir.ColumnRef):
+        return ir.ColumnRef(e.type, remap[e.index])
+    arg = None
+    if isinstance(e, ir.Constant):
+        if not _computed_literal(e):
+            return e
+        arg = _Arg(e, np.asarray(e.value, dtype=e.type.np_dtype),
+                   abs(int(e.value)) if L.has_bound(e.type) else None)
+    elif isinstance(e, ir.Call) and e.name in NONDETERMINISTIC_FUNCTIONS:
+        raise _NotAProgram(e.name)
+    elif _exact_anywhere(e):
+        arg = _folded(e)
+    if arg is not None:
+        args.append(arg)
+        return ir.Constant(e.type, expr_key.Literal(
+            len(args) - 1, arg.bound is not None, arg.errors))
+    if isinstance(e, ir.Call):
+        return ir.Call(e.type, e.name,
+                       tuple(_canonical(a, remap, args) for a in e.args))
+    if isinstance(e, ir.Case):
+        return ir.Case(
+            e.type,
+            tuple((_canonical(c, remap, args),
+                   _canonical(v, remap, args)) for c, v in e.whens),
+            None if e.default is None
+            else _canonical(e.default, remap, args))
+    if isinstance(e, ir.Cast):
+        return ir.Cast(e.type, _canonical(e.value, remap, args))
+    raise _NotAProgram(type(e).__name__)  # lambdas, parameters
+
+
+def _computed_literal(e: ir.Constant) -> bool:
+    """Whether the lowering may take ``e``'s value as a scalar argument:
+    a non-NULL number, date, timestamp or boolean its dtype holds (a
+    varchar is a dictionary; a call that reads the value on the host is
+    found by tracing, ``expr_key.HostRead``)."""
+    t, v = e.type, e.value
+    if (v is None or t.is_varchar or t.is_nested or t.np_dtype is None
+            or not isinstance(v, (bool, int, float, np.generic))):
+        return False
+    dtype = np.dtype(t.np_dtype)
+    if dtype.kind in "iu":
+        info = np.iinfo(dtype)
+        return (not isinstance(v, (float, np.floating))
+                and info.min <= int(v) <= info.max)
+    return dtype.kind in "fb"
+
+
+def _exact_anywhere(e: ir.Expr) -> bool:
+    """Whether ``e`` is a call over literals alone whose value is the same
+    on any device: no column, no floating type (an accelerator's float64
+    rounds as the host's does not), nothing that reads a dictionary."""
+    if not isinstance(e, (ir.Call, ir.Cast, ir.Case)):
+        return False
+    for x in ir.walk(e):
+        t = x.type
+        if (isinstance(x, (ir.ColumnRef, ir.Lambda, ir.Parameter, ir.OuterRef))
+                or t.is_floating or t.is_varchar or t.is_nested
+                or t.np_dtype is None
+                or (isinstance(x, ir.Call)
+                    and x.name in NONDETERMINISTIC_FUNCTIONS)):
+            return False
+    return True
+
+
+_FOLDED: Dict[ir.Expr, Optional[_Arg]] = {}
+
+
+def _folded(e: ir.Expr) -> Optional[_Arg]:
+    """``e``, a column-free subtree, lowered once on the host's CPU device
+    (memoised by its literals' values): the same lowering, so the same
+    value, dtype, bound and deferred errors as on every row of a page,
+    without compiling its primitives into the program (date arithmetic on
+    int64, which the TPU compiler takes many seconds over) or running them
+    over every row. None where it does not fold exactly: a NULL, two-limb
+    or nested result, an error that fires, or no CPU device."""
+    with _LEARNT_LOCK:
+        if e in _FOLDED:
+            return _FOLDED[e]
+    arg = None
+    try:
+        cpu = jax.local_devices(backend="cpu")[0]
+        with jax.default_device(cpu), jax.transfer_guard("allow"):
+            ctx = L.LowerCtx([], 1)
+            lv = L.lower(e, ctx)
+            fired = any(bool(np.asarray(flag)) for _, flag in ctx.errors)
+            if not (fired or lv.valid is not None or lv.hi is not None
+                    or lv.dictionary is not None
+                    or lv.children is not None):
+                arg = _Arg(e, np.asarray(lv.vals)[0], lv.bound,
+                           tuple(code for code, _ in ctx.errors))
+    except Exception:  # noqa: BLE001 — not folded: the program lowers it
+        arg = None
+    with _LEARNT_LOCK:
+        if len(_FOLDED) >= _LEARNT_MAX:
+            _FOLDED.clear()
+        _FOLDED[e] = arg
+    return arg
+
+
+def _holding(e: ir.Expr, held: set, args: list) -> ir.Expr:
+    """``e`` with the stand-ins of the ``held`` arguments put back."""
+    if not held:
+        return e
+    if isinstance(e, ir.Constant):
+        if isinstance(e.value, expr_key.Literal) and e.value.index in held:
+            return args[e.value.index].expr
+        return e
+    if isinstance(e, ir.Call):
+        return ir.Call(e.type, e.name,
+                       tuple(_holding(a, held, args) for a in e.args))
+    if isinstance(e, ir.Case):
+        return ir.Case(
+            e.type,
+            tuple((_holding(c, held, args), _holding(v, held, args))
+                  for c, v in e.whens),
+            None if e.default is None
+            else _holding(e.default, held, args))
+    if isinstance(e, ir.Cast):
+        return ir.Cast(e.type, _holding(e.value, held, args))
+    return e
+
+
+def _listed(kind: str, exprs) -> Optional[_Listed]:
+    """``exprs`` as their programs see them, made once for the plan's
+    expression objects; None where the seam does not take them."""
+    key = (kind,) + tuple(id(e) for e in exprs)
+    with _LEARNT_LOCK:
+        hit = _LISTED.get(key)
+    if hit is not None and all(a is b for a, b in zip(hit[0], exprs)):
+        return hit[1]
+    channels = sorted({c for e in exprs for c in ir.referenced_channels(e)})
+    channels = channels or [0]  # the page's row count
+    args: list = []
+    try:
+        remap = {c: i for i, c in enumerate(channels)}
+        listed = _Listed(channels,
+                         tuple(_canonical(e, remap, args) for e in exprs),
+                         args)
+    except _NotAProgram:
+        listed = None
+    with _LEARNT_LOCK:
+        if len(_LISTED) >= _LEARNT_MAX:
+            _LISTED.clear()
+        _LISTED[key] = (tuple(exprs), listed)
+    return listed
+
+
+def _run_expression_program(kind: str, exprs, page: Page):
+    """((the filter's selection,) or the project's columns, the deferred
+    errors) from ONE program, or None where the seam declines."""
+    listed = _listed(kind, exprs)
+    if listed is None or not page.columns:
+        return None
+    channels, canon, args = listed.channels, listed.canon, listed.args
+    source = Page([page.columns[c] for c in channels], page.sel,
+                  page.replicated)
+    arrays, page_spec = flatten_page(source)
+    columns = static_spec(page_spec)
+    if columns is None:  # nested columns
+        return None
+    bounded = tuple(
+        c for c, col in enumerate(source.columns)
+        if col.vrange is not None
+        and not jnp.issubdtype(col.values.dtype, jnp.floating))
+    skeleton = (kind, listed, columns, bounded)
+    with _LEARNT_LOCK:
+        learnt = _LEARNT.get(skeleton)
+        if learnt is None:
+            if len(_LEARNT) >= _LEARNT_MAX:
+                _LEARNT.clear()
+            learnt = _LEARNT[skeleton] = _Learnt()
+    env = {("col", c): expr_key.vrange_bound(source.columns[c].vrange)
+           for c in bounded}
+    env.update((("lit", i), arg.bound) for i, arg in enumerate(args)
+               if arg.bound is not None)
+    values = [arg.value for arg in args]
+    for _ in range(_TRACES_MAX):
+        with _LEARNT_LOCK:
+            if learnt.declined:
+                return None
+            node, answers = learnt.questions, []
+            while "question" in node:
+                a = expr_key.answer(node["question"], env)
+                answers.append((node["question"], a))
+                node = node["next"].setdefault(a, {})
+            held, builds = set(learnt.held), dict(learnt.tables)
+            # a held literal's value is in the spec's expressions
+            made = (tuple(answers), tuple(args[i].expr for i in sorted(held)))
+            spec = learnt.specs.get(made)
+        if spec is None:
+            spec = _ExpressionSpec(
+                kind, tuple(_holding(e, held, args) for e in canon),
+                columns, bounded, made[0], tuple(builds))
+        tables = [
+            np.asarray(build(*(d if isinstance(d, Dictionary)
+                               else source.columns[d.channel].dictionary
+                               for d in dictionaries)))
+            for build, dictionaries in builds.values()]
+        try:
+            out_arrays, out_spec, flags = expression_program(
+                spec, arrays, values, tables)
+        except expr_key.Unanswered as asked:
+            with _LEARNT_LOCK:
+                if asked.build is not None:
+                    learnt.tables[asked.question] = asked.build
+                    learnt.specs = {}
+                elif "question" not in node:
+                    node["question"], node["next"] = asked.question, {}
+            continue
+        except expr_key.HostRead as read:
+            with _LEARNT_LOCK:
+                learnt.held.add(read.index)
+                learnt.questions, learnt.specs = {}, {}
+            continue
+        except jax.errors.JaxRuntimeError:
+            raise  # the device failed the work: not the seam's to hide
+        except (expr_key.Unfused, WordsRead, NotImplementedError):
+            # arithmetic kept out of programs, a dictionary's words read,
+            # or what the lowering (and so the eager body) does not do
+            with _LEARNT_LOCK:
+                learnt.declined = True
+            return None
+        except Exception:  # noqa: BLE001 — unforeseen: said once, then eager
+            _log.warning("the %s of %r runs eagerly: its program failed to "
+                         "trace", kind, canon, exc_info=True)
+            with _LEARNT_LOCK:
+                learnt.declined = True
+            return None
+        with _LEARNT_LOCK:
+            if held == learnt.held and spec.tables == tuple(learnt.tables):
+                learnt.specs[made] = spec
+        codes, bounds = out_spec.notes
+        errors = list(zip(codes, flags))
+        if kind == "filter":
+            return out_arrays, errors
+        out = attach_dictionaries(
+            unflatten_page(out_spec.page_spec(), out_arrays), source)
+        for col, term in zip(out.columns, bounds):
+            if term is not None:
+                b = expr_key.evaluate(term, env)
+                col.vrange = (-b, b)
+        return out.columns, errors
+    with _LEARNT_LOCK:
+        learnt.declined = True  # a lowering that keeps asking
+    return None
 
 
 @dataclasses.dataclass

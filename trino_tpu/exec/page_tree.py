@@ -137,6 +137,19 @@ class InputDictionary:
     def __len__(self) -> int:
         return self.size
 
+    @property
+    def values(self):
+        raise WordsRead(self.channel)
+
+
+class WordsRead(AttributeError):
+    """A traced program read the words of a dictionary it holds only an
+    :class:`InputDictionary` for: what it computes from them has to run
+    untraced, or be handed to it as an argument."""
+
+    def __init__(self, channel: int):
+        super().__init__(f"the words of channel {channel}'s dictionary")
+
 
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)

@@ -39,7 +39,12 @@ the row of the operator that is executing on the thread
 (:func:`charge_to`). So do the aggregation bodies of the eager tier
 (:func:`count_charged`): ``aggPrograms`` ran as one compiled program
 (exec/executor.py ``direct_aggregation``), ``aggEager`` dispatched their
-primitives one by one, and ``prefixCompactions``, the pages
+primitives one by one; so do a Filter's predicate and a Project's
+computed expressions: ``exprPrograms`` ran as one compiled program a page
+(exec/executor.py ``expression_program``), ``exprEager`` were lowered
+primitive by primitive (a traced tier, nested input columns, a
+non-deterministic call, a lowering that reads a dictionary's content);
+and ``prefixCompactions``, the pages
 ``Executor.compact_to`` squeezed to their live rows (positions from
 prefix counts, ops/ranks.py ``true_positions``; a page it returns as it
 came counts nothing). A Join's row counts ``compactedJoins``: lookup
@@ -126,7 +131,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "prefixCompactions": 0, "compactedJoins": 0,
            "colocatedAggs": 0, "exchangedRows": 0, "outputFetches": 0,
            "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0,
-           "stagingPuts": 0, "exchangedBytes": 0}
+           "stagingPuts": 0, "exchangedBytes": 0,
+           "exprPrograms": 0, "exprEager": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -165,7 +171,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "prefixCompactions", "compactedJoins",
                       "colocatedAggs", "exchangedRows", "outputFetches",
                       "joinProbeSlots", "joinBuildSlots", "cacheBypasses",
-                      "stagingPuts", "exchangedBytes"):
+                      "stagingPuts", "exchangedBytes", "exprPrograms",
+                      "exprEager"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

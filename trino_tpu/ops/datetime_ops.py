@@ -10,9 +10,12 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from trino_tpu.ops import expr_key
+
 
 def civil_from_days(days):
     """epoch days -> (year, month, day), elementwise (int32 arrays)."""
+    expr_key.eager_only("civil_from_days")
     z = days.astype(jnp.int64) + 719468
     era = jnp.floor_divide(z, 146097)
     doe = z - era * 146097  # [0, 146096]
@@ -28,6 +31,7 @@ def civil_from_days(days):
 
 def days_from_civil(y, m, d):
     """(year, month, day) -> epoch days, elementwise."""
+    expr_key.eager_only("days_from_civil")
     y = y.astype(jnp.int64) - (m <= 2)
     era = jnp.floor_divide(y, 400)
     yoe = y - era * 400  # [0, 399]

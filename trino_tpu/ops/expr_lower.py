@@ -32,6 +32,7 @@ from trino_tpu import types as T
 from trino_tpu.data.dictionary import NULL_CODE, Dictionary
 from trino_tpu.data.page import Column
 from trino_tpu.ops import datetime_ops as dt
+from trino_tpu.ops import expr_key
 from trino_tpu.sql import ir
 
 DIVISION_BY_ZERO = "DIVISION_BY_ZERO"
@@ -67,10 +68,15 @@ class LowerCtx:
     matching the reference's semantics where filtered-out rows are never
     evaluated."""
 
-    def __init__(self, columns: List[Column], num_rows: int, sel: Optional[jnp.ndarray] = None):
+    def __init__(self, columns: List[Column], num_rows: int, sel: Optional[jnp.ndarray] = None,
+                 literals: Tuple = ()):
         self.columns = columns
         self.num_rows = num_rows
         self.sel = sel
+        # the values of the expression's ops/expr_key.Literal stand-ins:
+        # the scalar arguments of a program (exec/executor.py
+        # expression_program)
+        self.literals = literals
         self.errors: List[Tuple[str, jnp.ndarray]] = []
 
     def add_error(self, code: str, cond: jnp.ndarray, live: Optional[jnp.ndarray]):
@@ -95,7 +101,7 @@ def lower(expr: ir.Expr, ctx: LowerCtx) -> LoweredVal:
         valid = None if col.nulls is None else ~col.nulls
         bound = None
         if col.vrange is not None and not jnp.issubdtype(col.values.dtype, jnp.floating):
-            bound = max(abs(int(col.vrange[0])), abs(int(col.vrange[1])))
+            bound = expr_key.vrange_bound(col.vrange)
         children = None
         if col.children is not None:
             children = [
@@ -139,10 +145,23 @@ def _lower_constant(expr: ir.Constant, ctx: LowerCtx) -> LoweredVal:
     if t.is_varchar:
         d = Dictionary([expr.value])
         return LoweredVal(_const_array(ctx, np.int32, 0), None, d)
-    bound = None
-    if not (t.is_floating or t == T.BOOLEAN):
-        bound = abs(int(expr.value))
+    if isinstance(expr.value, expr_key.Literal):
+        # a program's literal (or column-free subtree folded on the host):
+        # its value is a scalar argument, its bound a term the program's
+        # key answers questions of
+        lit = expr.value
+        value = ctx.literals[lit.index]
+        for code in lit.errors:  # the folded subtree's, none of which fired
+            ctx.errors.append((code, jnp.zeros((), dtype=bool)))
+        bound = expr_key.Sym(("lit", lit.index)) if lit.bounded else None
+        return LoweredVal(_const_array(ctx, value.dtype, value), None, None, bound)
+    bound = abs(int(expr.value)) if has_bound(t) else None
     return LoweredVal(_const_array(ctx, t.np_dtype, expr.value), None, None, bound)
+
+
+def has_bound(t: T.Type) -> bool:
+    """Whether a literal of type ``t`` carries its magnitude as its bound."""
+    return not (t.is_floating or t == T.BOOLEAN)
 
 
 # ---------------------------------------------------------------------------
@@ -151,18 +170,33 @@ def _lower_constant(expr: ir.Constant, ctx: LowerCtx) -> LoweredVal:
 # ---------------------------------------------------------------------------
 
 
+def _vocab_table(name: tuple, build, *dictionaries) -> jnp.ndarray:
+    """``build(*dictionaries)``, a numpy table over the dictionaries' words,
+    on the device. Inside a program (exec/executor.py expression_program) a
+    page's dictionary is a stand-in with a channel and a size and no words:
+    the table is then one of the program's arguments, asked for under
+    ``name`` (ops/expr_key.table) and built by its caller from the page."""
+    if all(isinstance(d, Dictionary) for d in dictionaries):
+        return jnp.asarray(build(*dictionaries))
+    return expr_key.table(name, build, dictionaries)
+
+
+def _union_recode(d: Dictionary, other: Dictionary) -> np.ndarray:
+    """``d``'s codes in the union of ``d``'s and ``other``'s words."""
+    t = np.asarray(d.recode_table(d.merge(other)))
+    return t if len(t) else np.array([NULL_CODE], np.int32)
+
+
 def _align_varchar(a: LoweredVal, b: LoweredVal) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    assert a.dictionary is not None and b.dictionary is not None
-    if a.dictionary is b.dictionary or a.dictionary.values == b.dictionary.values:
+    da, db = a.dictionary, b.dictionary
+    assert da is not None and db is not None
+    if da is db or (isinstance(da, Dictionary) and isinstance(db, Dictionary)
+                    and da.values == db.values):
         return a.vals, b.vals
-    merged = a.dictionary.merge(b.dictionary)
-
-    def recode(d):
-        t = np.asarray(d.recode_table(merged))
-        return jnp.asarray(t if len(t) else np.array([NULL_CODE], np.int32))
-
-    av = jnp.where(a.vals >= 0, recode(a.dictionary)[jnp.clip(a.vals, 0)], NULL_CODE)
-    bv = jnp.where(b.vals >= 0, recode(b.dictionary)[jnp.clip(b.vals, 0)], NULL_CODE)
+    ta = _vocab_table(("union-recode",), _union_recode, da, db)
+    tb = _vocab_table(("union-recode",), _union_recode, db, da)
+    av = jnp.where(a.vals >= 0, ta[jnp.clip(a.vals, 0)], NULL_CODE)
+    bv = jnp.where(b.vals >= 0, tb[jnp.clip(b.vals, 0)], NULL_CODE)
     return av, bv
 
 
@@ -610,9 +644,14 @@ def _lower_like(ctx: LowerCtx, expr: ir.Call) -> LoweredVal:
     assert isinstance(pat, ir.Constant), "LIKE pattern must be a literal (round 1)"
     assert x.dictionary is not None
     rx = re.compile(_like_to_regex(pat.value), re.S)
-    lut = np.array([rx.fullmatch(v) is not None for v in x.dictionary.values], dtype=bool)
-    lut_dev = jnp.asarray(lut) if len(lut) else jnp.zeros((1,), dtype=bool)
-    out = jnp.where(x.vals >= 0, lut_dev[jnp.clip(x.vals, 0, max(len(lut) - 1, 0))], False)
+
+    def matches(d: Dictionary) -> np.ndarray:
+        lut = np.array([rx.fullmatch(v) is not None for v in d.values], dtype=bool)
+        return lut if len(lut) else np.zeros((1,), dtype=bool)
+
+    lut = _vocab_table(("like", pat.value), matches, x.dictionary)
+    n = len(x.dictionary)
+    out = jnp.where(x.vals >= 0, lut[jnp.clip(x.vals, 0, max(n - 1, 0))], False)
     return LoweredVal(out, x.valid, None)
 
 

@@ -23,6 +23,8 @@ from typing import Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from trino_tpu.ops import expr_key
+
 I128 = Tuple[jnp.ndarray, jnp.ndarray]  # (hi int64, lo int64 bit pattern)
 
 # numpy scalar to stay concrete if first imported under a trace
@@ -155,6 +157,7 @@ def _divmod_core(hi: jnp.ndarray, lo: jnp.ndarray, dd: jnp.ndarray):
     """Unsigned (hi,lo) u64 pair divided by u64 ``dd`` (< 2^63): shift-
     subtract over the low word after dividing the high word (64 unrolled
     vector steps)."""
+    expr_key.eager_only("int128 long division")
     q_hi = hi // dd
     r = hi % dd  # < d <= 2^63: doubling stays below 2^64
     q_lo = jnp.zeros_like(lo)
@@ -171,6 +174,7 @@ def divmod_u128(a: I128, b: I128) -> Tuple[I128, I128]:
     """Unsigned 128/128 division of NON-NEGATIVE operands (b > 0): classic
     shift-subtract long division, 128 unrolled vector steps (reference:
     Int128Math.divide's unsigned core). Returns (quotient, remainder)."""
+    expr_key.eager_only("int128 long division")
     n_hi, n_lo = _u(a[0]), _u(a[1])
     d_hi, d_lo = _u(b[0]), _u(b[1])
     r_hi = jnp.zeros_like(n_hi)

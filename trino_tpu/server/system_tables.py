@@ -328,6 +328,7 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("cacheBypasses", 0)),
             int(r.get("stagingPuts", 0)),
             int(r.get("exchangedBytes", 0)),
+            int(r.get("exprPrograms", 0)), int(r.get("exprEager", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:
