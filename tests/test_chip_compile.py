@@ -258,3 +258,47 @@ def test_q9s_joins_compile_at_sf10_shapes(one_chip, what):
         compiled = jax.jit(measure).lower(
             arr(15_728_640, jnp.int64), arr(15_728_640, jnp.bool_)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize("build", ["in place", "dead tail"])
+def test_q21s_residual_join_ranges_compile_without_a_sort(one_chip, build):
+    """TPC-H Q21 at SF 10: its NOT EXISTS build is lineitem's 60 x 2^20
+    slots under the lateness filter, its EXISTS build the same slots with a
+    dead tail, and its probe the 2^20 slots of a Compact. The build is
+    taken as it lies (``join_ops.in_place_build``: a running max and one
+    reduction; a dead tail masked) and the probe's key ranges found by
+    binary search (``join_ops.searched_counts``): no sort instruction of
+    either side in the program."""
+    from trino_tpu.ops import join as join_ops
+
+    def arr(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    def ranges(build_key, build_sel, probe_key, probe_sel):
+        if build == "in place":
+            b = join_ops.in_place_build((build_key, None), build_sel)
+        else:
+            b = join_ops.build_side([(build_key, None)], build_sel,
+                                    presorted=True)
+        return join_ops.searched_counts(b, [(probe_key, None)], probe_sel)
+
+    compiled = jax.jit(ranges).lower(
+        arr(62_914_560, jnp.int32), arr(62_914_560, jnp.bool_),
+        arr(1_048_576, jnp.int32), arr(1_048_576, jnp.bool_)).compile()
+    assert " sort(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_q21s_supplier_gather_compiles_in_blocks(one_chip):
+    """Q21 at SF 10 joins supplier's 100,000 rows to the 2^25 slots the
+    orders join kept, and gathers three int32 payload columns at every one:
+    as one [2^25, 3] row gather the v5e compiler asked 16 GiB (the result
+    laid out 128 lanes wide); in blocks of ``ROW_GATHER_BLOCK`` slots it
+    fits beside the tables."""
+    from trino_tpu.ops import ranks
+
+    arrays = tuple(jax.ShapeDtypeStruct((100_000,), jnp.int32,
+                                        sharding=one_chip) for _ in range(3))
+    idx = jax.ShapeDtypeStruct((33_554_432,), jnp.int32, sharding=one_chip)
+    compiled = ranks._gather_all.lower(arrays, idx).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -562,12 +562,12 @@ def q20(schema="tiny"):
     return rows
 
 
-def q21(schema="tiny", limit=100):
+def q21(schema="tiny", limit=100, nation_name="SAUDI ARABIA"):
     supp = load_table(schema, "supplier", ["s_suppkey", "s_name", "s_nationkey"])
     li = load_table(schema, "lineitem", ["l_orderkey", "l_suppkey", "l_receiptdate", "l_commitdate"])
     orders = load_table(schema, "orders", ["o_orderkey", "o_orderstatus"])
     nation = load_table(schema, "nation", ["n_nationkey", "n_name"])
-    saudi = {n["n_nationkey"] for n in nation if n["n_name"] == "SAUDI ARABIA"}
+    saudi = {n["n_nationkey"] for n in nation if n["n_name"] == nation_name}
     sname = {s["s_suppkey"]: s["s_name"] for s in supp if s["s_nationkey"] in saudi}
     fstat = {o["o_orderkey"] for o in orders if o["o_orderstatus"] == "F"}
     by_order = defaultdict(list)

@@ -257,6 +257,11 @@ SYSTEM_TABLES = {
         # that dispatched their primitives one by one
         ("expr_programs", "bigint"),
         ("expr_eager", "bigint"),
+        # slots of the (probe, build) expansion a residual semi/anti join
+        # evaluated (its static capacity), and the builds a join sorted,
+        # both on the Join's row
+        ("residual_pairs", "bigint"),
+        ("build_sorts", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

@@ -2029,24 +2029,40 @@ class Executor:
         return out
 
     @staticmethod
-    def _build_presorted(page: Page, key_channels) -> bool:
-        """True when the build page's single join key is ascending,
-        null-free, and dead rows form a tail — build_side skips its sort."""
+    def _key_in_order(page: Page, key_channels) -> bool:
+        """True when the build page's single join key is ascending over its
+        live rows and null-free."""
         if len(key_channels) != 1:
             return False
         col = page.columns[key_channels[0]]
-        if not col.ascending or col.nulls is not None:
-            return False
-        return page.sel is None or page.live_prefix
+        return col.ascending and col.nulls is None
+
+    @classmethod
+    def _build_presorted(cls, page: Page, key_channels) -> bool:
+        """True when the build page's single join key is in order and dead
+        rows form a tail — build_side skips its sort."""
+        return cls._key_in_order(page, key_channels) and (
+            page.sel is None or page.live_prefix)
+
+    def _sorted_build(self, build_keys, sel, presorted: bool):
+        """``join_ops.build_side``, counting ``buildSorts`` on the executing
+        Join's row when it sorts (a traced tier counts nothing)."""
+        if join_ops.sorts_build(build_keys, presorted):
+            self._count_build_sort()
+        return join_ops.build_side(build_keys, sel, presorted=presorted)
+
+    def _count_build_sort(self) -> None:
+        if self.eager_tier:
+            count_charged("buildSorts")
 
     def expand_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
         """General M:N inner/left join: count matches per probe row, then
         gather into a static-capacity probe-major output (ops/join.py
         probe_counts + expand; reference JoinHash position-links chains)."""
         build_keys, probe_keys = self._expansion_keys(node, left, right)
-        build = join_ops.build_side(
-            build_keys, right.sel,
-            presorted=node.left_keys and self._build_presorted(right, node.right_keys))
+        build = self._sorted_build(
+            build_keys, right.sel, bool(node.left_keys)
+            and self._build_presorted(right, node.right_keys))
         lo, counts = join_ops.probe_counts(build, probe_keys, left.sel)
         n = left.num_rows
         outer = node.join_type == "left"
@@ -2123,56 +2139,92 @@ class Executor:
         tail = Page(tail_cols, tail_sel, page.replicated)
         return Page.concat_pages(head, tail)
 
+    # a probe at most this share of its build finds its key ranges by
+    # binary search (join_ops.searched_counts), not by merge ranks
+    SEARCHED_PROBE_SHARE = 1 / 16
+
     def semi_join_filtered(self, node: P.JoinNode, left: Page, right: Page) -> Page:
         """Semi/anti join with a residual filter (correlated EXISTS with
         non-equality predicates): expand the matches, evaluate the filter,
-        then reduce any-passing back to the probe rows."""
+        then reduce any-passing back to the probe rows. Every pair is
+        evaluated, so a build whose single key is in order is never sorted:
+        a dead tail is masked (``presorted``), and dead rows among the live
+        ones stay where they are, the residual gating each pair by its
+        build row's liveness (``join_ops.in_place_build``; Q21's NOT
+        EXISTS build is lineitem under a filter). A probe far smaller than
+        its build finds its ranges by binary search."""
         build_keys, probe_keys = self._expansion_keys(node, left, right)
-        build = join_ops.build_side(
-            build_keys, right.sel,
-            presorted=node.left_keys and self._build_presorted(right, node.right_keys))
-        lo, counts = join_ops.probe_counts(build, probe_keys, left.sel)
+        presorted = bool(node.left_keys) and self._build_presorted(
+            right, node.right_keys)
+        # the eager tier sizes the expansion from the counts themselves; a
+        # traced tier's capacity comes from estimates of LIVE matches
+        if (not presorted and self.eager_tier and node.left_keys
+                and right.num_rows
+                and self._key_in_order(right, node.right_keys)
+                and not join_ops.sorts_build(build_keys, True)):
+            build = join_ops.in_place_build(build_keys[0], right.sel)
+        else:
+            build = self._sorted_build(build_keys, right.sel, presorted)
         n = left.num_rows
-        capacity = self.hint_capacity(f"join:{node.id}", counts)
-        p, k, live, total = join_ops.expand(counts, capacity)
-        self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}", total > capacity))
-        left_arrays = [lo]
-        for c in left.columns:
-            if c.type.is_nested:
-                raise NotImplementedError("array/map columns through join payloads")
-            left_arrays.append(c.values)
-            if c.nulls is not None:
-                left_arrays.append(c.nulls)
-            if c.hi is not None:
-                left_arrays.append(c.hi)
-        g = ranks_ops.batched_gather(left_arrays, p)
-        b_idx = jnp.clip(g[0] + k, 0, build.n - 1)
-        rows = build.rows[b_idx]
-        exp_cols = []
-        gi = 1
-        for c in left.columns:
-            v = g[gi]
-            gi += 1
-            nulls = None
-            if c.nulls is not None:
-                nulls = g[gi]
+        with tracing.span("join/residual", join_type=node.join_type,
+                          probe_rows=n, build_rows=right.num_rows) as sp:
+            if build.single and 0 < n <= self.SEARCHED_PROBE_SHARE * build.n:
+                lo, counts = join_ops.searched_counts(build, probe_keys,
+                                                      left.sel)
+            else:
+                lo, counts = join_ops.probe_counts(build, probe_keys, left.sel)
+            capacity = self.hint_capacity(f"join:{node.id}", counts)
+            sp.set("pairs", capacity)
+            if self.eager_tier:
+                count_charged("residualPairs", capacity)
+            p, k, live, total = join_ops.expand(counts, capacity)
+            self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}",
+                                total > capacity))
+            left_arrays = [lo]
+            for c in left.columns:
+                if c.type.is_nested:
+                    raise NotImplementedError(
+                        "array/map columns through join payloads")
+                left_arrays.append(c.values)
+                if c.nulls is not None:
+                    left_arrays.append(c.nulls)
+                if c.hi is not None:
+                    left_arrays.append(c.hi)
+            g = ranks_ops.batched_gather(left_arrays, p)
+            b_idx = jnp.clip(g[0] + k, 0, build.n - 1)
+            if build.in_place:
+                rows = b_idx
+                live = live & build.live[b_idx]
+            else:
+                rows = build.rows[b_idx]
+            exp_cols = []
+            gi = 1
+            for c in left.columns:
+                v = g[gi]
                 gi += 1
-            chi = None
-            if c.hi is not None:
-                chi = g[gi]
-                gi += 1
-            exp_cols.append(
-                Column(c.type, v, nulls, c.dictionary,
-                       c.vrange if chi is None else None, hi=chi))
-        exp_cols.extend(self._gather_right_cols(right.columns, rows, live))
-        exp_page = Page(exp_cols, live, left.replicated and right.replicated)
-        lv = self._lower(node.filter, exp_page)
-        passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
-        hit = (
-            seg.monotonic_segment_sum((live & passed).astype(jnp.int32), p, n) > 0
-        )
-        keep = hit if node.join_type == "semi" else ~hit
-        sel = keep if left.sel is None else left.sel & keep
+                nulls = None
+                if c.nulls is not None:
+                    nulls = g[gi]
+                    gi += 1
+                chi = None
+                if c.hi is not None:
+                    chi = g[gi]
+                    gi += 1
+                exp_cols.append(
+                    Column(c.type, v, nulls, c.dictionary,
+                           c.vrange if chi is None else None, hi=chi))
+            exp_cols.extend(self._gather_right_cols(right.columns, rows, live))
+            exp_page = Page(exp_cols, live, left.replicated and right.replicated)
+            lv = self._lower(node.filter, exp_page)
+            passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
+            hit = (seg.monotonic_segment_sum(
+                (live & passed).astype(jnp.int32), p, n) > 0)
+            keep = hit if node.join_type == "semi" else ~hit
+            sel = keep if left.sel is None else left.sel & keep
+            if self.eager_tier:
+                # the span ends when the device has the answer: the read of
+                # the mask that follows would wait for it anyway
+                sel.block_until_ready()
         return Page(left.columns, sel, left.replicated)
 
     def _dense_join_cols(self, node: P.JoinNode, left: Page, right: Page):
@@ -2250,7 +2302,7 @@ class Executor:
         dtypes = ",".join(str(v.dtype) for v, _ in build_keys)
 
         def load():
-            build = join_ops.build_side(build_keys, right.sel)
+            build = self._sorted_build(build_keys, right.sel, False)
             arrays = list(build.cols) + [build.rows, build.live]
             nbytes = sum(int(a.size) * a.dtype.itemsize for a in arrays)
             return build, int(build.n), nbytes, 0
@@ -2306,15 +2358,16 @@ class Executor:
             cached = None if presorted else self._cached_sorted_build(
                 node, right, build_keys)
             if presorted or cached is not None:
-                build = cached if cached is not None else join_ops.build_side(
-                    build_keys, right.sel, presorted=True)
+                build = cached if cached is not None else self._sorted_build(
+                    build_keys, right.sel, True)
                 return self._merge_sorted_tier(
                     node, left, right, build, build_keys, probe_keys)
             M.FUSED_JOIN_SELECTIONS.inc(1, "fused")
+            self._count_build_sort()
             return fused_ops.fused_probe_unique(
                 build_keys, right.sel, probe_keys)
         M.FUSED_JOIN_SELECTIONS.inc(1, "legacy")
-        build = join_ops.build_side(build_keys, right.sel, presorted=presorted)
+        build = self._sorted_build(build_keys, right.sel, presorted)
         return join_ops.probe_unique(build, probe_keys)
 
     def _lookup_probe(self, node: P.JoinNode, left: Page, right: Page):
@@ -2428,16 +2481,19 @@ class Executor:
             cached = None if presorted else self._cached_sorted_build(
                 node, right, build_keys)
             if presorted or cached is not None:
-                build = cached if cached is not None else join_ops.build_side(
-                    build_keys, right.sel, presorted=True)
+                build = cached if cached is not None else self._sorted_build(
+                    build_keys, right.sel, True)
                 _rows, hit = self._merge_sorted_tier(
                     node, left, right, build, build_keys, probe_keys)
             else:
                 M.FUSED_JOIN_SELECTIONS.inc(1, "fused")
+                self._count_build_sort()
                 hit = fused_ops.fused_membership(
                     build_keys, right.sel, probe_keys)
         else:
             M.FUSED_JOIN_SELECTIONS.inc(1, "legacy")
+            if join_ops.sorts_build(build_keys, presorted):
+                self._count_build_sort()
             hit = join_ops.membership(
                 build_keys, right.sel, probe_keys, presorted=presorted)
         keep = hit if node.join_type == "semi" else ~hit

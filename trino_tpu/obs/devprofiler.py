@@ -75,7 +75,12 @@ puts a fresh staging issued (exec/staging.py ``PagePuts``: one an array
 of the page; 0 on a hit). A Join's row counts ``joinProbeSlots``
 and ``joinBuildSlots``: the row capacities (static shapes, no read) of
 the probe and build pages of every execution, the work its place in the
-join ORDER makes it carry whatever the kernels do.
+join ORDER makes it carry whatever the kernels do; ``residualPairs``: the
+slots of the (probe, build) expansion a semi or anti join with a residual
+predicate evaluated (exec/executor.py ``semi_join_filtered``: its static
+capacity, no read); and ``buildSorts``: the builds whose keys it sorted,
+in any tier (``ops/join.py build_side``'s sort, the fused sort-merge
+tier's combined sort, a device-cached build's sort on a miss).
 
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
@@ -132,7 +137,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "colocatedAggs": 0, "exchangedRows": 0, "outputFetches": 0,
            "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0,
            "stagingPuts": 0, "exchangedBytes": 0,
-           "exprPrograms": 0, "exprEager": 0}
+           "exprPrograms": 0, "exprEager": 0,
+           "residualPairs": 0, "buildSorts": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -172,7 +178,7 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "colocatedAggs", "exchangedRows", "outputFetches",
                       "joinProbeSlots", "joinBuildSlots", "cacheBypasses",
                       "stagingPuts", "exchangedBytes", "exprPrograms",
-                      "exprEager"):
+                      "exprEager", "residualPairs", "buildSorts"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

@@ -83,6 +83,9 @@ sub-spans, the compiles, the exchange pulls and their consumer parts in
                   its uploads
     pull          an exchange pull or spool read with nothing finer open
     gc-pause      the garbage collector held the interpreter lock
+    residual-join a semi/anti join's expansion, residual filter and
+                  any-reduce (exec/executor.py ``semi_join_filtered``): a
+                  nested operator's rank, so it splits ``op:Join`` alone
     remainder     no detail span open. Under ``device-execute`` that is the
                   coordinator waiting on its workers and the executor's
                   unspanned glue, NOT device compute: no phase here is
@@ -235,6 +238,8 @@ DETAIL_LABELS: Dict[str, Tuple[int, str]] = {
     "exchange/deserialize": (6, "deserialize"),
     "exchange/pull": (7, "pull"),
     "spool/read": (7, "pull"),
+    # opened inside its Join's operator span: the innermost at that rank
+    "join/residual": (_R_OPERATOR, "residual-join"),
 }
 REMAINDER = "remainder"
 

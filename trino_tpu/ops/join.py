@@ -4,7 +4,9 @@ Reference: ``operator/join/`` — PagesHash open addressing + PositionLinks
 chains (JoinHash.java:28-69). TPU formulation: the build side is sorted by
 key once (one fused multi-operand ``lax.sort``); probe ranges come from
 merge ranks (ops/ranks.py: one combined stable sort + streaming prefixes —
-binary search and its log2(n) random-gather passes never appear):
+binary search and its log2(n) random-gather passes appear only where a
+build already in key order meets a probe far smaller than it,
+``searched_counts``):
 
 - unique-key build (PK-FK joins, N:1): probe -> at most one match -> output
   size == probe size (static shapes, no two-pass emit). The planner proves
@@ -102,6 +104,9 @@ class SortedBuild:
     rows: jnp.ndarray  # original row index per sorted slot
     live: jnp.ndarray  # bool per sorted slot
     single: bool  # True -> cols == [sentinel-masked key], no flag column
+    # True -> built by ``in_place_build``: slot i is build row i, and a
+    # key's range may hold dead rows, which its consumer gates by ``live``
+    in_place: bool = False
 
     @property
     def n(self) -> int:
@@ -119,6 +124,12 @@ def _live_mask(keys: List[Lowered], sel: Optional[jnp.ndarray]) -> jnp.ndarray:
     return live
 
 
+def sorts_build(keys: List[Lowered], presorted: bool) -> bool:
+    """Whether ``build_side(keys, sel, presorted)`` sorts: the presorted
+    promise holds for a single null-free key only."""
+    return not (presorted and len(keys) == 1 and keys[0][1] is None)
+
+
 def build_side(keys: List[Lowered], sel: Optional[jnp.ndarray],
                presorted: bool = False) -> SortedBuild:
     """Sort the build side by composite key; dead/null rows sort last and can
@@ -128,12 +139,10 @@ def build_side(keys: List[Lowered], sel: Optional[jnp.ndarray],
     ascending with dead rows forming a TAIL (Column.ascending +
     Page.live_prefix) — the build sort is skipped entirely (sentinel-masked
     dead tail keeps the array sorted: the sentinel is the dtype max)."""
-    import jax
-
     live = _live_mask(keys, sel)
     n = live.shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
-    if presorted and len(keys) == 1 and keys[0][1] is None:
+    if not sorts_build(keys, presorted):
         vals = keys[0][0]
         if vals.dtype == jnp.bool_:
             vals = vals.astype(jnp.int8)
@@ -157,6 +166,61 @@ def build_side(keys: List[Lowered], sel: Optional[jnp.ndarray],
     sort_keys = [dead] + masked
     out = ranks.stable_sort(tuple(sort_keys) + (live, iota), len(sort_keys))
     return SortedBuild(list(out[:-2]), out[-1], out[-2], False)
+
+
+def in_place_build(key: Lowered, sel: Optional[jnp.ndarray]) -> SortedBuild:
+    """The build of a SINGLE key whose LIVE rows the caller proves
+    ascending (Column.ascending), dead rows anywhere among them — a
+    filter's mask over a table in key order — with no sort and no
+    compaction. A dead row takes the key of the live row before it (a
+    running max over the live keys, so the column is sorted whatever the
+    dead rows hold) and the rows after the last live one the sentinel: a
+    key's range may then hold dead rows, and its consumer gates every
+    (probe, build) pair by ``live`` (``in_place``). Only a join that
+    evaluates each pair anyway, the residual semi/anti join, takes it."""
+    vals = key[0]
+    if vals.dtype == jnp.bool_:
+        vals = vals.astype(jnp.int8)
+    live = _live_mask([key], sel)
+    n = live.shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    k = scans.cummax(jnp.where(live, vals, scans._lowest(vals.dtype)))
+    last = jnp.max(jnp.where(live, iota, -1), initial=-1)
+    k = jnp.where(iota > last, _sentinel_max(vals.dtype), k)
+    return SortedBuild([k], iota, live, True, in_place=True)
+
+
+def searched_counts(
+    build: SortedBuild,
+    probe_keys: List[Lowered],
+    probe_sel: Optional[jnp.ndarray],
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``probe_counts`` by binary search of each probe key in a
+    single-key build that is already in key order: log2(build) gathers a
+    probe row, where the merge ranks sort the probe AND the build together.
+    For a probe far smaller than the build (Q21's 2^20 probe slots against
+    lineitem's 2^26) that is the cheaper one. A key's range ends where its
+    run of equal keys does: one streaming pass over the build, not a second
+    search."""
+    pk = _probe_cols(build, probe_keys)[0]
+    col = build.cols[0]
+    n = build.n
+    lo = jnp.searchsorted(col, pk, side="left").astype(jnp.int32)
+    iota = jnp.arange(n, dtype=jnp.int32)
+    last = jnp.concatenate([col[1:] != col[:-1], jnp.ones((1,), bool)])
+    run_end = scans.cummin(jnp.where(last, iota + 1, jnp.int32(n)),
+                           reverse=True)
+    at = jnp.clip(lo, 0, n - 1)
+    counts = jnp.where((lo < n) & (col[at] == pk), run_end[at] - lo, 0)
+    if not build.in_place:
+        # a probe equal to the sentinel would match the dead tail
+        counts = jnp.where(build.live[at], counts, 0)
+    pvalid = probe_valid(probe_keys)
+    if pvalid is not None:
+        counts = jnp.where(pvalid, counts, 0)
+    if probe_sel is not None:
+        counts = jnp.where(probe_sel, counts, 0)
+    return lo, counts
 
 
 def _probe_cols(build: SortedBuild, probe_keys: List[Lowered]) -> List[jnp.ndarray]:
@@ -211,9 +275,10 @@ def probe_counts(
     probe rows (sel/NULL key) count 0."""
     probe = _probe_cols(build, probe_keys)
     lo, counts = ranks.sorted_ranks(build.cols, probe)
-    # ranges of a real key contain only live rows (dead rows sort last with
-    # unmatchable key) but guard the all-dead-build edge anyway
-    counts = jnp.where(build.live[jnp.clip(lo, 0, build.n - 1)], counts, 0)
+    if not build.in_place:
+        # ranges of a real key contain only live rows (dead rows sort last
+        # with unmatchable key) but guard the all-dead-build edge anyway
+        counts = jnp.where(build.live[jnp.clip(lo, 0, build.n - 1)], counts, 0)
     pvalid = probe_valid(probe_keys)
     if pvalid is not None:
         counts = jnp.where(pvalid, counts, 0)

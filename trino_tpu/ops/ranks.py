@@ -166,10 +166,36 @@ def _gather_all(arrays, idx):
         if len(idxs) == 1:
             out[idxs[0]] = arrays[idxs[0]][idx]
         else:
-            g = jnp.stack([arrays[i] for i in idxs], axis=1)[idx]
+            cols = _row_gather(jnp.stack([arrays[i] for i in idxs], axis=1),
+                               idx)
             for j, i in enumerate(idxs):
-                out[i] = g[:, j]
+                out[i] = cols[j]
     return tuple(out)
+
+
+# The [n, k] result of a row gather may be laid out [n, 128] on the v5e,
+# whatever k is: 42.7x for three int32 columns, 16 GiB at n = 2^25 (TPC-H
+# Q21's supplier join at SF 10, which the chip's compiler refused). A
+# gather of more slots than ROW_GATHER_MAX goes by in blocks of
+# ROW_GATHER_BLOCK, each block's columns taken apart before the next.
+ROW_GATHER_BLOCK = 1 << 21
+ROW_GATHER_MAX = 1 << 23
+
+
+def _row_gather(stacked: jnp.ndarray, idx: jnp.ndarray) -> List[jnp.ndarray]:
+    """The columns of ``stacked[idx]``."""
+    n, k = idx.shape[0], stacked.shape[1]
+    if n <= ROW_GATHER_MAX:
+        g = stacked[idx]
+        return [g[:, j] for j in range(k)]
+    blocks = jnp.pad(idx, (0, -n % ROW_GATHER_BLOCK)).reshape(
+        -1, ROW_GATHER_BLOCK)
+
+    def block(b):
+        g = stacked[b]
+        return tuple(g[:, j] for j in range(k))
+
+    return [c.reshape(-1)[:n] for c in jax.lax.map(block, blocks)]
 
 
 def batched_gather(arrays: List[jnp.ndarray], idx: jnp.ndarray) -> List[jnp.ndarray]:
