@@ -289,6 +289,28 @@ def test_q21s_residual_join_ranges_compile_without_a_sort(one_chip, build):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+def test_q21s_expansion_and_any_reduce_compile_without_a_sort(one_chip):
+    """Q21 at SF 10 expands each residual join's 2^20 probe slots into 2^22
+    pair slots and reduces a flag a pair back to the probe rows: the slot
+    owners from one scatter and running maxes (``join_ops.expand``), the
+    any-reduce from the ranges' bounds (``join_ops.any_in_range``). No sort
+    instruction in the program, where the ranks it replaced sorted
+    2^23 rows twice for each."""
+    from trino_tpu.ops import join as join_ops
+
+    def expand_and_reduce(counts, flags):
+        ex = join_ops.expand(counts, 4_194_304)
+        return ex.p, ex.k, join_ops.any_in_range(ex, flags & ex.live)
+
+    compiled = jax.jit(expand_and_reduce).lower(
+        jax.ShapeDtypeStruct((1_048_576,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((4_194_304,), jnp.bool_,
+                             sharding=one_chip)).compile()
+    assert " sort(" not in compiled.as_text()
+    assert " scatter(" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_q21s_supplier_gather_compiles_in_blocks(one_chip):
     """Q21 at SF 10 joins supplier's 100,000 rows to the 2^25 slots the
     orders join kept, and gathers three int32 payload columns at every one:

@@ -6,8 +6,8 @@ sorted: a dead tail is masked, dead rows among the live ones stay where
 they are and the residual gates each pair by its build row's liveness
 (``ops/join.py in_place_build``), and a probe far smaller than its build
 finds its key ranges by binary search (``searched_counts``). The counters
-``residualPairs`` and ``buildSorts`` and the ``join/residual`` span say
-what the joins did."""
+``residualPairs``, ``buildSorts`` and ``expansionSorts`` and the
+``join/residual`` span say what the joins did."""
 import numpy as np
 import pytest
 
@@ -150,9 +150,10 @@ def _run_q21(schema, nation, monkeypatch=None, force_sort=False):
     ex = Executor(s)
     rows = [list(r) for r in ex.execute_checked(root).to_pylist()]
     joins = {j.id: j.join_type for j in _residual_joins(root)}
-    counts = {joins[nid]: (r["residualPairs"], r["buildSorts"])
+    counts = {joins[nid]: (r["residualPairs"], r["buildSorts"],
+                           r["expansionSorts"])
               for (nid, kind), r in ex.kernel_stats.items() if nid in joins}
-    other = sum(r["buildSorts"] + r["residualPairs"]
+    other = sum(r["buildSorts"] + r["residualPairs"] + r["expansionSorts"]
                 for (nid, _kind), r in ex.kernel_stats.items()
                 if nid not in joins)
     return rows, counts, other
@@ -161,17 +162,20 @@ def _run_q21(schema, nation, monkeypatch=None, force_sort=False):
 def test_the_residual_joins_answer_as_the_sorted_builds_did(monkeypatch):
     """The parent's path, kept here as the reference and not as a flag:
     both lineitem builds sorted and every range from merge ranks. Same
-    rows; it counts two build sorts where this change counts none."""
+    rows; it counts two build sorts and two expansion sorts (the merge
+    ranks) where this change counts none."""
     from benchmark.reference import tpch_q21
 
     nation = "GERMANY"
     rows, counts, other = _run_q21("tiny", nation)
     assert rows == tpch_q21.q21("tiny", [{"nation": nation}])[0]
     assert set(counts) == {"semi", "anti"} and other == 0
-    assert all(sorts == 0 and pairs >= 16 for pairs, sorts in counts.values())
+    assert all(sorts == ranked == 0 and pairs >= 16
+               for pairs, sorts, ranked in counts.values())
     was, was_counts, _ = _run_q21("tiny", nation, monkeypatch, force_sort=True)
     assert was == rows
-    assert {k: v[1] for k, v in was_counts.items()} == {"semi": 1, "anti": 1}
+    assert {k: v[1:] for k, v in was_counts.items()} == {
+        "semi": (1, 1), "anti": (1, 1)}
     # the in-place anti build keeps its dead rows in the ranges: never
     # fewer pairs than the sorted build's
     assert counts["anti"][0] >= was_counts["anti"][0]
@@ -282,6 +286,60 @@ def test_the_counters_reach_the_profile_and_the_kernels_table(served):
         "select sum(residual_pairs), sum(build_sorts) "
         f"from system.runtime.kernels where query_id = '{client.query_id}'")
     assert [int(v) for v in table[0]] == [pairs, sorts]
+
+
+# an M:N left join with a residual (``Executor.expand_join``'s tail): its
+# pairs' ranges from merge ranks, one combined rank sort, and the probe rows
+# with no passing pair back from the expansion's own ranges
+LEFT_RESIDUAL = ("select o_orderkey, l_linenumber, l_suppkey from orders "
+                 "left join lineitem on o_orderkey = l_orderkey "
+                 "and l_linenumber * 300 > o_custkey where o_orderkey < 300")
+
+
+def test_a_left_join_with_a_residual_equals_sqlite(served):
+    import sqlite3
+
+    db = sqlite3.connect(":memory:")
+    for table, cols in (("orders", ["o_orderkey", "o_custkey"]),
+                        ("lineitem", ["l_orderkey", "l_linenumber",
+                                      "l_suppkey"])):
+        db.execute(f"create table {table} ({', '.join(cols)})")
+        db.executemany(
+            f"insert into {table} values ({', '.join('?' * len(cols))})",
+            [tuple(r[c] for c in cols)
+             for r in oracle.load_table("tiny", table, cols)])
+    want = sorted(db.execute(LEFT_RESIDUAL).fetchall(),
+                  key=lambda r: tuple((v is None, v) for v in r))
+    client = _client(served)
+    _cols, rows = client.execute(LEFT_RESIDUAL)
+    got = sorted(map(tuple, rows),
+                 key=lambda r: tuple((v is None, v) for v in r))
+    assert got == want
+    # both sides of the residual occur: kept pairs and null-extended rows
+    assert any(r[1] is None for r in got) and any(r[1] for r in got)
+
+
+@pytest.mark.parametrize("sql, sorts", [
+    # served at tiny, the semi join's probe is its split's 61,440 slots, as
+    # many as its build's, and its ranges come from merge ranks; the anti
+    # join's probe of 1,024 slots is searched. Neither expansion nor
+    # any-reduce sorts (run in one process, both probes are searched:
+    # test_the_residual_joins_answer_as_the_sorted_builds_did)
+    (Q21.format(nation="SAUDI ARABIA"), 1),
+    (LEFT_RESIDUAL, 1),                       # the left join's merge ranks
+], ids=["q21", "left-residual"])
+def test_expansion_sorts_reach_the_profile_and_the_kernels_table(
+        served, sql, sorts):
+    client = _client(served)
+    client.execute(sql)
+    kernels = _profile(served, client.query_id)
+    joins = [k for k in kernels if k["operator"] == "Join"]
+    assert joins and sum(k["expansionSorts"] for k in joins) == sorts
+    assert sum(k["expansionSorts"] for k in kernels) == sorts
+    _cols, table = client.execute(
+        "select sum(expansion_sorts) from system.runtime.kernels "
+        f"where query_id = '{client.query_id}'")
+    assert int(table[0][0]) == sorts
 
 
 # ------------------------------ the reference can tell a missing residual

@@ -262,6 +262,9 @@ SYSTEM_TABLES = {
         # both on the Join's row
         ("residual_pairs", "bigint"),
         ("build_sorts", "bigint"),
+        # combined rank sorts an expansion join ran (its probe's key ranges
+        # by merge ranks), on the Join's row
+        ("expansion_sorts", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

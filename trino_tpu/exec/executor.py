@@ -2055,6 +2055,15 @@ class Executor:
         if self.eager_tier:
             count_charged("buildSorts")
 
+    def _expansion_counts(self, build, probe_keys, probe_sel):
+        """``join_ops.probe_counts`` for an expansion join, counting
+        ``expansionSorts`` on the executing Join's row: its merge ranks are
+        the one combined rank sort an expansion runs (a traced tier counts
+        nothing)."""
+        if self.eager_tier:
+            count_charged("expansionSorts")
+        return join_ops.probe_counts(build, probe_keys, probe_sel)
+
     def expand_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
         """General M:N inner/left join: count matches per probe row, then
         gather into a static-capacity probe-major output (ops/join.py
@@ -2063,7 +2072,7 @@ class Executor:
         build = self._sorted_build(
             build_keys, right.sel, bool(node.left_keys)
             and self._build_presorted(right, node.right_keys))
-        lo, counts = join_ops.probe_counts(build, probe_keys, left.sel)
+        lo, counts = self._expansion_counts(build, probe_keys, left.sel)
         n = left.num_rows
         outer = node.join_type == "left"
         probe_live = (
@@ -2072,8 +2081,10 @@ class Executor:
         plain_outer = outer and node.filter is None
         emit = jnp.where(probe_live, jnp.maximum(counts, 1), 0) if plain_outer else counts
         capacity = self.hint_capacity(f"join:{node.id}", emit)
-        p, k, live, total = join_ops.expand(emit, capacity)
-        self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}", total > capacity))
+        ex = join_ops.expand(emit, capacity)
+        p, k, live = ex.p, ex.k, ex.live
+        self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}",
+                            ex.total > capacity))
         # ONE batched random gather at p for lo/counts and every left column
         # (separate computed-index gathers don't fuse: ~40 ms each per 6M
         # rows on v5e — see ranks.batched_gather)
@@ -2118,11 +2129,7 @@ class Executor:
         # left join with filter: expanded rows that pass, plus one null-build
         # row for each probe row with no passing match
         passing = live & matched & passed
-        # p is probe-major (non-decreasing) — monotonic segment sum, no scatter
-        any_pass = (
-            seg.monotonic_segment_sum(passing.astype(jnp.int32), p, n) > 0
-        )
-        tail_sel = probe_live & ~any_pass
+        tail_sel = probe_live & ~join_ops.any_in_range(ex, passing)
         tail_cols = []
         for c in left.columns:
             tail_cols.append(c)
@@ -2172,14 +2179,16 @@ class Executor:
                 lo, counts = join_ops.searched_counts(build, probe_keys,
                                                       left.sel)
             else:
-                lo, counts = join_ops.probe_counts(build, probe_keys, left.sel)
+                lo, counts = self._expansion_counts(build, probe_keys,
+                                                    left.sel)
             capacity = self.hint_capacity(f"join:{node.id}", counts)
             sp.set("pairs", capacity)
             if self.eager_tier:
                 count_charged("residualPairs", capacity)
-            p, k, live, total = join_ops.expand(counts, capacity)
+            ex = join_ops.expand(counts, capacity)
+            live = ex.live
             self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}",
-                                total > capacity))
+                                ex.total > capacity))
             left_arrays = [lo]
             for c in left.columns:
                 if c.type.is_nested:
@@ -2190,8 +2199,8 @@ class Executor:
                     left_arrays.append(c.nulls)
                 if c.hi is not None:
                     left_arrays.append(c.hi)
-            g = ranks_ops.batched_gather(left_arrays, p)
-            b_idx = jnp.clip(g[0] + k, 0, build.n - 1)
+            g = ranks_ops.batched_gather(left_arrays, ex.p)
+            b_idx = jnp.clip(g[0] + ex.k, 0, build.n - 1)
             if build.in_place:
                 rows = b_idx
                 live = live & build.live[b_idx]
@@ -2217,8 +2226,7 @@ class Executor:
             exp_page = Page(exp_cols, live, left.replicated and right.replicated)
             lv = self._lower(node.filter, exp_page)
             passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
-            hit = (seg.monotonic_segment_sum(
-                (live & passed).astype(jnp.int32), p, n) > 0)
+            hit = join_ops.any_in_range(ex, live & passed)
             keep = hit if node.join_type == "semi" else ~hit
             sel = keep if left.sel is None else left.sel & keep
             if self.eager_tier:

@@ -78,9 +78,14 @@ the probe and build pages of every execution, the work its place in the
 join ORDER makes it carry whatever the kernels do; ``residualPairs``: the
 slots of the (probe, build) expansion a semi or anti join with a residual
 predicate evaluated (exec/executor.py ``semi_join_filtered``: its static
-capacity, no read); and ``buildSorts``: the builds whose keys it sorted,
+capacity, no read); ``buildSorts``: the builds whose keys it sorted,
 in any tier (``ops/join.py build_side``'s sort, the fused sort-merge
-tier's combined sort, a device-cached build's sort on a miss).
+tier's combined sort, a device-cached build's sort on a miss); and
+``expansionSorts``: the combined rank sorts an expansion join (an M:N
+join, or a semi or anti join with a residual) ran, in the eager tier: its
+probe's key ranges by merge ranks (``ops/join.py probe_counts``). The
+expansion itself and its any-reduce run none (``expand``,
+``any_in_range``).
 
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
@@ -138,7 +143,7 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0,
            "stagingPuts": 0, "exchangedBytes": 0,
            "exprPrograms": 0, "exprEager": 0,
-           "residualPairs": 0, "buildSorts": 0}
+           "residualPairs": 0, "buildSorts": 0, "expansionSorts": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -178,7 +183,8 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "colocatedAggs", "exchangedRows", "outputFetches",
                       "joinProbeSlots", "joinBuildSlots", "cacheBypasses",
                       "stagingPuts", "exchangedBytes", "exprPrograms",
-                      "exprEager", "residualPairs", "buildSorts"):
+                      "exprEager", "residualPairs", "buildSorts",
+                      "expansionSorts"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

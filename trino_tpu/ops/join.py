@@ -25,12 +25,13 @@ hashes arbitrary-width keys the same way (InterpretedHashGenerator.java:85).
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
 from trino_tpu.ops import ranks
 from trino_tpu.ops import scans
+from trino_tpu.ops import segments as seg
 
 Lowered = Tuple[jnp.ndarray, Optional[jnp.ndarray]]
 
@@ -287,36 +288,60 @@ def probe_counts(
     return lo, counts
 
 
-def expand(
-    counts: jnp.ndarray, capacity: int
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Pass 2: map output slot j -> (probe_row, within-range offset).
+class Expansion(NamedTuple):
+    """Output slot j of a probe-major expansion (all matches of probe row 0,
+    then row 1, ...) into ``capacity`` slots (:func:`expand`)."""
 
-    Returns (probe_row[cap], offset_in_range[cap], live[cap], total).
-    Output is probe-major (all matches of probe row 0, then row 1, ...).
-    """
+    p: jnp.ndarray        # [capacity] the probe row that owns slot j
+    k: jnp.ndarray        # [capacity] j's offset in that row's range
+    live: jnp.ndarray     # [capacity] j < min(total, capacity)
+    total: jnp.ndarray    # () int64 pairs asked for; > capacity overflowed
+    starts: jnp.ndarray   # [n] each probe row's first slot, <= capacity
+    ends: jnp.ndarray     # [n] one past its last slot, <= capacity
+
+
+def expand(counts: jnp.ndarray, capacity: int) -> Expansion:
+    """Pass 2: map output slot j -> (probe_row, within-range offset), from
+    the prefix sum of ``counts`` alone: each probe row writes its index at
+    its range's first slot (one scatter), and a running max carries the
+    row and that slot to the rest of the range. No sort. A dead slot reads
+    the last probe row, as a search of the offsets would."""
     n = counts.shape[0]
-    c64 = counts.astype(jnp.int64)  # cumsum in int64: totals can exceed 2^31
+    idx = jnp.int32 if capacity < 2**31 else jnp.int64
     if n == 0:  # zero-row probe page: all output slots dead
-        z = jnp.zeros((capacity,), jnp.int64)
-        return z, z, jnp.zeros((capacity,), bool), jnp.zeros((), jnp.int64)
+        z = jnp.zeros((capacity,), idx)
+        e = jnp.zeros((0,), idx)
+        return Expansion(z, z, jnp.zeros((capacity,), bool),
+                         jnp.zeros((), jnp.int64), e, e)
+    c64 = counts.astype(jnp.int64)  # cumsum in int64: totals can exceed 2^31
     offsets = scans.cumsum(c64)  # inclusive
     total = offsets[n - 1]
     starts = offsets - c64
-    # search in int32 when capacity fits: offsets past 2^31 only occur when
-    # total overflowed the capacity anyway (flagged, run discarded), so
-    # clipping them cannot change any slot j < capacity's result
-    if capacity < 2**31:
-        offs = jnp.clip(offsets, 0, 2**31 - 1).astype(jnp.int32)
-        j = jnp.arange(capacity, dtype=jnp.int32)
-    else:
-        offs = offsets
-        j = jnp.arange(capacity, dtype=jnp.int64)
-    # both sides sorted -> merge ranks, not binary search
-    p = jnp.clip(ranks.ranks_sorted_queries(offs, j, side="right"), 0, n - 1)
-    k = j.astype(jnp.int64) - starts[p]
-    live = j < jnp.minimum(total, capacity).astype(j.dtype)
-    return p, k, live, total
+    # every row writes row + 1 at its start: a row with no pairs shares its
+    # start with the next row that has some (the max keeps that one) or
+    # lies at ``total`` (a dead slot); a start past the capacity (an
+    # overflow, flagged and the run discarded) is dropped. The starts never
+    # fall, and a scatter told so is no sort on the TPU (its compiler sorts
+    # the indices of any other: 2^20 of them at Q21's shape)
+    at = jnp.minimum(starts, capacity).astype(idx)
+    first = jnp.zeros((capacity,), jnp.int32).at[at].max(
+        jnp.arange(1, n + 1, dtype=jnp.int32), mode="drop",
+        indices_are_sorted=True)
+    j = jnp.arange(capacity, dtype=idx)
+    owner = scans.cummax(first)  # row + 1 of the last range begun at <= j
+    begun = scans.cummax(jnp.where(first > 0, j, 0))
+    live = j < jnp.minimum(total, capacity).astype(idx)
+    p = jnp.where(live, owner - 1, n - 1)
+    k = j - jnp.where(live, begun, at[n - 1])
+    return Expansion(p, k, live, total, at,
+                     jnp.minimum(offsets, capacity).astype(idx))
+
+
+def any_in_range(ex: Expansion, flags: jnp.ndarray) -> jnp.ndarray:
+    """bool[n]: per probe row, whether ``flags`` holds at any slot of its
+    range — one prefix sum over the slots, two gathers at the rows' bounds."""
+    return seg._cumsum_diff_ranges(ex.starts, ex.ends,
+                                   flags.astype(jnp.int32)) > 0
 
 
 # ---------------------------------------------------------------- dense path

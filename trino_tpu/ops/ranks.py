@@ -323,11 +323,3 @@ def sorted_ranks(
     counts = right_o[nb:] - lo
     return lo, counts
 
-
-def ranks_sorted_queries(
-    sorted_vals: jnp.ndarray, queries_sorted: jnp.ndarray, side: str
-) -> jnp.ndarray:
-    """searchsorted(sorted_vals, queries_sorted, side) when BOTH arrays are
-    sorted — same combined-sort machinery, one call."""
-    lo, counts = sorted_ranks([sorted_vals], [queries_sorted])
-    return lo if side == "left" else lo + counts
