@@ -324,3 +324,58 @@ def test_q21s_supplier_gather_compiles_in_blocks(one_chip):
     idx = jax.ShapeDtypeStruct((33_554_432,), jnp.int32, sharding=one_chip)
     compiled = ranks._gather_all.lower(arrays, idx).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+# TPC-H Q13 at SF 10: customer's 1.5 M keys probe the 14.8 M orders that the
+# ON clause keeps (``special requests``, the widest exclusion), and the plain
+# LEFT join emits each match plus a null-extended row for each customer
+# with none, 15.3 M rows at a capacity of 2^24
+Q13_CUSTOMERS = 1_500_000
+Q13_KEPT_ORDERS = 14_780_421
+Q13_PAIRS = 16_777_216
+
+
+@pytest.mark.parametrize("what", ["build sort", "expansion", "probe gather",
+                                  "build gather"])
+def test_q13s_outer_expansion_compiles_at_sf10_shapes(one_chip, what):
+    """Each program of Q13's LEFT join at SF 10 in the eager tier
+    (``Executor.expand_join``): the build's sort pass at 2^24 slots, the
+    plain-outer expansion of the probe rows' counts into 2^24 slots, the
+    probe rows' columns gathered to them in ONE batched gather, and the
+    build's row ids and payload gathered there, in blocks over
+    ``ROW_GATHER_MAX`` (as one ``[2^25, 3]`` row gather the compiler asked
+    16 GiB: TPC-H Q21's supplier join). Each fits the chip beside the
+    tables."""
+    from trino_tpu.ops import join as join_ops, ranks
+
+    def arr(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    idx = arr(Q13_PAIRS, jnp.int32)
+    if what == "build sort":
+        compiled = ranks._sort_pass.lower(
+            arr(Q13_PAIRS, jnp.int32), arr(Q13_PAIRS, jnp.int32)).compile()
+        assert " sort(" in compiled.as_text()
+    elif what == "expansion":
+        def outer(counts, live):
+            emit = jnp.where(live, jnp.maximum(counts, 1), 0)
+            ex = join_ops.expand(emit, Q13_PAIRS)
+            return ex.p, ex.k, ex.live, ex.total
+
+        compiled = jax.jit(outer).lower(
+            arr(Q13_CUSTOMERS, jnp.int32),
+            arr(Q13_CUSTOMERS, jnp.bool_)).compile()
+        assert " sort(" not in compiled.as_text()
+    elif what == "probe gather":
+        # lo, counts and c_custkey
+        arrays = tuple(arr(Q13_CUSTOMERS, jnp.int32) for _ in range(3))
+        compiled = ranks._gather_all.lower(arrays, idx).compile()
+    else:
+        # the sorted build's row ids, then o_orderkey and o_custkey
+        rows = jax.jit(lambda r, i: r[i]).lower(
+            arr(Q13_PAIRS, jnp.int32), idx).compile()
+        assert rows.memory_analysis().temp_size_in_bytes < 2 << 30
+        arrays = tuple(arr(Q13_KEPT_ORDERS, jnp.int32) for _ in range(2))
+        compiled = ranks._gather_all.lower(arrays, idx).compile()
+        assert "s32[2097152,2]" in compiled.as_text()   # in blocks
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

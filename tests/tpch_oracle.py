@@ -408,12 +408,12 @@ def q12(schema="tiny"):
     return [(m, high[m], low[m]) for m in sorted(set(high) | set(low))]
 
 
-def q13(schema="tiny"):
+def q13(schema="tiny", word1="special", word2="requests"):
     cust = load_table(schema, "customer", ["c_custkey"])
     orders = load_table(schema, "orders", ["o_orderkey", "o_custkey", "o_comment"])
     cnt = defaultdict(int)
     for o in orders:
-        if _like(o["o_comment"], "%special%requests%"):
+        if _like(o["o_comment"], f"%{word1}%{word2}%"):
             continue
         cnt[o["o_custkey"]] += 1
     dist = defaultdict(int)

@@ -336,6 +336,12 @@ class AdaptivePlanner:
         threshold = int(self.props.get("adaptive_skew_threshold", 8) or 0)
         if frag.partitioning != "hash" or self.n_workers < 2:
             return None
+        if any(isinstance(n, P.AggregationNode)
+               and n.distribution == "partitioned"
+               for n in P.walk_plan(frag.root)):
+            # an aggregation finished in the join's fragment needs each
+            # group in one task: salting spreads a hot key over all of them
+            return None
         for j in P.walk_plan(frag.root):
             if not isinstance(j, P.JoinNode) or not j.left_keys:
                 continue

@@ -265,6 +265,11 @@ SYSTEM_TABLES = {
         # combined rank sorts an expansion join ran (its probe's key ranges
         # by merge ranks), on the Join's row
         ("expansion_sorts", "bigint"),
+        # slots an inner/left M:N join's expansion emitted into (its static
+        # capacity), on the Join's row, and executions of an aggregation
+        # finished in its partitioned join's fragment, on its own row
+        ("expanded_rows", "bigint"),
+        ("join_fragment_aggs", "bigint"),
     ),
     # the compile ledger (trino_tpu/obs/devprofiler.py): one row per
     # jit/Pallas compile event cluster-wide — plan fingerprint + shape

@@ -808,6 +808,9 @@ class Executor:
         if node.distribution == "colocated":
             # finished where its table is scanned: nothing was cut under it
             count_charged("colocatedAggs")
+        elif node.distribution == "partitioned":
+            # finished in its join's hash fragment: no joined row gathered
+            count_charged("joinFragmentAggs")
         return self.aggregate_page(node, page)
 
     def aggregate_partial(self, node: P.AggregationNode, page: Page) -> Page:
@@ -2067,84 +2070,92 @@ class Executor:
     def expand_join(self, node: P.JoinNode, left: Page, right: Page) -> Page:
         """General M:N inner/left join: count matches per probe row, then
         gather into a static-capacity probe-major output (ops/join.py
-        probe_counts + expand; reference JoinHash position-links chains)."""
+        probe_counts + expand; reference JoinHash position-links chains).
+        The counts, the expansion, its gathers and a left join's outer
+        tail run inside the ``join/expand`` span."""
         build_keys, probe_keys = self._expansion_keys(node, left, right)
         build = self._sorted_build(
             build_keys, right.sel, bool(node.left_keys)
             and self._build_presorted(right, node.right_keys))
-        lo, counts = self._expansion_counts(build, probe_keys, left.sel)
-        n = left.num_rows
-        outer = node.join_type == "left"
-        probe_live = (
-            left.sel if left.sel is not None else jnp.ones((n,), dtype=bool)
-        )
-        plain_outer = outer and node.filter is None
-        emit = jnp.where(probe_live, jnp.maximum(counts, 1), 0) if plain_outer else counts
-        capacity = self.hint_capacity(f"join:{node.id}", emit)
-        ex = join_ops.expand(emit, capacity)
-        p, k, live = ex.p, ex.k, ex.live
-        self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}",
-                            ex.total > capacity))
-        # ONE batched random gather at p for lo/counts and every left column
-        # (separate computed-index gathers don't fuse: ~40 ms each per 6M
-        # rows on v5e — see ranks.batched_gather)
-        left_arrays = [lo, counts]
-        for c in left.columns:
-            if c.type.is_nested:
-                raise NotImplementedError("array/map columns through join payloads")
-            left_arrays.append(c.values)
-            if c.nulls is not None:
-                left_arrays.append(c.nulls)
-            if c.hi is not None:
-                left_arrays.append(c.hi)
-        g = ranks_ops.batched_gather(left_arrays, p)
-        lo_p, counts_p = g[0], g[1]
-        matched = live & (k < counts_p)
-        b_idx = jnp.clip(lo_p + k, 0, build.n - 1)
-        rows = build.rows[b_idx]
-        out_cols = []
-        gi = 2
-        for c in left.columns:
-            v = g[gi]
-            gi += 1
-            nulls = None
-            if c.nulls is not None:
-                nulls = g[gi]
-                gi += 1
-            chi = None
-            if c.hi is not None:
-                chi = g[gi]
-                gi += 1
-            out_cols.append(
-                Column(c.type, v, nulls, c.dictionary,
-                       c.vrange if chi is None else None, hi=chi))
-        out_cols.extend(self._gather_right_cols(right.columns, rows, matched))
-        page = Page(out_cols, live, left.replicated and right.replicated)
-        if node.filter is None:
-            return page
-        lv = self._lower(node.filter, page)
-        passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
-        if not outer:
-            return Page(out_cols, live & passed, page.replicated)
-        # left join with filter: expanded rows that pass, plus one null-build
-        # row for each probe row with no passing match
-        passing = live & matched & passed
-        tail_sel = probe_live & ~join_ops.any_in_range(ex, passing)
-        tail_cols = []
-        for c in left.columns:
-            tail_cols.append(c)
-        for rc in right.columns:
-            tail_cols.append(
-                Column(
-                    rc.type,
-                    jnp.zeros((n,), dtype=rc.values.dtype),
-                    jnp.ones((n,), dtype=bool),
-                    rc.dictionary,
-                )
+        with tracing.span("join/expand", join_type=node.join_type,
+                          probe_rows=left.num_rows,
+                          build_rows=right.num_rows) as sp:
+            lo, counts = self._expansion_counts(build, probe_keys, left.sel)
+            n = left.num_rows
+            outer = node.join_type == "left"
+            probe_live = (
+                left.sel if left.sel is not None else jnp.ones((n,), dtype=bool)
             )
-        head = Page(out_cols, passing, page.replicated)
-        tail = Page(tail_cols, tail_sel, page.replicated)
-        return Page.concat_pages(head, tail)
+            plain_outer = outer and node.filter is None
+            emit = jnp.where(probe_live, jnp.maximum(counts, 1), 0) if plain_outer else counts
+            capacity = self.hint_capacity(f"join:{node.id}", emit)
+            sp.set("pairs", capacity)
+            if self.eager_tier:
+                count_charged("expandedRows", capacity)
+            ex = join_ops.expand(emit, capacity)
+            p, k, live = ex.p, ex.k, ex.live
+            self.errors.append((f"CAPACITY_EXCEEDED:join:{node.id}",
+                                ex.total > capacity))
+            # ONE batched random gather at p for lo/counts and every left column
+            # (separate computed-index gathers don't fuse: ~40 ms each per 6M
+            # rows on v5e — see ranks.batched_gather)
+            left_arrays = [lo, counts]
+            for c in left.columns:
+                if c.type.is_nested:
+                    raise NotImplementedError("array/map columns through join payloads")
+                left_arrays.append(c.values)
+                if c.nulls is not None:
+                    left_arrays.append(c.nulls)
+                if c.hi is not None:
+                    left_arrays.append(c.hi)
+            g = ranks_ops.batched_gather(left_arrays, p)
+            lo_p, counts_p = g[0], g[1]
+            matched = live & (k < counts_p)
+            b_idx = jnp.clip(lo_p + k, 0, build.n - 1)
+            rows = build.rows[b_idx]
+            out_cols = []
+            gi = 2
+            for c in left.columns:
+                v = g[gi]
+                gi += 1
+                nulls = None
+                if c.nulls is not None:
+                    nulls = g[gi]
+                    gi += 1
+                chi = None
+                if c.hi is not None:
+                    chi = g[gi]
+                    gi += 1
+                out_cols.append(
+                    Column(c.type, v, nulls, c.dictionary,
+                           c.vrange if chi is None else None, hi=chi))
+            out_cols.extend(self._gather_right_cols(right.columns, rows, matched))
+            page = Page(out_cols, live, left.replicated and right.replicated)
+            if node.filter is not None:
+                lv = self._lower(node.filter, page)
+                passed = lv.vals if lv.valid is None else (lv.vals & lv.valid)
+                if not outer:
+                    page = Page(out_cols, live & passed, page.replicated)
+                else:
+                    # left join with filter: expanded rows that pass, plus
+                    # one null-build row for each probe row with no
+                    # passing match
+                    passing = live & matched & passed
+                    tail_sel = probe_live & ~join_ops.any_in_range(
+                        ex, passing)
+                    tail_cols = list(left.columns)
+                    for rc in right.columns:
+                        tail_cols.append(Column(
+                            rc.type, jnp.zeros((n,), dtype=rc.values.dtype),
+                            jnp.ones((n,), dtype=bool), rc.dictionary))
+                    page = Page.concat_pages(
+                        Page(out_cols, passing, page.replicated),
+                        Page(tail_cols, tail_sel, page.replicated))
+            if self.eager_tier:
+                # the span ends when the device has the page
+                jax.block_until_ready(
+                    [page.sel] + [c.values for c in page.columns])
+        return page
 
     # a probe at most this share of its build finds its key ranges by
     # binary search (join_ops.searched_counts), not by merge ranks

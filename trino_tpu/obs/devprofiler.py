@@ -55,7 +55,10 @@ page under ``prefixCompactions``). An Aggregation's row counts
 ``colocatedAggs``: executions of a single-step aggregation the fragmenter
 finished inside the source fragment that scans its table
 (sql/planner/fragmenter.py ``_colocated_aggregation``: no partial/final
-cut, no exchange under it). The row of a fragment's ROOT operator counts
+cut, no exchange under it), and ``joinFragmentAggs``: executions of one
+it finished inside the hash fragment of the partitioned join beneath it
+(``_join_fragment_of``: its group keys hold the join's partitioning key,
+so no joined row is gathered). The row of a fragment's ROOT operator counts
 ``exchangedRows``: the live rows its task handed to its output buffer
 (server/task.py ``SqlTask._output_path``), what crosses an exchange,
 ``exchangedBytes``: the bytes of the frames those rows were written in,
@@ -85,7 +88,9 @@ tier's combined sort, a device-cached build's sort on a miss); and
 join, or a semi or anti join with a residual) ran, in the eager tier: its
 probe's key ranges by merge ranks (``ops/join.py probe_counts``). The
 expansion itself and its any-reduce run none (``expand``,
-``any_in_range``).
+``any_in_range``); and ``expandedRows``: the slots of the expansion an
+inner or left M:N join emitted into (exec/executor.py ``expand_join``: its
+static capacity, no read).
 
 Hot-path contract: ``count_launch`` is a couple of integer adds under
 one short lock — safe on the point-lookup serving path.  Metrics and
@@ -143,7 +148,8 @@ def new_kernel_row(plan_node_id: str, operator: str, tier: str,
            "joinProbeSlots": 0, "joinBuildSlots": 0, "cacheBypasses": 0,
            "stagingPuts": 0, "exchangedBytes": 0,
            "exprPrograms": 0, "exprEager": 0,
-           "residualPairs": 0, "buildSorts": 0, "expansionSorts": 0}
+           "residualPairs": 0, "buildSorts": 0, "expansionSorts": 0,
+           "expandedRows": 0, "joinFragmentAggs": 0}
     if node_id is not None:
         row["nodeId"] = node_id
     return row
@@ -184,7 +190,7 @@ def merge_kernel_rows(dst: Dict[tuple, dict],
                       "joinProbeSlots", "joinBuildSlots", "cacheBypasses",
                       "stagingPuts", "exchangedBytes", "exprPrograms",
                       "exprEager", "residualPairs", "buildSorts",
-                      "expansionSorts"):
+                      "expansionSorts", "expandedRows", "joinFragmentAggs"):
             agg[field] += int(row.get(field, 0))
         for field in ("wallS", "deviceS", "hostSyncS", "compileS"):
             agg[field] += float(row.get(field, 0.0))

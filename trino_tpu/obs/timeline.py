@@ -86,6 +86,9 @@ sub-spans, the compiles, the exchange pulls and their consumer parts in
     residual-join a semi/anti join's expansion, residual filter and
                   any-reduce (exec/executor.py ``semi_join_filtered``): a
                   nested operator's rank, so it splits ``op:Join`` alone
+    expand-join   an inner/left M:N join's counts, expansion, gathers and
+                  outer tail (exec/executor.py ``expand_join``), at the
+                  same rank as ``residual-join``
     remainder     no detail span open. Under ``device-execute`` that is the
                   coordinator waiting on its workers and the executor's
                   unspanned glue, NOT device compute: no phase here is
@@ -240,6 +243,7 @@ DETAIL_LABELS: Dict[str, Tuple[int, str]] = {
     "spool/read": (7, "pull"),
     # opened inside its Join's operator span: the innermost at that rank
     "join/residual": (_R_OPERATOR, "residual-join"),
+    "join/expand": (_R_OPERATOR, "expand-join"),
 }
 REMAINDER = "remainder"
 

@@ -331,6 +331,7 @@ class CoordinatorSystemTables(spi.LiveTableProvider):
             int(r.get("exprPrograms", 0)), int(r.get("exprEager", 0)),
             int(r.get("residualPairs", 0)), int(r.get("buildSorts", 0)),
             int(r.get("expansionSorts", 0)),
+            int(r.get("expandedRows", 0)), int(r.get("joinFragmentAggs", 0)),
         )
 
     def _compiles_rows(self) -> List[tuple]:

@@ -25,6 +25,7 @@ import dataclasses
 import itertools
 from typing import List, Optional, Tuple
 
+from trino_tpu.sql import ir
 from trino_tpu.sql.planner import plan as P
 from trino_tpu.sql.planner.optimizer import _trace_to_scan
 
@@ -157,11 +158,95 @@ def _colocated_join(session, node: P.JoinNode, left, right) -> bool:
     return True
 
 
+def _partitioned_join_key_sets(join: P.JoinNode) -> List[set]:
+    """For each key of a partitioned join, the output channels that carry
+    it in every row the join emits: the probe side's key always (rows are
+    partitioned by it, and a LEFT join's null-extended rows keep it), and
+    for an inner join the build side's too (equal to the probe's in every
+    pair). A LEFT join's build key is NULL in its null-extended rows, which
+    lie in every partition, so it carries nothing."""
+    width = len(join.left.output_types)
+    sets = []
+    for lk, rk in zip(join.left_keys, join.right_keys):
+        carried = {lk}
+        if join.join_type == "inner":
+            carried.add(width + rk)
+        sets.append(carried)
+    return sets
+
+
+def _remote_channel(node: P.PlanNode, channel: int):
+    """(RemoteSourceNode, channel) that ``channel`` of ``node`` comes from
+    through Filters, Compacts and column references of Projects, or None."""
+    while isinstance(node, (P.FilterNode, P.CompactNode, P.ProjectNode)):
+        if isinstance(node, P.ProjectNode):
+            e = node.expressions[channel]
+            if not isinstance(e, ir.ColumnRef):
+                return None
+            channel = e.index
+        node = node.source
+    return (node, channel) if isinstance(node, RemoteSourceNode) else None
+
+
+def _join_fragment_of(node: P.AggregationNode, src,
+                      fragments: List[PlanFragment]) -> Optional[PlanFragment]:
+    """The ``hash`` fragment of the partitioned join whose gathered output
+    ``src`` reads, when every group of the single-step ``node`` is whole
+    inside one of its partitions: for every join key, a channel that
+    carries it (:func:`_partitioned_join_key_sets`) is among the group keys
+    traced down to the join. The aggregation then runs in that fragment,
+    and only its groups cross an exchange (reference: AddExchanges adds no
+    exchange under an aggregation whose source partitioning satisfies the
+    grouping)."""
+    if node.step != "single" or not node.group_channels:
+        return None
+    traced = [_remote_channel(src, ch) for ch in node.group_channels]
+    if any(t is None for t in traced):
+        return None
+    remote = traced[0][0]
+    if remote.exchange_type != "gather" or any(
+            t[0] is not remote for t in traced):
+        return None
+    frag = next((f for f in fragments if f.id == remote.fragment_id), None)
+    if frag is None or frag.partitioning != "hash":
+        return None
+    join = frag.root
+    if not isinstance(join, P.JoinNode) or join.distribution != "partitioned":
+        return None
+    grouped = {ch for _remote, ch in traced}
+    if all(carried & grouped for carried in _partitioned_join_key_sets(join)):
+        return frag
+    return None
+
+
+def _replace_remote(node: P.PlanNode, by: P.PlanNode) -> P.PlanNode:
+    """``node`` with the RemoteSourceNode under its Filters, Compacts and
+    Projects replaced by ``by``."""
+    if isinstance(node, RemoteSourceNode):
+        return by
+    node.source = _replace_remote(node.source, by)
+    return node
+
+
+def _partitioning(root: P.PlanNode) -> str:
+    """A fragment that reads a partitioned exchange runs one task a
+    partition (``hash``); one that does not runs over its scans' splits."""
+    return "hash" if any(
+        isinstance(n, RemoteSourceNode) and n.exchange_type == "partitioned"
+        for n in P.walk_plan(root)) else "source"
+
+
 def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
     """Cut the optimized plan into fragments mirroring the SPMD execution."""
     global _frag_ids
     _frag_ids = itertools.count()
     fragments: List[PlanFragment] = []
+    # the id of a join fragment an aggregation above it took in, for the
+    # next fragment cut: ids stay dense
+    freed: List[int] = []
+
+    def new_id() -> int:
+        return freed.pop() if freed else next(_frag_ids)
 
     def cut(node: P.PlanNode, fragments: List[PlanFragment],
             keep_under: int = 0) -> Tuple[P.PlanNode, bool]:
@@ -178,6 +263,16 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             return node, rep
         if isinstance(node, P.AggregationNode):
             src, rep = cut(node.source, fragments)
+            joined = _join_fragment_of(node, src, fragments) if rep else None
+            if joined is not None:
+                # every group is whole inside one partition of the join:
+                # finish the aggregation in the join's fragment, which the
+                # fragment cut above it takes in
+                fragments.remove(joined)
+                freed.append(joined.id)
+                node.source = _replace_remote(src, joined.root)
+                node.distribution = "partitioned"
+                return node, False
             if rep:
                 node.source = src
                 return node, True
@@ -190,8 +285,8 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             if not P.can_split_aggs(node.aggregates):
                 # DISTINCT aggregates can't be split partial/final: gather the
                 # raw rows, aggregate single-step above the exchange
-                fid = next(_frag_ids)
-                fragments.append(PlanFragment(fid, "source", src))
+                fid = new_id()
+                fragments.append(PlanFragment(fid, _partitioning(src), src))
                 node.source = RemoteSourceNode(
                     fragment_id=fid,
                     types=src.output_types,
@@ -211,9 +306,9 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                 # partition aggregates disjoint key sets in parallel —
                 # no process ever holds all groups (reference:
                 # PagePartitioner producer + hash-distributed final stage)
-                fid = next(_frag_ids)
+                fid = new_id()
                 fragments.append(PlanFragment(
-                    fid, "source", partial,
+                    fid, _partitioning(partial), partial,
                     output_partition_channels=list(range(k))))
                 remote = RemoteSourceNode(
                     fragment_id=fid,
@@ -225,7 +320,7 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                     remote, list(range(k)), node.aggregates, step="final",
                     names=node.names,
                 )
-                hfid = next(_frag_ids)
+                hfid = new_id()
                 fragments.append(PlanFragment(hfid, "hash", final))
                 return RemoteSourceNode(
                     fragment_id=hfid,
@@ -233,8 +328,8 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                     names=final.output_names,
                     exchange_type="gather",
                 ), True
-            fid = next(_frag_ids)
-            fragments.append(PlanFragment(fid, "source", partial))
+            fid = new_id()
+            fragments.append(PlanFragment(fid, _partitioning(partial), partial))
             remote = RemoteSourceNode(
                 fragment_id=fid,
                 types=partial.output_types,
@@ -292,13 +387,13 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                 # process ever materializes a whole side (reference:
                 # PagePartitioner.java:134-149 + partitioned join
                 # distribution).
-                lfid = next(_frag_ids)
+                lfid = new_id()
                 fragments.append(PlanFragment(
-                    lfid, "source", left,
+                    lfid, _partitioning(left), left,
                     output_partition_channels=list(node.left_keys)))
-                rfid = next(_frag_ids)
+                rfid = new_id()
                 fragments.append(PlanFragment(
-                    rfid, "source", right,
+                    rfid, _partitioning(right), right,
                     output_partition_channels=list(node.right_keys)))
                 node.left = RemoteSourceNode(
                     fragment_id=lfid, types=left.output_types,
@@ -307,7 +402,7 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                     fragment_id=rfid, types=right.output_types,
                     names=right.output_names, exchange_type="partitioned")
                 node.distribution = "partitioned"
-                jfid = next(_frag_ids)
+                jfid = new_id()
                 fragments.append(PlanFragment(jfid, "hash", node))
                 return RemoteSourceNode(
                     fragment_id=jfid, types=node.output_types,
@@ -316,8 +411,8 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             node.left = left
             if not rrep:
                 # build side broadcast: its own source fragment
-                fid = next(_frag_ids)
-                fragments.append(PlanFragment(fid, "source", right))
+                fid = new_id()
+                fragments.append(PlanFragment(fid, _partitioning(right), right))
                 node.right = RemoteSourceNode(
                     fragment_id=fid,
                     types=right.output_types,
@@ -332,8 +427,8 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
                              P.MatchRecognizeNode)):
             src, rep = cut(node.source, fragments)
             if not rep:
-                fid = next(_frag_ids)
-                fragments.append(PlanFragment(fid, "source", src))
+                fid = new_id()
+                fragments.append(PlanFragment(fid, _partitioning(src), src))
                 src = RemoteSourceNode(
                     fragment_id=fid,
                     types=src.output_types,
@@ -349,8 +444,8 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             for kid in kids:
                 src, rep = cut(kid, fragments)
                 if not rep:
-                    fid = next(_frag_ids)
-                    fragments.append(PlanFragment(fid, "source", src))
+                    fid = new_id()
+                    fragments.append(PlanFragment(fid, _partitioning(src), src))
                     src = RemoteSourceNode(
                         fragment_id=fid,
                         types=src.output_types,
@@ -372,8 +467,8 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
     body, rep = cut(copy.deepcopy(root.source), fragments)
     out = P.OutputNode(body, root.column_names)
     if not rep:
-        fid = next(_frag_ids)
-        fragments.append(PlanFragment(fid, "source", body))
+        fid = new_id()
+        fragments.append(PlanFragment(fid, _partitioning(body), body))
         out = P.OutputNode(
             RemoteSourceNode(
                 fragment_id=fid,
@@ -383,7 +478,7 @@ def fragment_plan(root: P.OutputNode, session=None) -> List[PlanFragment]:
             ),
             root.column_names,
         )
-    fragments.append(PlanFragment(next(_frag_ids), "single", out))
+    fragments.append(PlanFragment(new_id(), "single", out))
     from trino_tpu.sql.planner.sanity import (
         validate_fragments, validation_enabled)
 

@@ -239,7 +239,10 @@ class AggregationNode(PlanNode):
     # 'colocated': a single-step aggregation the fragmenter finished inside
     # the source fragment that scans its table, because the group keys
     # include the table's partitioning columns (every group is whole inside
-    # one split): no partial/final cut, no exchange under it
+    # one split): no partial/final cut, no exchange under it.
+    # 'partitioned': one the fragmenter finished inside the hash fragment of
+    # a partitioned join beneath it, because the group keys include the
+    # join's partitioning key (every group is whole inside one partition)
     distribution: Optional[str] = None
 
     @property
